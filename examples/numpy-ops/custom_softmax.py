@@ -33,16 +33,16 @@ class Softmax(mx.operator.CustomOp):
         x = in_data[0].asnumpy()
         x = x - x.max(axis=1, keepdims=True)
         e = np.exp(x)
-        self.assign(out_data[0], req[0], nd.array(e / e.sum(axis=1,
-                                                            keepdims=True)))
+        # NumPy straight into assign: the callback stays off the device
+        self.assign(out_data[0], req[0], e / e.sum(axis=1, keepdims=True))
 
     def backward(self, req, out_grad, in_data, out_data, in_grad, aux):
         # fused softmax + CE gradient: p - onehot(label)
         p = out_data[0].asnumpy().copy()
         y = in_data[1].asnumpy().astype(np.int64)
         p[np.arange(p.shape[0]), y] -= 1.0
-        self.assign(in_grad[0], req[0], nd.array(p / p.shape[0]))
-        self.assign(in_grad[1], req[1], nd.zeros(in_data[1].shape))
+        self.assign(in_grad[0], req[0], p / p.shape[0])
+        self.assign(in_grad[1], req[1], np.zeros(in_data[1].shape))
 
 
 @mx.operator.register("softmax_loss")

@@ -142,10 +142,7 @@ def num_gpus():
 
 def num_tpus():
     try:
-        plat = jax.default_backend()
-        if plat == "cpu":
-            return 0
-        return len(jax.devices())
+        return len(jax.devices("tpu"))
     except RuntimeError:
         return 0
 

@@ -13,7 +13,7 @@ import numpy as np
 
 from .. import initializer as init_mod
 from ..base import MXNetError
-from ..context import current_context
+from ..context import Context, current_context
 from ..ndarray import NDArray, array, zeros
 
 
@@ -41,6 +41,7 @@ class Parameter:
         self._grad = None
         self._deferred_init = None
         self._ctx = None
+        self._placed = False  # initialize() was given one Context
         # sharding annotation for pjit'd steps (jax.sharding.PartitionSpec
         # or None = replicated); consumed by parallel.data_parallel
         self.partition_spec = None
@@ -73,6 +74,7 @@ class Parameter:
         if self._data is not None and not force_reinit:
             return
         self._ctx = ctx or current_context()
+        self._placed = isinstance(ctx, Context)
         if self._shape_incomplete():
             if self.allow_deferred_init:
                 self._deferred_init = (init, self._ctx, default_init)
@@ -88,6 +90,13 @@ class Parameter:
         initializer = init_mod.create(init or self.init or default_init)
         desc = init_mod.InitDesc(self.name)
         initializer(desc, data)
+        if self._placed:
+            # an explicit single context places the data there (and so
+            # the grad, the optimizer state and every computation that
+            # follows it); without one the array stays uncommitted on
+            # the default device. Initializers rebind the array, so the
+            # move comes after them
+            data = data.as_in_context(self._ctx)
         self._data = _mem.tag_role(data, "parameter")
         self._deferred_init = None
         if self._grad_req != "null":

@@ -30,7 +30,6 @@ class CollectiveConn:
 
     def __init__(self):
         import jax
-        from jax._src import distributed as _jdist
 
         uri = os.environ.get("DMLC_PS_ROOT_URI")
         port = os.environ.get("DMLC_PS_ROOT_PORT")
@@ -39,7 +38,7 @@ class CollectiveConn:
         # check the distributed-runtime state WITHOUT touching the XLA
         # backend (jax.process_count() would initialize it and make a
         # late jax.distributed.initialize impossible)
-        if n > 1 and _jdist.global_state.client is None:
+        if n > 1 and not jax.distributed.is_initialized():
             if not (uri and port):
                 raise MXNetError(
                     "collective kvstore needs DMLC_PS_ROOT_URI/PORT (set "

@@ -679,6 +679,13 @@ def run_server(port=None, num_workers=None, poll_ms=200):
     import signal
     import sys
 
+    import jax
+
+    # a parameter server is a host process: its merges and optimizer
+    # updates run on the CPU backend, and it must never take the chip a
+    # worker on the same host owns (a chip belongs to one process)
+    jax.config.update("jax_platforms", "cpu")
+
     lib = _native.load_comm()
     if port is None:
         _, port = server_address()

@@ -8,6 +8,13 @@ into the dependency engine. The TPU-native escape hatch is
 a jit/hybridize trace XLA inserts a host callback at that point in the
 program. Gradients route back through the user's ``backward`` via
 ``jax.custom_vjp``, so custom ops compose with autograd and hybridize.
+
+The callback stays on NumPy. It runs on a thread of the runtime that is
+executing the enclosing program, and device work started from there —
+``jnp.zeros`` is a jitted program of its own — waits for that runtime
+and never returns. So ``forward``/``backward`` are handed NDArrays
+whose buffers are host NumPy memory, and ``assign`` keeps them so: read
+with ``.asnumpy()``, compute in NumPy, hand ``assign`` the result.
 """
 from __future__ import annotations
 
@@ -35,11 +42,13 @@ class CustomOp:
     def assign(self, dst, req, src):
         if req in ("null",):
             return
-        src = src if isinstance(src, NDArray) else NDArray(src)
+        # host NumPy in, host NumPy kept (see the module docstring); an
+        # NDArray src is read back, which is a copy and not a program
+        src = np.asarray(src._data if isinstance(src, NDArray) else src)
         if req == "add":
-            dst._data = dst._data + src._data
+            dst._data = np.asarray(dst._data) + src
         else:  # write / inplace
-            dst._data = src._data
+            dst._data = src
 
 
 class CustomOpProp:
@@ -91,6 +100,18 @@ def get_registered(op_type):
             "CustomOpProp with @mx.operator.register(...)") from None
 
 
+def _host_nd(value):
+    """An NDArray over host NumPy memory, for the arrays a CustomOp sees
+    inside the host callback (the constructor would make a device
+    array, which is device work inside the callback)."""
+    arr = NDArray.__new__(NDArray)
+    arr._data = np.asarray(value)
+    arr.grad = None
+    arr._grad_req = "null"
+    arr._entry = None
+    return arr
+
+
 def _custom_fn(op_type, kwargs, in_shapes, in_dtypes):
     """Build the jax-facing function for one (op_type, shapes) instance."""
     prop = get_registered(op_type)(**kwargs)
@@ -104,24 +125,23 @@ def _custom_fn(op_type, kwargs, in_shapes, in_dtypes):
                      for s, t in zip(in_shapes, in_dtypes))
 
     def host_forward(*in_datas):
-        ins = [NDArray(jnp.asarray(np.asarray(d))) for d in in_datas]
-        outs = [NDArray(jnp.zeros(tuple(s), jnp.dtype(t)))
+        ins = [_host_nd(d) for d in in_datas]
+        outs = [_host_nd(np.zeros(tuple(s), jnp.dtype(t)))
                 for s, t in zip(out_shapes, out_types)]
         op.forward(True, ["write"] * n_out, ins, outs, [])
-        return tuple(np.asarray(o._data) for o in outs)
+        return tuple(np.asarray(o._data, jnp.dtype(t))
+                     for o, t in zip(outs, out_types))
 
     def host_backward(*datas):
         n_in = len(in_shapes)
-        ograds = [NDArray(jnp.asarray(np.asarray(d)))
-                  for d in datas[:n_out]]
-        ins = [NDArray(jnp.asarray(np.asarray(d)))
-               for d in datas[n_out:n_out + n_in]]
-        outs = [NDArray(jnp.asarray(np.asarray(d)))
-                for d in datas[n_out + n_in:]]
-        igrads = [NDArray(jnp.zeros(tuple(s), jnp.dtype(t)))
+        ograds = [_host_nd(d) for d in datas[:n_out]]
+        ins = [_host_nd(d) for d in datas[n_out:n_out + n_in]]
+        outs = [_host_nd(d) for d in datas[n_out + n_in:]]
+        igrads = [_host_nd(np.zeros(tuple(s), jnp.dtype(t)))
                   for s, t in zip(in_shapes, in_dtypes)]
         op.backward(["write"] * n_in, ograds, ins, outs, igrads, [])
-        return tuple(np.asarray(g._data) for g in igrads)
+        return tuple(np.asarray(g._data, jnp.dtype(t))
+                     for g, t in zip(igrads, in_dtypes))
 
     @jax.custom_vjp
     def f(*in_datas):

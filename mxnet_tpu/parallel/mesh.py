@@ -72,36 +72,10 @@ def mesh_sharding(mesh, *spec):
     return NamedSharding(mesh, P(*spec))
 
 
-_SHARD_MAP_IMPL = []  # [(callable, spells_check_vma)] — probed once
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma=None, **kwargs):
-    """Version-bridging ``shard_map``: newer jax spells it
-    ``jax.shard_map(..., check_vma=...)``, older runtimes
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)`` —
-    and the top-level exposure and the kwarg rename shipped in
-    DIFFERENT releases, so the kwarg spelling is probed from the
-    signature, not inferred from where the function lives. All
-    in-repo call sites (parallel/, bench, tools, tests) route through
-    this one wrapper so the codebase runs on every range — without
-    monkeypatching the jax namespace."""
-    if not _SHARD_MAP_IMPL:
-        import inspect
-        impl = getattr(jax, "shard_map", None)
-        if impl is None:
-            from jax.experimental.shard_map import shard_map as impl
-        try:
-            spells_vma = "check_vma" in inspect.signature(
-                impl).parameters
-        except (TypeError, ValueError):
-            spells_vma = True  # unsignaturable: assume the new spelling
-        _SHARD_MAP_IMPL.append((impl, spells_vma))
-    impl, spells_vma = _SHARD_MAP_IMPL[0]
-    if check_vma is not None:
-        kwargs["check_vma" if spells_vma else "check_rep"] = check_vma
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs)
+# re-exported so in-repo call sites (parallel/, bench, tools, tests)
+# spell it ``parallel.shard_map(f, mesh=, in_specs=, out_specs=,
+# check_vma=)``
+shard_map = jax.shard_map
 
 
 def replica_devices(n, devices=None, exclude=()):

@@ -234,7 +234,7 @@ def dumps(reset=False, format="table"):
 # -- kvstore recovery telemetry -------------------------------------------
 # The dist transport reports every recovery incident (reconnect storms,
 # budget exhaustions) here, independent of the run/stop profiling state —
-# the bench supervisor needs to answer "WHY did this distributed run
+# bench.py needs to answer "WHY did this distributed run
 # degrade" even when nobody armed the profiler. When the profiler IS
 # running, each incident also lands in the chrome trace (category
 # "kvstore_recovery") so waits line up against the op timeline.
@@ -303,7 +303,7 @@ def recovery_incidents():
 
 def recovery_summary():
     """Aggregate recovery telemetry: the structured 'why it degraded'
-    record the bench supervisor folds into its JSON artifact.
+    record bench.py folds into its JSON artifact.
 
     Compatibility shim since PR 4: the counts come from the telemetry
     registry's mx_recovery_* families (unbounded, exported everywhere),

@@ -150,8 +150,13 @@ def _split_args(line, open_idx):
                 return line[open_idx + 1:i], line[i + 1:]
         i += 1
     return line[open_idx + 1:], ""
+# an operand is ``%name``, with its shape in front only in the older
+# print style (``f32[8,4]{1,0} %name``, where the sigil was optional);
+# the current one prints bare ``%name`` and the shape is looked up from
+# the defining instruction when the computation closes
 _OPERAND_RE = re.compile(
-    r"([a-z0-9]+\[[\d,]*\])(?:{[^}]*})?\s+%?([\w.\-]+)")
+    r"(?:([a-z0-9]+\[[\d,]*\])(?:{[^}]*})?\s+)?%([\w.\-]+)"
+    r"|([a-z0-9]+\[[\d,]*\])(?:{[^}]*})?\s+([\w.\-]+)")
 _METADATA_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"(?:calls|to_apply|body|condition|branch_"
                        r"computations)=\{?%?([\w.\-, %]+)\}?")
@@ -198,6 +203,12 @@ def parse_module(text):
                     entry = cur
             continue
         if stripped == "}":
+            shapes = {i.name: re.sub(r"{[^}]*}", "", i.shape)
+                      for i in computations[cur]}
+            for i in computations[cur]:
+                i.operand_shapes = [
+                    sh or shapes.get(n, "")
+                    for sh, n in zip(i.operand_shapes, i.operands)]
             cur = None
             continue
         im = _INSTR_HEAD.match(line)
@@ -207,9 +218,9 @@ def parse_module(text):
         args, tail = _split_args(line, im.end() - 1)
         operand_shapes = []
         operands = []
-        for oshape, oname in _OPERAND_RE.findall(args):
-            operand_shapes.append(oshape)
-            operands.append(oname)
+        for sh_a, name_a, sh_b, name_b in _OPERAND_RE.findall(args):
+            operand_shapes.append(sh_a or sh_b)
+            operands.append(name_a or name_b)
         md = _METADATA_RE.search(tail)
         calls = []
         for cm in _CALLS_RE.finditer(tail):

@@ -16,8 +16,8 @@ keys every row back to a *framework* op name:
   that produced the cluster, so "did this fusion rule pay?" is a
   ledger diff, not a guess.
 
-Every row gets a roofline classification against
-``MXTPU_PEAK_TFLOPS`` / ``MXTPU_PEAK_HBM_GBS``: ``compute`` when
+Every row gets a roofline classification against the peaks of the
+chip (``DEVICE_PEAKS``, keyed by ``device_kind``): ``compute`` when
 flops/peak dominates the estimated time, ``hbm`` when bytes/bandwidth
 does, ``comms`` for collectives, ``trivial`` for costless plumbing.
 
@@ -30,26 +30,55 @@ from __future__ import annotations
 import json
 import os
 import re
+import sys
 
+from ..base import MXNetError
 from . import hlo
 
 LEDGER_VERSION = 1
 
-# MXTPU_PEAK_TFLOPS default matches bench.py (v5e bf16); HBM GB/s
-# default is the v5e figure — both overridable per chip
-_DEF_PEAK_TFLOPS = 197.0
-_DEF_PEAK_HBM_GBS = 819.0
+# published per-chip peaks keyed by jax's ``device_kind`` (v5e: Google
+# Cloud documentation, "TPU v5e"). A device that is not here is an
+# error, never a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "int8_tops": 393.0,
+                    "hbm_gbs": 819.0},
+}
+# the chip the cost model prices for when no accelerator is attached
+# (the chip-free ledger is a statement about this chip, not the host)
+MODEL_DEVICE_KIND = "TPU v5 lite"
 
 _JIT_SCOPE = re.compile(r"^jit\(([^)]*)\)$")
 
 
+def device_peaks(device_kind):
+    """The peaks row for a ``device_kind``; an unknown kind raises."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise MXNetError(
+            "no published peaks for device kind %r (known: %s); add its "
+            "row to profiling.ledger.DEVICE_PEAKS with its source"
+            % (device_kind, sorted(DEVICE_PEAKS))) from None
+
+
 def _peaks(peak_tflops=None, peak_hbm_gbs=None):
-    if peak_tflops is None:
-        peak_tflops = float(os.environ.get("MXTPU_PEAK_TFLOPS",
-                                           _DEF_PEAK_TFLOPS))
-    if peak_hbm_gbs is None:
-        peak_hbm_gbs = float(os.environ.get("MXTPU_PEAK_HBM_GBS",
-                                            _DEF_PEAK_HBM_GBS))
+    """Explicit peaks win; otherwise the attached TPU's row (an unknown
+    kind raises), or the modelled chip's where no accelerator is
+    attached. Never imports jax itself: the stdlib-side tools price HLO
+    text with no backend at all."""
+    if peak_tflops is None or peak_hbm_gbs is None:
+        kind = MODEL_DEVICE_KIND
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            dev = jax.devices()[0]
+            if dev.platform == "tpu":
+                kind = dev.device_kind
+        row = device_peaks(kind)
+        if peak_tflops is None:
+            peak_tflops = row["bf16_tflops"]
+        if peak_hbm_gbs is None:
+            peak_hbm_gbs = row["hbm_gbs"]
     return peak_tflops, peak_hbm_gbs
 
 
@@ -235,7 +264,7 @@ def mfu_estimate(doc, items_per_step=None, step_s=None):
 
     - ``mfu_at_roofline``: flops_total / (est_s * peak) — the MFU the
       roofline model says this module could reach if every op hit its
-      bound. The honest ceiling a wedged round can still commit.
+      bound: a ceiling that needs no chip run.
     - with ``step_s``: ``mfu_measured`` = flops_total / (step_s * peak).
     - with ``items_per_step``: ``gflops_per_item`` for throughput math.
     """

@@ -121,11 +121,9 @@ _listener_installed = [False]
 def _install_compile_listener():
     if _listener_installed[0]:
         return True
-    try:
-        from jax._src import monitoring as _mon
-        _mon.register_event_duration_secs_listener(_on_event_duration)
-    except Exception:  # noqa: BLE001 — private seam; degrade to
-        return False   # uncounted compiles rather than failed import
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_event_duration)
     _listener_installed[0] = True
     return True
 

@@ -1,0 +1,122 @@
+"""The Pallas kernels of the main path, compiled for a described TPU.
+
+The TPU's compiler is installed without a chip attached: it compiles
+for a ``v5e:2x2`` topology that is described, not present
+(on-chip-measurement guide section 2.3). Interpret mode cannot see what
+Mosaic refuses — the paged kernel passed every interpret-mode test
+while the chip's compiler refused it at every shape — so each kernel is
+compiled here at the widths the chip smoke runs, in compiled mode, and
+must come out as a ``tpu_custom_call``. Nothing runs: these say nothing
+about results or times.
+
+Only the worker that is handed this file loads the TPU library, and
+only once a test has started: the topology is described inside a
+fixture, never at import, and every compile happens in this process.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """shape, dtype -> ShapeDtypeStruct on the described chip, with the
+    persistent compile cache off while this file runs: an entry written
+    for a described device cannot be read back without one, and the
+    next compile would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _is_kernel(lowered):
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("bh,t,d", [(16, 4096, 128), (8, 8192, 128)])
+def test_flash_compiles(chip, bh, t, d):
+    q = chip((bh, t, d), jnp.bfloat16)
+    _is_kernel(pk._flash_call.lower(
+        q, q, q, causal=True, scale=d ** -0.5, block_q=256, block_k=512,
+        interpret=False))
+
+
+# h16 x d128 is the smoke's decoder; h4 x d16 is GenerativeDecoder's
+# default. paged_attention's rule is one line — on the chip every head
+# shape takes the kernel — so the smallest shipped shape compiles too
+@pytest.mark.parametrize("h,d,dtype", [
+    (16, 128, jnp.bfloat16), (16, 128, jnp.float32), (4, 16, jnp.float32)])
+def test_paged_compiles(chip, h, d, dtype):
+    b, bt, blocks, width = 8, 16, 256, 6
+    pool = chip((blocks, bt, h, d), dtype)
+    _is_kernel(pk._paged_call.lower(
+        chip((b, h, d), dtype), pool, pool,
+        chip((b, width), jnp.int32), chip((b,), jnp.int32),
+        scale=d ** -0.5, interpret=False))
+
+
+def test_paged_interpret_parity_at_chip_width():
+    """The rewritten kernel at (h16, d128, bt16) against the gather
+    reference — on the CPU, no topology needed."""
+    rng = np.random.default_rng(0)
+    b, h, d, bt, blocks, width = 3, 16, 128, 16, 12, 4
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((blocks, bt, h, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((blocks, bt, h, d)), jnp.float32)
+    tables = jnp.asarray(rng.integers(0, blocks, (b, width)), jnp.int32)
+    lens = jnp.asarray([1, 17, width * bt], jnp.int32)
+    got = pk.paged_attention(q, k, v, tables, lens, force=True,
+                             interpret=True)
+    want = pk._paged_gather_reference(q, k, v, tables, lens, d ** -0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+_SGD = (("clip", -1.0), ("lr", 0.05), ("momentum", 0.9), ("rescale", 1.0),
+        ("wd", 1e-4))
+_ADAM = (("beta1", 0.9), ("beta2", 0.999), ("clip", -1.0), ("eps", 1e-8),
+         ("lr", 1e-3), ("rescale", 1.0), ("wd", 0.0))
+
+
+# ResNet-50 weights as the dispatcher reshapes them to (rows, 128) f32
+@pytest.mark.parametrize("weight", [(512, 512, 3, 3), (1000, 2048),
+                                    (256, 64, 1, 1)])
+@pytest.mark.parametrize("kind,hyper,n", [("sgd_mom", _SGD, 3),
+                                          ("adam", _ADAM, 4)])
+def test_fused_optimizer_compiles(chip, kind, hyper, n, weight):
+    a = chip((int(np.prod(weight)) // 128, 128), jnp.float32)
+    _is_kernel(pk._fused_opt_call.lower(kind, (a,) * n, hyper, False))
+
+
+# a ResNet-50 bs128 stage-1 accumulator (128*56*56*256 / 128) and the
+# smallest row count the dispatcher tiles
+@pytest.mark.parametrize("rows", [8, 802816])
+def test_int8_epilogue_compiles(chip, rows):
+    _is_kernel(pk._int8_epilogue_call.lower(
+        chip((rows, 128), jnp.int32), chip((), jnp.float32),
+        chip((), jnp.float32), relu=True, interpret=False))

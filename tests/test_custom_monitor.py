@@ -106,3 +106,46 @@ def test_print_summary(capsys):
     assert "fc1" in out and "fc2" in out
     # fc1: 4*8 + 8 = 40; fc2: 8*2 + 2 = 18
     assert total == 58
+
+
+def test_custom_op_callback_stays_on_numpy():
+    """The arrays a CustomOp sees inside the host callback are backed by
+    host NumPy memory and assign() keeps them so: a device array built
+    there is a program started from inside the program that is waiting
+    for the callback — on a small thread pool that never returns (the
+    numpy-ops example used to hang its test for the harness's whole
+    timeout)."""
+    seen = []
+
+    @mx.operator.register("scale_numpy_only")
+    class ScaleProp(mx.operator.CustomOpProp):
+        def list_arguments(self):
+            return ["data"]
+
+        def create_operator(self, ctx, in_shapes, in_dtypes):
+            class Scale(mx.operator.CustomOp):
+                def forward(self, is_train, req, in_data, out_data, aux):
+                    seen.append(("fwd", type(in_data[0]._data),
+                                 type(out_data[0]._data)))
+                    self.assign(out_data[0], req[0],
+                                in_data[0].asnumpy() * 3.0)
+                    seen.append(("assigned", type(out_data[0]._data)))
+
+                def backward(self, req, out_grad, in_data, out_data,
+                             in_grad, aux):
+                    seen.append(("bwd", type(out_grad[0]._data),
+                                 type(in_grad[0]._data)))
+                    self.assign(in_grad[0], req[0],
+                                out_grad[0].asnumpy() * 3.0)
+            return Scale()
+
+    x = nd.array(np.arange(6, dtype=np.float32).reshape(2, 3))
+    x.attach_grad()
+    with autograd.record():
+        y = nd.Custom(x, op_type="scale_numpy_only")
+    y.backward()
+    np.testing.assert_allclose(y.asnumpy(), x.asnumpy() * 3.0)
+    np.testing.assert_allclose(x.grad.asnumpy(), np.full((2, 3), 3.0))
+    assert seen, "callbacks never ran"
+    for entry in seen:
+        assert all(t is np.ndarray for t in entry[1:]), entry

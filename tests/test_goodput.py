@@ -421,7 +421,11 @@ def test_recorder_overhead_bounded():
     Process CPU time, interleaved min-of-N with retries (the
     test_telemetry overhead idiom): noise only ever ADDS time, so min
     estimates the true cost of each mode."""
-    reg = metrics.registry()
+    # a registry of its own: the bound is on the recorder, and a frame
+    # of the process registry also pays for every series and snapshot
+    # collector (the live-array census) that earlier tests in this
+    # worker happened to leave behind
+    reg = metrics.MetricRegistry()
     c = reg.counter("t_gp_overhead_total", "t", labelnames=("k",))
     h = reg.histogram("t_gp_overhead_seconds", "t")
 
@@ -436,13 +440,13 @@ def test_recorder_overhead_bounded():
                 tl.tick()
         return time.process_time() - t0
 
-    workload(Timeline(window=8))     # warm both paths
+    workload(Timeline(window=8, registry=reg))     # warm both paths
     workload(None)
     best = None
     for _ in range(4):
         on, off = [], []
         for _ in range(4):
-            on.append(workload(Timeline(window=8)))
+            on.append(workload(Timeline(window=8, registry=reg)))
             off.append(workload(None))
         ratio = min(on) / min(off)
         best = ratio if best is None else min(best, ratio)

@@ -175,14 +175,19 @@ def test_worker_crash_respawn_bit_identical(rec_path):
         clean = _drain(p)
     finally:
         p.close()
+    # two ring slots and a kill after the first batch: worker 0 owns
+    # batches 0, 2, 4, 6 and cannot have started 6 before 2 is handed
+    # over, so it is certainly still needed when it dies (with more
+    # slots or a later kill it may have finished its shard already, and
+    # then nothing respawns)
     p = ShardedRecordPipeline(rec_path, (3, CROP, CROP), BATCH,
-                              num_workers=2, shuffle=True, seed=5)
+                              num_workers=2, shuffle=True, seed=5,
+                              ring_batches=2)
     try:
         got = []
-        for _ in range(3):
-            b = p.next()
-            got.append((b.data[0].asnumpy().copy(),
-                        b.label[0].asnumpy().copy()))
+        b = p.next()
+        got.append((b.data[0].asnumpy().copy(),
+                    b.label[0].asnumpy().copy()))
         p._workers[0].proc.kill()
         while True:
             try:

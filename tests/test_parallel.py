@@ -362,7 +362,8 @@ def test_zero2_zero3_hlo_collectives():
     assert scattered, "stage-2 grads were never scattered to shards"
     # the constraint itself must be IN the lowered program (stage 2 pins
     # the gradient sharding; stage 1 pins none)
-    assert "Sharding" in low_2, "grad sharding constraint disappeared"
+    assert "sdy.sharding_constraint" in low_2, \
+        "grad sharding constraint disappeared"
 
     # stage 3: parameters live sharded (1/dp at rest), gathered on use
     step_3, p_3, s_3 = make_zero_train_step(

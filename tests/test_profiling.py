@@ -413,38 +413,59 @@ def test_mfu_report_capture_cli_resnet_train(tmp_path):
 
 
 # -------------------------------------------------------------- perf_gate
-def test_perf_gate_committed_artifacts():
+# the shapes of a bench artifact, with made-up round numbers: no bench
+# measurement is committed (the driver's ledger is the record)
+_BENCH_GOOD = {"metric": "resnet50_inference_bf16_bs128", "value": 1000.0,
+               "unit": "img/s/chip", "vs_baseline": 0.25, "mfu_bf16": 0.25}
+_BENCH_BARE_ZERO = {"n": 5, "rc": 124, "parsed": {
+    "metric": "resnet50_inference_bf16_bs128", "value": 0.0,
+    "unit": "img/s/chip", "vs_baseline": 0.0,
+    "error": "backend init failed"}}
+
+
+def _bench_good(tmp_path):
+    p = tmp_path / "good.json"
+    p.write_text(json.dumps(_BENCH_GOOD))
+    return str(p)
+
+
+def test_perf_gate_bench_artifact_shapes(tmp_path):
     sys.path.insert(0, TOOLS)
     import perf_gate
 
-    # the committed last-good artifact gates against itself: PASS
-    assert perf_gate.main([os.path.join(
-        REPO, "docs", "artifacts", "BENCH_LAST_GOOD.json")]) == 0
-    # BENCH_r05 is the bare-zero shape this PR abolishes: rejected
-    assert perf_gate.main([os.path.join(REPO, "BENCH_r05.json")]) == 3
+    good = _bench_good(tmp_path)
+    # a reference gates against itself: PASS
+    assert perf_gate.main([good, "--last-good", good]) == 0
+    # a driver round file around value 0.0 with no diag and no
+    # cost_ledger is the bare-zero shape: rejected
+    bare = tmp_path / "bare.json"
+    bare.write_text(json.dumps(_BENCH_BARE_ZERO))
+    assert perf_gate.main([str(bare), "--last-good", good]) == 3
+    # a bench artifact has no committed reference to fall back to
+    assert perf_gate.main([good]) == 2
 
 
 def test_perf_gate_regression_and_tolerance(tmp_path):
     sys.path.insert(0, TOOLS)
     import perf_gate
 
-    good = perf_gate.load_artifact(os.path.join(
-        REPO, "docs", "artifacts", "BENCH_LAST_GOOD.json"))
-    cand = dict(good)
-    cand["value"] = good["value"] * 0.5
-    cand.pop("stale", None)
+    ref = _bench_good(tmp_path)
+    cand = dict(_BENCH_GOOD)
+    cand["value"] = _BENCH_GOOD["value"] * 0.5
     p = tmp_path / "cand.json"
     p.write_text(json.dumps(cand))
-    assert perf_gate.main([str(p)]) == 1
+    assert perf_gate.main([str(p), "--last-good", ref]) == 1
     # a generous headline tolerance turns the same artifact green
-    assert perf_gate.main([str(p), "--tolerance", "0.6"]) == 0
+    assert perf_gate.main([str(p), "--last-good", ref,
+                           "--tolerance", "0.6"]) == 0
     # per-metric regression still caught under a loose default
-    cand2 = dict(good)
-    cand2["mfu_bf16"] = good.get("mfu_bf16", 0.25) * 0.1
+    cand2 = dict(_BENCH_GOOD)
+    cand2["mfu_bf16"] = _BENCH_GOOD["mfu_bf16"] * 0.1
     p2 = tmp_path / "cand2.json"
     p2.write_text(json.dumps(cand2))
-    assert perf_gate.main([str(p2)]) == 1
-    assert perf_gate.main([str(p2), "--tol", "mfu_bf16=0.95"]) == 0
+    assert perf_gate.main([str(p2), "--last-good", ref]) == 1
+    assert perf_gate.main([str(p2), "--last-good", ref,
+                           "--tol", "mfu_bf16=0.95"]) == 0
 
 
 def test_perf_gate_diagnosed_zero_is_not_bare(tmp_path):
@@ -455,46 +476,31 @@ def test_perf_gate_diagnosed_zero_is_not_bare(tmp_path):
             "error": "wedged", "cost_ledger": {"stages": {}}}
     p = tmp_path / "zero.json"
     p.write_text(json.dumps(zero))
-    assert perf_gate.main([str(p)]) == 1  # failed, but not signal-free
+    # failed, but not signal-free
+    assert perf_gate.main([str(p), "--last-good",
+                           _bench_good(tmp_path)]) == 1
 
 
 # ---------------------------------------------------- bench cost ledger
 def test_bench_failure_artifact_embeds_cost_ledger(
         tmp_path, monkeypatch, capsys):
-    """Acceptance: the bench harness in failure-injection mode (every
-    probe wedged, no last-good tier) still emits a failure line whose
-    cost_ledger carries the CPU cost-model MFU estimate and top-10."""
+    """Acceptance: a bench failure line carries the cost_ledger the
+    CPU-pinned child computed — the cost-model MFU estimate and
+    top-10."""
     import bench
 
-    monkeypatch.setattr(bench, "_LAST_GOOD",
-                        str(tmp_path / "absent.json"))
-    monkeypatch.setattr(bench, "_LAST_GOOD_FALLBACK",
-                        str(tmp_path / "absent2.json"))
     monkeypatch.setattr(bench, "_LEDGER_PATH",
                         str(tmp_path / "ledger.json"))
-    monkeypatch.setattr(bench, "_probe_backend", lambda **k: False)
-    monkeypatch.setenv("MXTPU_BENCH_BUDGET", "500")
     # conftest defaults the attribution pass OFF for the suite (a real
     # ledger subprocess costs minutes); this test is the one that
     # proves the wiring, so it opts back in on the fast tiny stage
     monkeypatch.setenv("MXTPU_PROFILE_ATTRIB", "1")
     monkeypatch.setenv("MXTPU_LEDGER_STAGES", "tiny")
-    monkeypatch.setenv("MXTPU_LEDGER_DEADLINE_SEC", "180")
-    # fake clock: the supervise loop burns its fake budget in
-    # milliseconds; the ledger subprocess runs in real time and
-    # _ledger_finish joins it before the final line
-    t = [0.0]
-
-    def mono():
-        t[0] += 1.0
-        return t[0]
-
-    monkeypatch.setattr(bench.time, "monotonic", mono)
-    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-    rc = bench.supervise()
-    out = capsys.readouterr().out
-    assert rc == 1
-    line = bench._json_line(out.encode())
+    assert bench._ledger_start() is not None
+    bench._ledger_finish(wait_s=180)
+    bench._fail_json("section failed")
+    line = [ln for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("{")][-1]
     parsed = json.loads(line)
     assert parsed["value"] == 0.0 and "error" in parsed
     led = parsed.get("cost_ledger")
@@ -606,7 +612,7 @@ def test_new_env_vars_registered():
     from mxnet_tpu import libinfo
 
     new = ("MXTPU_PROFILE_ATTRIB", "MXTPU_PROFILE_DIR",
-           "MXTPU_PEAK_HBM_GBS", "MXTPU_BENCH_BATCH",
+           "MXTPU_BENCH_BATCH",
            "MXTPU_LEDGER_OUT", "MXTPU_LEDGER_STAGES",
            "MXTPU_LEDGER_DEADLINE_SEC")
     for name in new:

@@ -21,16 +21,15 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
-PEAK_TFLOPS = float(os.environ.get("MXTPU_PEAK_TFLOPS", "197"))
 # single source of truth for the per-image FLOP estimate (bench.py:32)
 from bench import RESNET50_GFLOPS  # noqa: E402
 
 
-def _sync_factory():
+def _peak_tflops():
+    """bf16 peak of the attached chip; an unknown device kind raises."""
     import jax
-    import jax.numpy as jnp
-    reduce_fn = jax.jit(lambda t: jnp.sum(t.astype(jnp.float32)))
-    return lambda out: float(reduce_fn(out))
+    from mxnet_tpu.profiling.ledger import device_peaks
+    return device_peaks(jax.devices()[0].device_kind)["bf16_tflops"]
 
 
 def timeit(fn, args, sync, iters=30, warmup=3):
@@ -57,7 +56,7 @@ def probe_matmul(sync):
     dt = timeit(f, (a, a), sync)
     tf = 2 * n ** 3 / dt / 1e12
     print("matmul %dx%d bf16: %.1f TFLOP/s (%.2f of peak)"
-          % (n, n, tf, tf / PEAK_TFLOPS))
+          % (n, n, tf, tf / _peak_tflops()))
     return tf
 
 
@@ -75,7 +74,7 @@ def probe_conv(sync, batch=128):
     fl = 2 * batch * 28 * 28 * 128 * 128 * 9
     tf = fl / dt / 1e12
     print("conv3x3 28x28x128 bs%d: %.1f TFLOP/s (%.2f of peak)"
-          % (batch, tf, tf / PEAK_TFLOPS))
+          % (batch, tf, tf / _peak_tflops()))
     return tf
 
 
@@ -156,7 +155,7 @@ def probe_pure(sync, batch):
     x = jnp.ones((batch, 224, 224, 3), jnp.bfloat16)
     dt = timeit(f, (pvals, x), sync, iters=20)
     ips = batch / dt
-    mfu = ips * RESNET50_GFLOPS / (PEAK_TFLOPS * 1e3)
+    mfu = ips * RESNET50_GFLOPS / (_peak_tflops() * 1e3)
     print("pure-jax resnet50 NHWC bs%d: %.0f img/s mfu %.3f"
           % (batch, ips, mfu))
     return ips, mfu
@@ -173,7 +172,7 @@ def probe_framework(sync, batch, layout="NHWC", fuse=True):
     x = jnp.ones((batch, 3, 224, 224), jnp.bfloat16)
     dt = timeit(f, (pvals, x), sync, iters=20)
     ips = batch / dt
-    mfu = ips * RESNET50_GFLOPS / (PEAK_TFLOPS * 1e3)
+    mfu = ips * RESNET50_GFLOPS / (_peak_tflops() * 1e3)
     print("framework resnet50 %s fuse=%s bs%d: %.0f img/s mfu %.3f"
           % (layout, fuse, batch, ips, mfu))
     return ips, mfu
@@ -188,18 +187,15 @@ def main():
                                    "(machine-readable artifact)")
     args = ap.parse_args()
 
-    os.environ.setdefault("MXTPU_COMPILE_CACHE", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".xla_cache"))
     import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ["MXTPU_COMPILE_CACHE"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+    import mxnet_tpu as mx
+    mx.util.enable_compile_cache()
     print("devices:", jax.devices())
-    sync = _sync_factory()
+    sync = jax.block_until_ready
 
     results = {"backend": jax.default_backend(),
-               "peak_tflops": PEAK_TFLOPS, "batch": args.batch}
+               "peak_tflops": _peak_tflops(), "batch": args.batch}
     results["matmul_tflops"] = round(probe_matmul(sync), 2)
     results["conv_tflops_bs%d" % args.batch] = round(
         probe_conv(sync, args.batch), 2)

@@ -104,8 +104,9 @@ exists the kernel/fallback speedup must hold ``--kernels-min-ratio``
 (a compiled kernel that LOSES to its fallback is a regression; a CPU
 artifact records ``null`` and the ratio gate notes it).
 
-Compares a bench artifact against the committed last-good measurement
-(``docs/artifacts/BENCH_LAST_GOOD.json`` unless ``--last-good``) with
+Compares a bench artifact against a reference artifact given with
+``--last-good`` (no bench measurement is committed: the driver's
+``PERF_LEDGER.jsonl`` is the record of what ran on the chip) with
 per-metric tolerances. The artifact may be any of the shapes the
 bench pipeline produces: a driver round file ({"parsed": {...}}), a
 raw result line (dict), or a last-good wrapper ({"line": "..."}).
@@ -114,16 +115,13 @@ live bytes embedded by the cost-ledger pass (growth beyond
 ``--mem-tol`` is the regression — direction inverted vs throughput).
 
 Exit codes:
-  0  within tolerance (stale artifacts pass with a warning — the
-     driver already knows the round was wedged, and the stale line
-     repeats a measurement that DID pass),
+  0  within tolerance,
   1  regression: headline or a compared metric fell more than its
      tolerance below last-good, or a zero-value artifact that at
      least carries diagnostics,
   2  usage / unreadable artifact,
   3  bare-zero: value 0.0 with NO diag and NO cost_ledger — the
-     signal-free artifact shape PR 6 exists to abolish (BENCH_r04/r05
-     shipped exactly this).
+     signal-free artifact shape PR 6 exists to abolish.
 
 Stdlib only; wired as a tier-1 test over the committed artifacts
 (tests/test_profiling.py), so the gate itself cannot rot.
@@ -136,8 +134,9 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_LAST_GOOD = os.path.join(REPO, "docs", "artifacts",
-                                 "BENCH_LAST_GOOD.json")
+# --last-good not given: each artifact mode then falls back to its own
+# committed reference; the bench mode has none and asks for one
+DEFAULT_LAST_GOOD = None
 DEFAULT_IO_LAST_GOOD = os.path.join(REPO, "docs", "artifacts",
                                     "IO_LAST_GOOD.json")
 DEFAULT_SERVING_LAST_GOOD = os.path.join(REPO, "docs", "artifacts",
@@ -315,10 +314,6 @@ def gate(candidate, last_good, tolerance=0.25, per_metric=None,
         return 1, ["zero-value artifact (diagnosed: %s)"
                    % ("error=" + str(candidate.get("error"))[:120]
                       if candidate.get("error") else "see diag")]
-    if candidate.get("stale"):
-        msgs.append("warning: stale artifact (reason: %s) — gating "
-                    "the repeated last-good value"
-                    % str(candidate.get("stale_reason"))[:120])
     rc = 0
     good_value = float(last_good.get("value") or 0.0)
     tol = per_metric.get("value", per_metric.get(
@@ -1488,8 +1483,9 @@ def main(argv=None):
                                  description=__doc__.splitlines()[0])
     ap.add_argument("artifact", help="bench artifact JSON to gate")
     ap.add_argument("--last-good", default=DEFAULT_LAST_GOOD,
-                    help="reference artifact (default: committed "
-                         "docs/artifacts/BENCH_LAST_GOOD.json)")
+                    help="reference artifact (default: the mode's "
+                         "committed docs/artifacts/*_LAST_GOOD.json; "
+                         "required for a bench artifact)")
     ap.add_argument("--tolerance", type=float, default=0.25,
                     help="default allowed fractional drop (0.25)")
     ap.add_argument("--tol", action="append", default=[],
@@ -1736,6 +1732,10 @@ def main(argv=None):
     except (OSError, ValueError) as e:
         print("perf_gate: cannot read artifact %s: %s"
               % (args.artifact, e), file=sys.stderr)
+        return 2
+    if args.last_good is None:
+        print("perf_gate: a bench artifact needs --last-good (no bench "
+              "measurement is committed)", file=sys.stderr)
         return 2
     try:
         last_good = load_artifact(args.last_good)
