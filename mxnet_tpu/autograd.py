@@ -16,6 +16,7 @@ import weakref
 import jax
 import jax.numpy as jnp
 
+from . import tracing as _tracing
 from .base import MXNetError
 
 _state = threading.local()
@@ -278,8 +279,12 @@ def _compute_gradients(heads, head_grads, create_graph=False):
         ]
 
     def gradfn(*lv):
-        _, vjp_fn = jax.vjp(f, *lv)
-        return vjp_fn(tuple(hg))
+        # the replay is traced anew by every call: the span holds that
+        # re-trace and the dispatch of the linearised forward
+        with _tracing.span("autograd.vjp"):
+            _, vjp_fn = jax.vjp(f, *lv)
+        with _tracing.span("autograd.pullback"):
+            return vjp_fn(tuple(hg))
 
     grads = gradfn(*leaf_vals)
     grad_nds = [NDArray(g) for g in grads]
@@ -297,18 +302,19 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     their .grad per grad_req (ref: MXAutogradBackwardEx)."""
     from .ndarray.ndarray import NDArray
 
-    grad_leaves, grads = _compute_gradients(heads, head_grads)
-    for e, g in zip(grad_leaves, grads):
-        nd = e.nd_ref()
-        if nd._grad_req == "add" and nd.grad is not None:
-            nd.grad._data = nd.grad._data + g._data
-        else:
-            if nd.grad is None:
-                nd.grad = NDArray(g._data)
+    with _tracing.span("autograd.backward"):
+        grad_leaves, grads = _compute_gradients(heads, head_grads)
+        for e, g in zip(grad_leaves, grads):
+            nd = e.nd_ref()
+            if nd._grad_req == "add" and nd.grad is not None:
+                nd.grad._data = nd.grad._data + g._data
             else:
-                nd.grad._data = g._data
-    if not retain_graph:
-        _st().tape.clear()
+                if nd.grad is None:
+                    nd.grad = NDArray(g._data)
+                else:
+                    nd.grad._data = g._data
+        if not retain_graph:
+            _st().tape.clear()
 
 
 def grad(heads, variables, head_grads=None, retain_graph=None,
@@ -323,8 +329,9 @@ def grad(heads, variables, head_grads=None, retain_graph=None,
         if v._grad_req == "null":
             v._grad_req = "write"
     try:
-        grad_leaves, grads = _compute_gradients(
-            heads, head_grads, create_graph=create_graph)
+        with _tracing.span("autograd.backward"):
+            grad_leaves, grads = _compute_gradients(
+                heads, head_grads, create_graph=create_graph)
     finally:
         for v, req in prev_reqs:
             v._grad_req = req

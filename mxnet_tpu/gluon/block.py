@@ -20,6 +20,7 @@ import jax
 from .. import autograd
 from .. import ndarray as nd_mod
 from .. import random as _random
+from .. import tracing as _tracing
 from ..base import MXNetError
 from ..ndarray import NDArray
 from .parameter import (DeferredInitializationError, Parameter, ParameterDict)
@@ -289,6 +290,8 @@ class HybridBlock(Block):
 
     def __init__(self, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        # what a capture calls this block's programs (_named)
+        self._program_alias = prefix.rstrip("_") if prefix else self._alias()
         self._active = False
         self._cached_jit = {}
         self._cached_plist = None
@@ -342,7 +345,12 @@ class HybridBlock(Block):
             params = {n: p.var() for n, p in self._reg_params.items()}
             return self.hybrid_forward(sym_mod, x, *args, **params)
         if self._active and not getattr(_in_trace, "value", False):
-            return self._call_cached_op(x, *args)
+            # one span per outermost hybridized call (children are
+            # reached only while tracing, through hybrid_forward):
+            # parameter walk, signature, cache lookup, dispatch, tape
+            # record, aux write-back
+            with _tracing.span("block.call"):
+                return self._call_cached_op(x, *args)
         params = self._collect_param_values(x, *args)
         return self.hybrid_forward(nd_mod, x, *args, **params)
 
@@ -477,7 +485,20 @@ class HybridBlock(Block):
             res_list = res if isinstance(res, list) else [res]
             return [r._data for r in res_list], []
 
-        return jax.jit(pure_fn), [out_spec], [[]]
+        return jax.jit(self._named(pure_fn, False)), [out_spec], [[]]
+
+    def _named(self, pure_fn, training):
+        """Name the traced program after the block and the mode, so a
+        capture's ``XLA Modules`` line reads ``jit_mx_<block>_train`` /
+        ``_eval`` and not ``jit_pure_fn``. ``<block>`` is the prefix the
+        user gave this block, else its class: never gluon's numbered
+        name, which counts the blocks built before it in the process.
+        The persistent compilation cache holds the module's name in its
+        key, so a name that moved with the count would compile cold."""
+        pure_fn.__name__ = pure_fn.__qualname__ = "mx_%s_%s" % (
+            re.sub(r"\W", "_", self._program_alias),
+            "train" if training else "eval")
+        return pure_fn
 
     def _build_cached(self, plist, in_spec, training):
         """Trace the whole subtree once into a jitted pure function."""
@@ -526,7 +547,8 @@ class HybridBlock(Block):
             return ([o._data for o in flat_out],
                     [v._data for _, v in aux])
 
-        return jax.jit(pure_fn), out_spec_box, aux_params_box
+        return (jax.jit(self._named(pure_fn, training)), out_spec_box,
+                aux_params_box)
 
     def export(self, path, epoch=0):
         """Export to symbol JSON + params (ref: block.py export).

@@ -111,7 +111,8 @@ class Trainer:
             # grad-norm / nonfinite attrs land on the span so
             # trace_merge can name the rank that went unhealthy; a
             # MXTPU_HEALTH=raise trip surfaces here, at the boundary
-            _health.step_boundary("trainer", span=sp)
+            with _tracing.span("trainer.health"):
+                _health.step_boundary("trainer", span=sp)
         # one boundary per optimizer step: charges the data/comm/compile
         # time accumulated since the previous step to this one
         # (telemetry/step.py; wall-clock only, no host sync). Manual
@@ -160,7 +161,7 @@ class Trainer:
         # dispatch covering the sentry counts AND the norm telemetry;
         # the per-call Updater check is suppressed underneath it.
         probe = _health.step_probe()
-        with _health.updater_covered():
+        with _tracing.span("trainer.update"), _health.updater_covered():
             for i, p in enumerate(self._params):
                 if p.grad_req == "null":
                     continue
@@ -186,7 +187,10 @@ class Trainer:
                     probe.add(p.name, p.data(), p.grad(),
                               weight_before=old)
         if probe is not None:
-            probe.commit()
+            # what the default-on health plane adds to the step: one
+            # more jitted program over every (weight, gradient) pair
+            with _tracing.span("trainer.health"):
+                probe.commit()
 
     def save_states(self, fname):
         """Optimizer state checkpoint (ref: trainer.py save_states). When the

@@ -36,8 +36,6 @@ tier-1 bitwise-greedy contract.
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
 from ...base import MXNetError
@@ -232,14 +230,24 @@ class CompiledDecodeSteps:
                 lambda a: _mem.tag_role(jax.device_put(a, device),
                                         "parameter"),
                 decoder.param_tree())
+        sizes = dict(num_heads=decoder.num_heads,
+                     block_tokens=pool.block_tokens)
+
+        # a functools.partial has no __name__ and jits as
+        # ``jit__unknown``; named functions make the two programs
+        # readable on a capture's ``XLA Modules`` line
+        def mx_prefill(params, k_cache, v_cache, tokens, n_valid, blocks):
+            return _prefill_impl(params, k_cache, v_cache, tokens, n_valid,
+                                 blocks, **sizes)
+
+        def mx_decode(params, k_cache, v_cache, tokens, positions, tables):
+            return _decode_impl(params, k_cache, v_cache, tokens, positions,
+                                tables, **sizes)
+
         self._prefill = jax.jit(
-            functools.partial(_prefill_impl, num_heads=decoder.num_heads,
-                              block_tokens=pool.block_tokens),
-            donate_argnums=(1, 2) if donate else ())
+            mx_prefill, donate_argnums=(1, 2) if donate else ())
         self._decode = jax.jit(
-            functools.partial(_decode_impl, num_heads=decoder.num_heads,
-                              block_tokens=pool.block_tokens),
-            donate_argnums=(1, 2) if donate else ())
+            mx_decode, donate_argnums=(1, 2) if donate else ())
 
     def prefill(self, tokens, n_valid, blocks):
         """Run one request's padded prompt; the pool adopts the

@@ -92,10 +92,6 @@ _met = _tm.lazy_metrics(lambda reg: {
     "depth": reg.gauge(
         "mx_serving_queue_depth",
         "requests pending in the model queue", labelnames=("model",)),
-    "batch_rows": reg.histogram(
-        "mx_serving_generate_batch_rows",
-        "running requests per decode step", labelnames=("model",),
-        buckets=(1, 2, 4, 8, 16, 32, 64)),
     # phase = steady | recover: the autoscaler (and anyone reading
     # latency SLOs) can see a failover stall for what it is instead
     # of mistaking it for steady-state degradation
@@ -579,7 +575,6 @@ class GenLane:
         t1 = clock.now_ns()
         met["steps"].labels(model=m.name, phase="decode").inc()
         met["tokens"].labels(model=m.name, phase="decode").inc(len(live))
-        met["batch_rows"].labels(model=m.name).observe(len(live))
         self._observe_pool()
         finished = []
         for i, req in enumerate(live):

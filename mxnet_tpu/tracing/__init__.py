@@ -32,6 +32,7 @@ from __future__ import annotations
 import contextvars
 import os
 import random as _random_mod
+import sys
 import threading
 
 from ..base import get_env
@@ -147,13 +148,38 @@ def rings():
 
 
 # -- spans -------------------------------------------------------------------
+# jax.profiler.TraceAnnotation, looked up once jax has been imported by
+# someone else: this package never imports it (the kvstore server
+# process runs without JAX, and there a span stays ring-only)
+_annotation_cls = None
+
+
+def _profiler_annotation(name):
+    """The span's twin on the profiler's timeline, or None without JAX.
+    With no capture running it is an object and one flag test."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return None
+        cls = _annotation_cls = profiler.TraceAnnotation
+    return cls(name)
+
+
 class Span:
     """One in-flight interval. Use via ``with span(...)``; reading
     ``trace_id``/``span_id`` while open is how the kvstore worker puts
-    the context on the wire."""
+    the context on the wire. While it is open the same interval stands
+    under the same name as a TraceMe on the host plane of a
+    ``jax.profiler`` capture, beside ``PjitFunction(...)``: the ring
+    keeps it on ``CLOCK_MONOTONIC`` with its parent link, the capture on
+    the profiler's clock with the device's operations."""
 
     __slots__ = ("name", "cat", "attrs", "trace_id", "span_id",
-                 "parent_id", "start_ns", "_token", "_ring_ref")
+                 "parent_id", "start_ns", "_token", "_ring_ref",
+                 "_mirror")
 
     def __init__(self, name, cat, attrs, trace_id, parent_id):
         self.name = name
@@ -165,6 +191,7 @@ class Span:
         self.start_ns = 0
         self._token = None
         self._ring_ref = None
+        self._mirror = None
 
     def set_attr(self, key, value):
         self.attrs[key] = value
@@ -175,9 +202,14 @@ class Span:
         r = self._ring_ref = _ring()
         r.open.append(self)
         _touch()
+        mirror = self._mirror = _profiler_annotation(self.name)
+        if mirror is not None:
+            mirror.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         end_ns = clock.now_ns()
         _ctx.reset(self._token)
         r = self._ring_ref

@@ -80,6 +80,32 @@ def test_paged_compiles(chip, h, d, dtype):
         scale=d ** -0.5, interpret=False))
 
 
+def test_paged_kernel_keeps_its_name_inside_a_decode_program(chip):
+    """``benchmark/layer_metrics/paged_attn_roofline_pct.py`` finds the
+    kernel's device events by the instruction name the jitted wrapper
+    gives them. An outer jit that slices a layer out of the pool and
+    calls ``paged_attention`` as ``_decode_impl`` does still names every
+    ``tpu_custom_call`` ``_paged_call.N``."""
+    h, d, b, bt, blocks, width, layers = 16, 128, 8, 16, 256, 6, 2
+
+    def mx_decode(q, k_cache, v_cache, tables, lens):
+        for li in range(layers):
+            q = pk.paged_attention(q, k_cache[li], v_cache[li], tables,
+                                   lens, interpret=False)
+        return q
+
+    pool = chip((layers, blocks, bt, h, d), jnp.bfloat16)
+    text = jax.jit(mx_decode).lower(
+        chip((b, h, d), jnp.bfloat16), pool, pool,
+        chip((b, width), jnp.int32), chip((b,), jnp.int32)
+    ).compile().as_text()
+    assert text.startswith("HloModule jit_mx_decode")
+    kernels = [line.split(" = ")[0].split("%")[-1]
+               for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(kernels) == layers
+    assert all(k.startswith("_paged_call") for k in kernels), kernels
+
+
 def test_paged_interpret_parity_at_chip_width():
     """The rewritten kernel at (h16, d128, bt16) against the gather
     reference — on the CPU, no topology needed."""
