@@ -156,12 +156,14 @@ class Trainer:
         from ..profiling import health as _health
         # one probe per step: the post-allreduce gradients, updated
         # weights and (for update-to-weight ratios) the pre-update
-        # weights — updates are functional, so the old array stays
-        # reachable with no copy. commit() is ONE cached jitted
-        # dispatch covering the sentry counts AND the norm telemetry;
-        # the per-call Updater check is suppressed underneath it.
+        # weights — updates are functional and donate nothing, so the
+        # old array stays reachable with no copy. commit() is ONE cached
+        # jitted dispatch covering the sentry counts AND the norm
+        # telemetry; the per-index Updater check is suppressed
+        # underneath it.
         probe = _health.step_probe()
         with _tracing.span("trainer.update"), _health.updater_covered():
+            live = []
             for i, p in enumerate(self._params):
                 if p.grad_req == "null":
                     continue
@@ -171,21 +173,24 @@ class Trainer:
                             f"parameter {p.name} not initialized "
                             "before step()")
                     continue
-                # pre-update weights only when the probe computes
-                # update ratios: with MXTPU_HEALTH_NORMS=0 holding
-                # them would pin a superseded copy of every weight
-                # through the loop for nothing
-                old = p.data()._data if probe is not None \
-                    and probe.wants_norms else None
-                if self._kvstore is not None and \
-                        self._update_on_kvstore:
+                live.append((i, p))
+            # pre-update weights only when the probe computes update
+            # ratios: with MXTPU_HEALTH_NORMS=0 holding them would pin
+            # a superseded copy of every weight for nothing
+            olds = [p.data()._data for _, p in live] \
+                if probe is not None and probe.wants_norms else None
+            if self._kvstore is not None and self._update_on_kvstore:
+                for i, p in live:
                     self._kvstore.push(i, p.grad())
                     self._kvstore.pull(i, p.data())
-                else:
-                    self._updaters(i, p.grad(), p.data())
-                if probe is not None:
-                    probe.add(p.name, p.data(), p.grad(),
-                              weight_before=old)
+            else:
+                # the whole tree in one optimizer program; what cannot
+                # fuse goes per index inside (Updater.update_tree)
+                self._updaters.update_tree(
+                    [(i, p.grad(), p.data()) for i, p in live])
+            if probe is not None:
+                for (_, p), old in zip(live, olds or [None] * len(live)):
+                    probe.add(p.name, p.data(), p.grad(), weight_before=old)
         if probe is not None:
             # what the default-on health plane adds to the step: one
             # more jitted program over every (weight, gradient) pair

@@ -1,13 +1,22 @@
 """Optimizers (ref: python/mxnet/optimizer/optimizer.py).
 
-Each optimizer's update rule is a pure jitted function over jax arrays (the
-reference implements them as fused mshadow kernels, src/operator/optimizer_op.cc
-— here XLA fuses the update chain into one kernel per parameter). The
-Optimizer/Updater API surface (registry, lr/wd multipliers, multi-precision
-fp32 master weights, num_update-driven schedules) matches the reference.
+Each optimizer is two parts. The *host part* (``_step_scalars``) does the
+per-index bookkeeping — update counts, the scheduled and multiplied lr and
+wd, the step-count terms — and returns a short tuple of Python floats. The
+*kernel* (``_kernel``) is a pure function ``(w, g, state, scalars) ->
+(w, state)`` over jax arrays (the reference implements them as fused mshadow
+kernels, src/operator/optimizer_op.cc). One jitted program maps the kernel
+over however many leaves it is handed — one for ``Optimizer.update``, the
+whole parameter tree for ``Updater.update_tree`` — and takes every scalar
+that can change between steps in ONE float32 array, so a step is one
+transfer and one dispatch whatever the lr schedule or the batch size does.
+The Optimizer/Updater API surface (registry, lr/wd multipliers,
+multi-precision fp32 master weights, num_update-driven schedules) matches
+the reference.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -32,7 +41,79 @@ def create(name, **kwargs):
     return _reg.get(name)(**kwargs)
 
 
+def _arrays(state):
+    """The jax arrays of an optimizer state, in the state's own nesting."""
+    if state is None:
+        return None
+    if isinstance(state, (tuple, list)):
+        return tuple(_arrays(s) for s in state)
+    return state._data
+
+
+def _store(state, new):
+    """Write a kernel's new state arrays into the NDArray wrappers."""
+    if state is None:
+        return
+    if isinstance(state, (tuple, list)):
+        for s, n in zip(state, new):
+            _store(s, n)
+    else:
+        state._data = new
+
+
+def _leaf_scalars(w, s):
+    # a Python float meets an array in the array's precision; the packed
+    # row is float32, so hand a half-precision leaf its scalars in kind
+    return s.astype(w.dtype) if jnp.issubdtype(w.dtype, jnp.floating) else s
+
+
+@functools.lru_cache(maxsize=64)
+def _step_program(cls, fields, name, rows=False):
+    """The jitted program of one optimizer configuration: ``cls``'s kernel
+    with ``fields`` (its ``_kernel_fields`` and their values) as trace-time
+    constants, mapped over the leaves it is called with. ``jax.jit``
+    re-specialises on the leaves' shapes and the tree's structure itself;
+    nothing else about a step reaches the trace, so two optimizers of one
+    configuration share the executable. ``rows`` gives the single-leaf
+    program of the row-sparse kernel instead."""
+    opt = object.__new__(cls)
+    vars(opt).update(fields)    # a kernel reading any other field fails loudly
+
+    def leaf(w, g, state, s):
+        if opt.multi_precision and isinstance(state, tuple) and state \
+                and isinstance(state[0], jax.Array) \
+                and state[0].dtype == jnp.float32 \
+                and w.dtype != jnp.float32:
+            # fp32 master weights (update_multi_precision, in one program)
+            master, inner = state
+            master, inner = opt._kernel(master, g.astype(jnp.float32),
+                                        inner, s)
+            return master.astype(w.dtype), (master, inner)
+        return opt._kernel(w, g, state, _leaf_scalars(w, s))
+
+    def tree(ws, gs, states, scalars):
+        return [leaf(w, g, st, scalars[i])
+                for i, (w, g, st) in enumerate(zip(ws, gs, states))]
+
+    def row_leaf(w, g, idx, state, s):
+        return opt._kernel_rows(w, g, idx, state, _leaf_scalars(w, s))
+
+    fn = row_leaf if rows else tree
+    # the program runs as jit_<name>(<fingerprint>)
+    fn.__name__ = name + "_rows" if rows else name
+    return jax.jit(fn)
+
+
 class Optimizer:
+    # the fields a kernel may read: constants of the optimizer, closed over
+    # when its program is traced (and part of the program's cache key)
+    _kernel_fields = ("clip_gradient", "multi_precision")
+    # row-sparse gradients touch only their rows, where the optimizer has
+    # a row kernel; without one (or with lazy_update off) they are
+    # densified
+    lazy_update = True
+    _kernel_rows = None
+
     def __init__(self, rescale_grad=1.0, param_idx2name=None, wd=0.0,
                  clip_gradient=None, learning_rate=0.01, lr_scheduler=None,
                  sym=None, begin_num_update=0, multi_precision=False,
@@ -112,8 +193,62 @@ class Optimizer:
             return (master, self.create_state(index, master))
         return self.create_state(index, weight)
 
-    def update(self, index, weight, grad, state):
+    # -- the step: host part, kernel, program ------------------------------
+    def _step_scalars(self, index):
+        """Host part of one index's step: count it, then everything the
+        kernel needs that can change from step to step, as Python floats
+        (computed in double, as the reference's Python does). Subclasses
+        extend or replace the tuple; its layout is theirs and their
+        kernel's alone."""
+        self._update_count(index)
+        return (self._get_lr(index), self._get_wd(index), self.rescale_grad)
+
+    def _kernel(self, w, g, state, s):
+        """Pure update of one leaf: jax arrays in, ``(w, state)`` out;
+        ``s`` is the leaf's row of ``_step_scalars``."""
         raise NotImplementedError
+
+    def _kernel_name(self):
+        """What the step's program is called in a trace."""
+        return "_%s_step" % type(self).__name__.lower()
+
+    def _grad(self, w, g, rescale, wd):
+        g = g * rescale
+        if self.clip_gradient is not None:
+            g = jnp.clip(g, -self.clip_gradient, self.clip_gradient)
+        return g + wd * w
+
+    def _program(self, rows=False):
+        return _step_program(type(self),
+                             tuple((f, getattr(self, f))
+                                   for f in self._kernel_fields),
+                             self._kernel_name(), rows)
+
+    def _apply(self, leaves, scalars):
+        """One dispatch over ``leaves`` — ``(weight, grad, state)`` of
+        NDArrays — with ``scalars`` their ``_step_scalars`` rows; the new
+        arrays land in the wrappers (no donation: the old ones stay
+        readable for whoever holds them)."""
+        outs = self._program()(
+            [w._data for w, _, _ in leaves], [g._data for _, g, _ in leaves],
+            [_arrays(st) for _, _, st in leaves],
+            np.asarray(scalars, np.float32))
+        for (w, _, st), (new_w, new_st) in zip(leaves, outs):
+            w._data = new_w
+            _store(st, new_st)
+
+    def update(self, index, weight, grad, state):
+        s = self._step_scalars(index)
+        if isinstance(grad, RowSparseNDArray):
+            if self._kernel_rows is not None and self.lazy_update:
+                new_w, new_st = self._program(rows=True)(
+                    weight._data, grad.data._data, grad.indices._data,
+                    _arrays(state), np.asarray(s, np.float32))
+                weight._data = new_w
+                _store(state, new_st)
+                return
+            grad = grad.tostype("default")
+        self._apply([(weight, grad, state)], [s])
 
     def update_multi_precision(self, index, weight, grad, state):
         if self.multi_precision and isinstance(state, tuple) and \
@@ -127,22 +262,24 @@ class Optimizer:
         else:
             self.update(index, weight, grad, state)
 
-    def _preprocess(self, weight, grad, wd):
-        g = grad._data * self.rescale_grad
-        if self.clip_gradient is not None:
-            g = jnp.clip(g, -self.clip_gradient, self.clip_gradient)
-        return g + wd * weight._data
+    def _fuses(self):
+        """Whether a step of this optimizer is a pure function of arrays
+        and scalars: the built-in ``update`` (host part, kernel) and not a
+        subclass's own, which may do anything."""
+        cls = type(self)
+        return cls.update is Optimizer.update and \
+            cls.update_multi_precision is Optimizer.update_multi_precision
 
-    def _sparse_to_dense(self, grad, weight):
-        if isinstance(grad, RowSparseNDArray):
-            return grad.tostype("default")
-        return grad
+    def _preprocess(self, weight, grad, wd):
+        return self._grad(weight._data, grad._data, self.rescale_grad, wd)
 
 
 @register
 class SGD(Optimizer):
     """SGD with momentum + optional multi-precision
     (ref: optimizer.py SGD; kernels src/operator/optimizer_op.cc:32)."""
+
+    _kernel_fields = Optimizer._kernel_fields + ("momentum",)
 
     def __init__(self, momentum=0.0, lazy_update=True, **kwargs):
         super().__init__(**kwargs)
@@ -154,73 +291,40 @@ class SGD(Optimizer):
             return None
         return NDArray(jnp.zeros_like(weight._data))
 
-    @staticmethod
-    @jax.jit
-    def _step(w, g, lr, wd, rescale, clip, has_clip):
-        g = g * rescale
-        g = jnp.where(has_clip, jnp.clip(g, -clip, clip), g)
-        g = g + wd * w
-        return w - lr * g
+    def _step(self, w, g, s):
+        lr, wd, rescale = s
+        return w - lr * self._grad(w, g, rescale, wd), None
 
-    @staticmethod
-    @jax.jit
-    def _step_mom(w, g, mom, lr, wd, mu, rescale, clip, has_clip):
-        g = g * rescale
-        g = jnp.where(has_clip, jnp.clip(g, -clip, clip), g)
-        g = g + wd * w
-        mom = mu * mom - lr * g
+    def _step_mom(self, w, g, mom, s):
+        lr, wd, rescale = s
+        mom = self.momentum * mom - lr * self._grad(w, g, rescale, wd)
         return w + mom, mom
 
-    @staticmethod
-    @jax.jit
-    def _step_rows(w, g, rows, lr, wd, rescale, clip, has_clip):
+    def _kernel(self, w, g, state, s):
+        if state is None:
+            return self._step(w, g, s)
+        return self._step_mom(w, g, state, s)
+
+    def _kernel_name(self):
+        return "_step_mom" if self.momentum != 0.0 else "_step"
+
+    def _kernel_rows(self, w, g, rows, state, s):
         """Row-sparse lazy update: touch only the gradient's rows
         (ref: src/operator/optimizer_op.cc:32 sgd_update rsp kernel —
         scatter on HBM instead of a full-matrix write)."""
-        g = g * rescale
-        g = jnp.where(has_clip, jnp.clip(g, -clip, clip), g)
-        g = g + wd * w[rows]
-        return w.at[rows].add(-lr * g)
-
-    @staticmethod
-    @jax.jit
-    def _step_mom_rows(w, g, mom, rows, lr, wd, mu, rescale, clip,
-                       has_clip):
-        g = g * rescale
-        g = jnp.where(has_clip, jnp.clip(g, -clip, clip), g)
-        g = g + wd * w[rows]
-        new_mom_rows = mu * mom[rows] - lr * g
-        mom = mom.at[rows].set(new_mom_rows)
-        return w.at[rows].add(new_mom_rows), mom
-
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        clip = self.clip_gradient if self.clip_gradient is not None else 1.0
-        has_clip = self.clip_gradient is not None
-        if isinstance(grad, RowSparseNDArray) and self.lazy_update:
-            rows = grad.indices._data
-            if state is None:
-                weight._data = SGD._step_rows(
-                    weight._data, grad.data._data, rows, lr, wd,
-                    self.rescale_grad, clip, has_clip)
-            else:
-                weight._data, state._data = SGD._step_mom_rows(
-                    weight._data, grad.data._data, state._data, rows, lr,
-                    wd, self.momentum, self.rescale_grad, clip, has_clip)
-            return
-        grad = self._sparse_to_dense(grad, weight)
+        lr, wd, rescale = s
+        g = self._grad(w[rows], g, rescale, wd)
         if state is None:
-            weight._data = SGD._step(weight._data, grad._data, lr, wd,
-                                     self.rescale_grad, clip, has_clip)
-        else:
-            weight._data, state._data = SGD._step_mom(
-                weight._data, grad._data, state._data, lr, wd, self.momentum,
-                self.rescale_grad, clip, has_clip)
+            return w.at[rows].add(-lr * g), None
+        new_mom_rows = self.momentum * state[rows] - lr * g
+        return w.at[rows].add(new_mom_rows), \
+            state.at[rows].set(new_mom_rows)
 
 
 @register
 class Signum(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("momentum",)
+
     def __init__(self, learning_rate=0.01, momentum=0.9, wd_lh=0.0, **kwargs):
         super().__init__(learning_rate=learning_rate, **kwargs)
         self.momentum = momentum
@@ -231,21 +335,23 @@ class Signum(Optimizer):
             return None
         return NDArray(jnp.zeros_like(weight._data))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        g = self._preprocess(weight, grad, wd)
-        if state is not None:
-            state._data = self.momentum * state._data - (1 - self.momentum) * g
-            weight._data = (1 - lr * self.wd_lh) * weight._data + \
-                lr * jnp.sign(state._data)
-        else:
-            weight._data = (1 - lr * self.wd_lh) * weight._data - \
-                lr * jnp.sign(g)
+    def _step_scalars(self, index):
+        lr, wd, rescale = super()._step_scalars(index)
+        return (lr, wd, rescale, 1 - lr * self.wd_lh)
+
+    def _kernel(self, w, g, state, s):
+        lr, wd, rescale, keep = s
+        g = self._grad(w, g, rescale, wd)
+        if state is None:
+            return keep * w - lr * jnp.sign(g), None
+        state = self.momentum * state - (1 - self.momentum) * g
+        return keep * w + lr * jnp.sign(state), state
 
 
 @register
 class NAG(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("momentum",)
+
     def __init__(self, momentum=0.0, **kwargs):
         super().__init__(**kwargs)
         self.momentum = momentum
@@ -255,19 +361,19 @@ class NAG(Optimizer):
             return None
         return NDArray(jnp.zeros_like(weight._data))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        g = self._preprocess(weight, grad, wd)
+    def _kernel(self, w, g, state, s):
+        lr, wd, rescale = s
+        g = self._grad(w, g, rescale, wd)
         if state is None:
-            weight._data = weight._data - lr * g
-        else:
-            state._data = self.momentum * state._data + g
-            weight._data = weight._data - lr * (g + self.momentum * state._data)
+            return w - lr * g, None
+        state = self.momentum * state + g
+        return w - lr * (g + self.momentum * state), state
 
 
 @register
 class Adam(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("beta1", "beta2", "epsilon")
+
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, lazy_update=True, **kwargs):
         super().__init__(learning_rate=learning_rate, **kwargs)
@@ -279,21 +385,25 @@ class Adam(Optimizer):
         return (NDArray(jnp.zeros_like(weight._data)),
                 NDArray(jnp.zeros_like(weight._data)))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
+    def _step_scalars(self, index):
+        lr, wd, rescale = super()._step_scalars(index)
         t = self._index_update_count[index]
         lr_t = lr * math.sqrt(1 - self.beta2 ** t) / (1 - self.beta1 ** t)
-        g = self._preprocess(weight, grad, wd)
+        return (lr_t, wd, rescale)
+
+    def _kernel(self, w, g, state, s):
+        lr_t, wd, rescale = s
+        g = self._grad(w, g, rescale, wd)
         m, v = state
-        m._data = self.beta1 * m._data + (1 - self.beta1) * g
-        v._data = self.beta2 * v._data + (1 - self.beta2) * g * g
-        weight._data = weight._data - lr_t * m._data / (
-            jnp.sqrt(v._data) + self.epsilon)
+        m = self.beta1 * m + (1 - self.beta1) * g
+        v = self.beta2 * v + (1 - self.beta2) * g * g
+        return w - lr_t * m / (jnp.sqrt(v) + self.epsilon), (m, v)
 
 
 @register
 class AdaGrad(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("float_stable_eps",)
+
     def __init__(self, eps=1e-7, **kwargs):
         super().__init__(**kwargs)
         self.float_stable_eps = eps
@@ -301,31 +411,29 @@ class AdaGrad(Optimizer):
     def create_state(self, index, weight):
         return NDArray(jnp.zeros_like(weight._data))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        if isinstance(grad, RowSparseNDArray):
-            # row-sparse AdaGrad: only the touched rows accumulate
-            # history (ref: optimizer_op.cc adagrad rsp kernel — the
-            # wide_deep path's standard optimizer)
-            rows = grad.indices._data
-            g = grad.data._data * self.rescale_grad
-            if self.clip_gradient is not None:
-                g = jnp.clip(g, -self.clip_gradient, self.clip_gradient)
-            g = g + wd * weight._data[rows]
-            hist_rows = state._data[rows] + g * g
-            state._data = state._data.at[rows].set(hist_rows)
-            weight._data = weight._data.at[rows].add(
-                -lr * g / (jnp.sqrt(hist_rows) + self.float_stable_eps))
-            return
-        g = self._preprocess(weight, grad, wd)
-        state._data = state._data + g * g
-        weight._data = weight._data - lr * g / (
-            jnp.sqrt(state._data) + self.float_stable_eps)
+    def _kernel(self, w, g, state, s):
+        lr, wd, rescale = s
+        g = self._grad(w, g, rescale, wd)
+        state = state + g * g
+        return w - lr * g / (jnp.sqrt(state) + self.float_stable_eps), state
+
+    def _kernel_rows(self, w, g, rows, state, s):
+        # row-sparse AdaGrad: only the touched rows accumulate history
+        # (ref: optimizer_op.cc adagrad rsp kernel — the wide_deep path's
+        # standard optimizer)
+        lr, wd, rescale = s
+        g = self._grad(w[rows], g, rescale, wd)
+        hist_rows = state[rows] + g * g
+        return w.at[rows].add(
+            -lr * g / (jnp.sqrt(hist_rows) + self.float_stable_eps)), \
+            state.at[rows].set(hist_rows)
 
 
 @register
 class RMSProp(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + (
+        "gamma1", "gamma2", "epsilon", "centered", "clip_weights")
+
     def __init__(self, learning_rate=0.001, gamma1=0.9, gamma2=0.9,
                  epsilon=1e-8, centered=False, clip_weights=None, **kwargs):
         super().__init__(learning_rate=learning_rate, **kwargs)
@@ -342,29 +450,28 @@ class RMSProp(Optimizer):
                     NDArray(jnp.zeros_like(weight._data)))
         return NDArray(jnp.zeros_like(weight._data))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        g = self._preprocess(weight, grad, wd)
+    def _kernel(self, w, g, state, s):
+        lr, wd, rescale = s
+        g = self._grad(w, g, rescale, wd)
         if self.centered:
             n, gmean, delta = state
-            n._data = (1 - self.gamma1) * g * g + self.gamma1 * n._data
-            gmean._data = (1 - self.gamma1) * g + self.gamma1 * gmean._data
-            delta._data = self.gamma2 * delta._data - lr * g / jnp.sqrt(
-                n._data - gmean._data * gmean._data + self.epsilon)
-            weight._data = weight._data + delta._data
+            n = (1 - self.gamma1) * g * g + self.gamma1 * n
+            gmean = (1 - self.gamma1) * g + self.gamma1 * gmean
+            delta = self.gamma2 * delta - lr * g / jnp.sqrt(
+                n - gmean * gmean + self.epsilon)
+            w, state = w + delta, (n, gmean, delta)
         else:
-            n = state
-            n._data = (1 - self.gamma1) * g * g + self.gamma1 * n._data
-            weight._data = weight._data - lr * g / jnp.sqrt(
-                n._data + self.epsilon)
+            state = (1 - self.gamma1) * g * g + self.gamma1 * state
+            w = w - lr * g / jnp.sqrt(state + self.epsilon)
         if self.clip_weights:
-            weight._data = jnp.clip(weight._data, -self.clip_weights,
-                                    self.clip_weights)
+            w = jnp.clip(w, -self.clip_weights, self.clip_weights)
+        return w, state
 
 
 @register
 class AdaDelta(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("rho", "epsilon")
+
     def __init__(self, rho=0.9, epsilon=1e-5, **kwargs):
         super().__init__(**kwargs)
         self.rho = rho
@@ -374,20 +481,25 @@ class AdaDelta(Optimizer):
         return (NDArray(jnp.zeros_like(weight._data)),
                 NDArray(jnp.zeros_like(weight._data)))
 
-    def update(self, index, weight, grad, state):
+    def _step_scalars(self, index):
         self._update_count(index)
-        wd = self._get_wd(index)
-        g = self._preprocess(weight, grad, wd)
+        return (self._get_wd(index), self.rescale_grad)
+
+    def _kernel(self, w, g, state, s):
+        wd, rescale = s
+        g = self._grad(w, g, rescale, wd)
         acc_g, acc_delta = state
-        acc_g._data = self.rho * acc_g._data + (1 - self.rho) * g * g
-        delta = jnp.sqrt(acc_delta._data + self.epsilon) / jnp.sqrt(
-            acc_g._data + self.epsilon) * g
-        acc_delta._data = self.rho * acc_delta._data + (1 - self.rho) * delta * delta
-        weight._data = weight._data - delta
+        acc_g = self.rho * acc_g + (1 - self.rho) * g * g
+        delta = jnp.sqrt(acc_delta + self.epsilon) / jnp.sqrt(
+            acc_g + self.epsilon) * g
+        acc_delta = self.rho * acc_delta + (1 - self.rho) * delta * delta
+        return w - delta, (acc_g, acc_delta)
 
 
 @register
 class Ftrl(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("lamda1", "beta")
+
     def __init__(self, lamda1=0.01, learning_rate=0.1, beta=1, **kwargs):
         super().__init__(learning_rate=learning_rate, **kwargs)
         self.lamda1 = lamda1
@@ -397,25 +509,27 @@ class Ftrl(Optimizer):
         return (NDArray(jnp.zeros_like(weight._data)),  # z
                 NDArray(jnp.zeros_like(weight._data)))  # n
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        g = grad._data * self.rescale_grad
+    def _kernel(self, w, g, state, s):
+        lr, wd, rescale = s
+        g = g * rescale
         if self.clip_gradient is not None:
             g = jnp.clip(g, -self.clip_gradient, self.clip_gradient)
         z, n = state
-        sigma = (jnp.sqrt(n._data + g * g) - jnp.sqrt(n._data)) / lr
-        z._data = z._data + g - sigma * weight._data
-        n._data = n._data + g * g
-        weight._data = jnp.where(
-            jnp.abs(z._data) > self.lamda1,
-            -(z._data - jnp.sign(z._data) * self.lamda1)
-            / ((self.beta + jnp.sqrt(n._data)) / lr + wd),
+        sigma = (jnp.sqrt(n + g * g) - jnp.sqrt(n)) / lr
+        z = z + g - sigma * w
+        n = n + g * g
+        w = jnp.where(
+            jnp.abs(z) > self.lamda1,
+            -(z - jnp.sign(z) * self.lamda1)
+            / ((self.beta + jnp.sqrt(n)) / lr + wd),
             0.0)
+        return w, (z, n)
 
 
 @register
 class Adamax(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("beta1", "beta2")
+
     def __init__(self, learning_rate=0.002, beta1=0.9, beta2=0.999, **kwargs):
         super().__init__(learning_rate=learning_rate, **kwargs)
         self.beta1 = beta1
@@ -425,20 +539,24 @@ class Adamax(Optimizer):
         return (NDArray(jnp.zeros_like(weight._data)),
                 NDArray(jnp.zeros_like(weight._data)))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
+    def _step_scalars(self, index):
+        lr, wd, rescale = super()._step_scalars(index)
         t = self._index_update_count[index]
-        lr_t = lr / (1 - self.beta1 ** t)
-        g = self._preprocess(weight, grad, wd)
+        return (lr / (1 - self.beta1 ** t), wd, rescale)
+
+    def _kernel(self, w, g, state, s):
+        lr_t, wd, rescale = s
+        g = self._grad(w, g, rescale, wd)
         m, u = state
-        m._data = self.beta1 * m._data + (1 - self.beta1) * g
-        u._data = jnp.maximum(self.beta2 * u._data, jnp.abs(g))
-        weight._data = weight._data - lr_t * m._data / (u._data + 1e-8)
+        m = self.beta1 * m + (1 - self.beta1) * g
+        u = jnp.maximum(self.beta2 * u, jnp.abs(g))
+        return w - lr_t * m / (u + 1e-8), (m, u)
 
 
 @register
 class Nadam(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("beta1", "beta2", "epsilon")
+
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, schedule_decay=0.004, **kwargs):
         super().__init__(learning_rate=learning_rate, **kwargs)
@@ -452,29 +570,34 @@ class Nadam(Optimizer):
         return (NDArray(jnp.zeros_like(weight._data)),
                 NDArray(jnp.zeros_like(weight._data)))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
+    def _step_scalars(self, index):
+        lr, wd, rescale = super()._step_scalars(index)
         t = self._index_update_count[index]
-        g = self._preprocess(weight, grad, wd)
         mu_t = self.beta1 * (1 - 0.5 * 0.96 ** (t * self.schedule_decay))
         mu_tp1 = self.beta1 * (1 - 0.5 * 0.96 ** ((t + 1) * self.schedule_decay))
         self.m_schedule = self.m_schedule * mu_t
         m_sched_next = self.m_schedule * mu_tp1
+        return (lr, wd, rescale, 1 - self.m_schedule, 1 - m_sched_next,
+                1 - self.beta2 ** t, 1 - mu_t, mu_tp1)
+
+    def _kernel(self, w, g, state, s):
+        lr, wd, rescale, g_div, m_div, v_div, g_mix, m_mix = s
+        g = self._grad(w, g, rescale, wd)
         m, v = state
-        m._data = self.beta1 * m._data + (1 - self.beta1) * g
-        v._data = self.beta2 * v._data + (1 - self.beta2) * g * g
-        g_prime = g / (1 - self.m_schedule)
-        m_prime = m._data / (1 - m_sched_next)
-        v_prime = v._data / (1 - self.beta2 ** t)
-        m_bar = (1 - mu_t) * g_prime + mu_tp1 * m_prime
-        weight._data = weight._data - lr * m_bar / (
-            jnp.sqrt(v_prime) + self.epsilon)
+        m = self.beta1 * m + (1 - self.beta1) * g
+        v = self.beta2 * v + (1 - self.beta2) * g * g
+        g_prime = g / g_div
+        m_prime = m / m_div
+        v_prime = v / v_div
+        m_bar = g_mix * g_prime + m_mix * m_prime
+        return w - lr * m_bar / (jnp.sqrt(v_prime) + self.epsilon), (m, v)
 
 
 @register
 class SGLD(Optimizer):
-    """Stochastic gradient Langevin dynamics."""
+    """Stochastic gradient Langevin dynamics. Its noise comes from the
+    global key stream, so it keeps an ``update`` of its own: one
+    dispatch per index, in the caller's order."""
 
     def update(self, index, weight, grad, state):
         from .. import random as _random
@@ -488,6 +611,8 @@ class SGLD(Optimizer):
 
 @register
 class FTML(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("beta1", "beta2", "epsilon")
+
     def __init__(self, learning_rate=0.0025, beta1=0.6, beta2=0.999,
                  epsilon=1e-8, **kwargs):
         super().__init__(learning_rate=learning_rate, **kwargs)
@@ -500,24 +625,27 @@ class FTML(Optimizer):
                 NDArray(jnp.zeros_like(weight._data)),
                 NDArray(jnp.zeros_like(weight._data)))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
+    def _step_scalars(self, index):
+        lr, wd, rescale = super()._step_scalars(index)
         t = self._index_update_count[index]
-        g = self._preprocess(weight, grad, wd)
+        return (wd, rescale, (1 - self.beta1 ** t) / lr,
+                1 - self.beta2 ** t)
+
+    def _kernel(self, w, g, state, s):
+        wd, rescale, d_scale, v_div = s
+        g = self._grad(w, g, rescale, wd)
         d, v, z = state
-        v._data = self.beta2 * v._data + (1 - self.beta2) * g * g
-        d_t = (1 - self.beta1 ** t) / lr * (
-            jnp.sqrt(v._data / (1 - self.beta2 ** t)) + self.epsilon)
-        sigma = d_t - self.beta1 * d._data
-        z._data = self.beta1 * z._data + (1 - self.beta1) * g - \
-            sigma * weight._data
-        d._data = d_t
-        weight._data = -z._data / d_t
+        v = self.beta2 * v + (1 - self.beta2) * g * g
+        d_t = d_scale * (jnp.sqrt(v / v_div) + self.epsilon)
+        sigma = d_t - self.beta1 * d
+        z = self.beta1 * z + (1 - self.beta1) * g - sigma * w
+        return -z / d_t, (d_t, v, z)
 
 
 @register
 class DCASGD(Optimizer):
+    _kernel_fields = Optimizer._kernel_fields + ("momentum", "lamda")
+
     def __init__(self, momentum=0.0, lamda=0.04, **kwargs):
         super().__init__(**kwargs)
         self.momentum = momentum
@@ -527,25 +655,25 @@ class DCASGD(Optimizer):
         mom = NDArray(jnp.zeros_like(weight._data)) if self.momentum else None
         return (mom, NDArray(jnp.copy(weight._data)))
 
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        g = self._preprocess(weight, grad, wd)
+    def _kernel(self, w, g, state, s):
+        lr, wd, rescale = s
+        g = self._grad(w, g, rescale, wd)
         mom, prev = state
-        comp = g + self.lamda * g * g * (weight._data - prev._data)
+        comp = g + self.lamda * g * g * (w - prev)
         if mom is not None:
-            mom._data = self.momentum * mom._data - lr * comp
-            delta = mom._data
+            mom = self.momentum * mom - lr * comp
+            delta = mom
         else:
             delta = -lr * comp
-        prev._data = weight._data
-        weight._data = weight._data + delta
+        return w + delta, (mom, w)
 
 
 @register
 class LBSGD(SGD):
     """Large-batch SGD with LARS-style layer-wise scaling
     (ref: optimizer.py LBSGD)."""
+
+    _kernel_rows = None
 
     def __init__(self, momentum=0.0, warmup_strategy="linear",
                  warmup_epochs=5, batch_scale=1, updates_per_epoch=32,
@@ -575,28 +703,25 @@ class LBSGD(SGD):
             return lr + (target - lr) * (frac ** 0.5)
         return lr  # "lars": constant base lr during warmup
 
-    @staticmethod
-    @jax.jit
-    def _lars_step(w, g, mom, lr, wd, mu, rescale):
+    def _lars_step(self, w, g, mom, s):
         # trust ratio computed on device — no host round-trip per parameter
+        lr, wd, rescale = s
         g = g * rescale
         wnorm = jnp.linalg.norm(w)
         gnorm = jnp.linalg.norm(g)
         ratio = jnp.where((wnorm > 0) & (gnorm > 0),
                           wnorm / (gnorm + wd * wnorm + 1e-9), 1.0)
         g = g + wd * w
-        mom = mu * mom - (lr * ratio) * g
+        mom = self.momentum * mom - (lr * ratio) * g
         return w + mom, mom
+
+    _kernel = _lars_step
+
+    def _kernel_name(self):
+        return "_lars_step"
 
     def create_state(self, index, weight):
         return NDArray(jnp.zeros_like(weight._data))
-
-    def update(self, index, weight, grad, state):
-        self._update_count(index)
-        lr, wd = self._get_lr(index), self._get_wd(index)
-        weight._data, state._data = LBSGD._lars_step(
-            weight._data, grad._data, state._data, lr, wd, self.momentum,
-            self.rescale_grad)
 
 
 @register
@@ -604,8 +729,11 @@ class Test(Optimizer):
     def create_state(self, index, weight):
         return NDArray(jnp.zeros_like(weight._data))
 
-    def update(self, index, weight, grad, state):
-        weight._data = weight._data - self.rescale_grad * grad._data
+    def _step_scalars(self, index):
+        return (self.rescale_grad,)
+
+    def _kernel(self, w, g, state, s):
+        return w - s[0] * g, state
 
 
 class Updater:
@@ -616,30 +744,60 @@ class Updater:
         self.optimizer = optimizer
         self.states = {}
 
-    def __call__(self, index, grad, weight):
-        from ..profiling import health as _health
-        from ..profiling import memory as _mem
+    def _state(self, index, weight):
         if index not in self.states:
             self.states[index] = \
                 self.optimizer.create_state_multi_precision(index,
                                                             weight)
-        self.optimizer.update_multi_precision(index, weight, grad,
-                                              self.states[index])
+        return self.states[index]
+
+    def _after(self, triples):
+        from ..profiling import health as _health
+        from ..profiling import memory as _mem
         if _health.enabled() and not _health.updater_is_covered():
             # optimizer in/out sentry: the incoming gradient and the
-            # updated weight in ONE lazy reduce per call — kvstore
+            # updated weight in ONE lazy reduce per index — kvstore
             # servers and Module.update get the same coverage as a
-            # local Trainer (whose StepProbe covers its whole loop in
-            # one program and suppresses this per-call check)
-            name = self.optimizer.idx2name.get(index, str(index))
-            _health.check("optimizer/%s" % name, [grad, weight])
+            # local Trainer (whose StepProbe covers its whole step in
+            # one program and suppresses this per-index check)
+            for index, grad, weight in triples:
+                name = self.optimizer.idx2name.get(index, str(index))
+                _health.check("optimizer/%s" % name, [grad, weight])
         if _mem.census_enabled():
             # updates are functional (fresh jax arrays land in the
             # NDArray wrappers), so the census roles are re-stamped
             # here — one weakref-table write per array, no device work
-            _mem.tag_tree(self.states[index], "optimizer_state")
-            _mem.tag_role(weight, "parameter")
-            _mem.tag_role(grad, "gradient")
+            for index, grad, weight in triples:
+                _mem.tag_tree(self.states[index], "optimizer_state")
+                _mem.tag_role(weight, "parameter")
+                _mem.tag_role(grad, "gradient")
+
+    def __call__(self, index, grad, weight):
+        self.optimizer.update_multi_precision(
+            index, weight, grad, self._state(index, weight))
+        self._after([(index, grad, weight)])
+
+    def update_tree(self, triples):
+        """``__call__`` over many ``(index, grad, weight)`` at once: the
+        host parts run in the order given, so update counts and the lr
+        schedule read as the per-index loop showed them, and then ONE
+        program updates every leaf whose step is a pure function of arrays
+        and scalars. A row-sparse gradient, or an optimizer whose class
+        brings an ``update`` of its own, goes per index where it stands
+        in that order: one dispatch each."""
+        opt = self.optimizer
+        fuses = opt._fuses()
+        leaves, scalars = [], []
+        for index, grad, weight in triples:
+            state = self._state(index, weight)
+            if fuses and not isinstance(grad, RowSparseNDArray):
+                scalars.append(opt._step_scalars(index))
+                leaves.append((weight, grad, state))
+            else:
+                opt.update_multi_precision(index, weight, grad, state)
+        if leaves:
+            opt._apply(leaves, scalars)
+        self._after(triples)
 
     def get_states(self, dump_optimizer=False):
         import pickle
