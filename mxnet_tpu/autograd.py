@@ -297,6 +297,18 @@ def _compute_gradients(heads, head_grads, create_graph=False):
     return grad_leaves, grad_nds
 
 
+def _free_graph():
+    """Drop the recorded graph and everything it holds. A node and its
+    output entries refer to each other, so left to themselves the arrays
+    snapshotted in a node's slots (every parameter a cached op read, its
+    inputs and its outputs) stay on the device until the cyclic collector
+    happens to run: at a large model's size, several steps' worth."""
+    tape = _st().tape
+    for node in tape:
+        node.slots = node.out_entries = ()
+    tape.clear()
+
+
 def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     """Compute gradients of heads wrt all marked variables, accumulating into
     their .grad per grad_req (ref: MXAutogradBackwardEx)."""
@@ -314,7 +326,7 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
                 else:
                     nd.grad._data = g._data
         if not retain_graph:
-            _st().tape.clear()
+            _free_graph()
 
 
 def grad(heads, variables, head_grads=None, retain_graph=None,
@@ -343,7 +355,7 @@ def grad(heads, variables, head_grads=None, retain_graph=None,
                              "contribute to the heads")
         out.append(by_id[id(v)])
     if not retain_graph:
-        _st().tape.clear()
+        _free_graph()
     return out
 
 

@@ -1,0 +1,13 @@
+"""Language models built from a published configuration (a dict with the
+keys of the model's ``config.json``)."""
+from .lfm2_moe import LFM2MoE, lfm2_moe
+
+_models = {"lfm2_moe": lfm2_moe}
+
+
+def get_model(name, **kwargs):
+    name = name.lower()
+    if name not in _models:
+        raise ValueError(
+            f"model {name!r} not in model zoo; available: {sorted(_models)}")
+    return _models[name](**kwargs)

@@ -1,7 +1,7 @@
 from .basic_layers import (Activation, BatchNorm, Dense, Dropout, ELU,
                            Embedding, Flatten, GELU, HybridLambda,
                            HybridSequential, InstanceNorm, Lambda, LayerNorm,
-                           LeakyReLU, PReLU, SELU, Sequential, Swish,
+                           LeakyReLU, PReLU, RMSNorm, SELU, Sequential, Swish,
                            SyncBatchNorm)
 from .conv_layers import (AvgPool1D, AvgPool2D, AvgPool3D, Conv1D,
                           Conv1DTranspose, Conv2D, Conv2DTranspose, Conv3D,

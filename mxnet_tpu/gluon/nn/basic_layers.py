@@ -313,6 +313,26 @@ class LayerNorm(HybridBlock):
         return F.LayerNorm(x, gamma, beta, axis=self._axis, eps=self._epsilon)
 
 
+class RMSNorm(HybridBlock):
+    """Root-mean-square normalisation over the last axis:
+    ``x / sqrt(mean(x^2) + epsilon) * gamma``, the mean in float32."""
+
+    def __init__(self, epsilon=1e-5, gamma_initializer="ones",
+                 in_channels=0, dtype="float32", prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get(
+                "gamma", shape=(in_channels,), init=gamma_initializer,
+                dtype=dtype, allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma.shape_inferred((x.shape[-1],))
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, eps=self._epsilon)
+
+
 class Embedding(HybridBlock):
     def __init__(self, input_dim, output_dim, dtype="float32",
                  weight_initializer=None, sparse_grad=False, prefix=None,

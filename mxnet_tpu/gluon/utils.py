@@ -26,6 +26,22 @@ def split_data(data, num_slice, batch_axis=0, even_split=True):
     return slices
 
 
+def recompute(fn, x):
+    """``fn(x)`` for a block or function of one NDArray, rematerialised:
+    inside a hybridized block's trace, differentiating the result keeps
+    ``x`` (and the parameters ``fn`` reads) alone, and ``fn`` runs again
+    in the backward pass (``jax.checkpoint``). What ``fn`` computes in
+    between (its float32 intermediates, its products' inputs) is not
+    kept. Anywhere else (eager calls, symbolic traces) it is ``fn(x)``.
+    ``fn`` must not defer an aux-state update: a value made inside does
+    not leave but through the result."""
+    import jax
+
+    if not isinstance(x, NDArray) or not isinstance(x._data, jax.core.Tracer):
+        return fn(x)
+    return NDArray(jax.checkpoint(lambda d: fn(NDArray(d))._data)(x._data))
+
+
 def split_and_load(data, ctx_list, batch_axis=0, even_split=True):
     """Lay the batch out across the contexts (ref: utils.py
     split_and_load).

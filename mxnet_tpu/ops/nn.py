@@ -11,6 +11,8 @@ here (and is *verified* by the subgraph tests rather than hand-scheduled).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,16 +28,16 @@ from .registry import register
 
 @register("FullyConnected")
 def fully_connected(data, weight, bias=None, num_hidden=0, no_bias=False,
-                    flatten=True):
+                    flatten=True, out_dtype=None):
     x = data.reshape(data.shape[0], -1) if flatten else data
     # weight layout (num_hidden, in_units) as in the reference
     # bf16 operands ride the MXU, which accumulates in fp32 internally;
-    # requesting an f32 output via preferred_element_type would break
-    # the VJP (the transpose rule feeds the f32 cotangent into a conv
-    # with bf16 operands) so the output stays in the input dtype
+    # the output stays in the input dtype unless ``out_dtype`` asks for
+    # the accumulator's (float32 logits from 16-bit operands)
     out = lax.dot_general(
         x, weight,
-        dimension_numbers=(((x.ndim - 1,), (1,)), ((), ())))
+        dimension_numbers=(((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=out_dtype and jnp.dtype(out_dtype))
     if not no_bias and bias is not None:
         out = out + bias
     return out
@@ -328,9 +330,6 @@ def softmax_activation(data, mode="instance"):
         return jax.nn.softmax(data, axis=1)
     flat = data.reshape(data.shape[0], -1)
     return jax.nn.softmax(flat, axis=-1).reshape(data.shape)
-
-
-import functools
 
 
 @functools.lru_cache(maxsize=None)
@@ -816,3 +815,107 @@ def legacy_crop(data, crop_like=None, offset=(0, 0), h_w=(0, 0),
             f"Crop: window offset ({y0},{x0}) size ({th},{tw}) exceeds "
             f"input ({H},{W}) (the reference CHECKs the same at crop-inl.h)")
     return data[:, :, y0:y0 + th, x0:x0 + tw]
+
+
+# ---------------------------------------------------------------------------
+# Sequence-model layers: RMSNorm, rotary encoding, gated MLP product, short
+# causal convolution, grouped-query attention, routed experts. Statistics,
+# softmax and router scores are float32 whatever the storage type.
+#
+# The elementwise ones compute in float32 and are ``jax.checkpoint``ed:
+# differentiated, they keep their 16-bit inputs and recompute the float32
+# intermediates in the backward pass, where autodiff would keep every one
+# (at 8,192 tokens x 2,048 wide, 67 MB apiece and a dozen a layer).
+# ---------------------------------------------------------------------------
+
+
+def _recomputed(fn):
+    """``fn(*arrays, **attrs)`` whose gradient keeps the arrays alone."""
+    @functools.wraps(fn)
+    def op(*arrays, **attrs):
+        return jax.checkpoint(lambda *a: fn(*a, **attrs))(*arrays)
+    return op
+
+
+@register("RMSNorm", aliases=("_contrib_RMSNorm",))
+@_recomputed
+def rms_norm(data, gamma, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, the mean in
+    float32."""
+    x = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * inv * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register("RotaryEmbedding", aliases=("_contrib_RotaryEmbedding",))
+@_recomputed
+def rotary_embedding(data, theta=10000.0, offset=0):
+    """Rotary position encoding of ``data`` [batch, seq, heads, dim], the
+    rotate-half form over all ``dim``: with ``a_t,i = (offset + t) *
+    theta^(-2i/dim)``, ``out = x * cos(a) + rotate_half(x) * sin(a)`` and
+    ``rotate_half(x) = concat(-x[dim/2:], x[:dim/2])``."""
+    t, d = data.shape[1], data.shape[3]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = (offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv_freq
+    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    x = data.astype(jnp.float32)
+    half = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
+    return (x * jnp.cos(ang) + half * jnp.sin(ang)).astype(data.dtype)
+
+
+@register("SwiGLU", aliases=("_contrib_SwiGLU",))
+@_recomputed
+def swiglu(gate, up):
+    """``silu(gate) * up``: the product of a gated MLP, taken in
+    float32."""
+    g = gate.astype(jnp.float32)
+    return (g * jax.nn.sigmoid(g) * up.astype(jnp.float32)).astype(up.dtype)
+
+
+@register("CausalConv1D", aliases=("_contrib_CausalConv1D",))
+@_recomputed
+def causal_conv1d(data, weight):
+    """Depthwise causal convolution over the sequence axis: ``data``
+    [batch, seq, channels], ``weight`` [channels, width];
+    ``out_t = sum_j weight[:, j] * data_{t - (width - 1) + j}`` with zeros
+    left of the sequence, summed in float32."""
+    width = weight.shape[1]
+    t = data.shape[1]
+    x = jnp.pad(data.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    out = sum(x[:, j:j + t, :] * w[:, j] for j in range(width))
+    return out.astype(data.dtype)
+
+
+@register("GQAttention", aliases=("_contrib_GQAttention",))
+def gq_attention(query, key, value, causal=True, scale=None):
+    """Softmax attention with grouped-query heads: ``query`` [batch, seq,
+    heads, dim], ``key`` / ``value`` [batch, seq, kv_heads, dim], each K/V
+    head serving ``heads // kv_heads`` consecutive query heads; softmax in
+    float32. Long sequences take the flash kernel
+    (``pallas_kernels.flash_attention`` owns the dispatch), which reads
+    the shared K/V head through its index map."""
+    from .pallas_kernels import flash_attention
+    out = flash_attention(jnp.swapaxes(query, 1, 2), jnp.swapaxes(key, 1, 2),
+                          jnp.swapaxes(value, 1, 2), causal=causal,
+                          scale=scale)
+    return jnp.swapaxes(out, 1, 2)
+
+
+@register("MoERoute", aliases=("_contrib_MoERoute",), num_outputs=3,
+          optional_arrays=("expert_bias",))
+def moe_route(data, router_weight, expert_bias=None, k=1, norm_topk=True,
+              scale=1.0):
+    """Top-k routing without drops over all the experts
+    (``parallel.moe.route``): ``(selection, gate, counts)``."""
+    from ..parallel.moe import route
+    return route(data, router_weight, expert_bias, k=k, norm_topk=norm_topk,
+                 scale=scale)
+
+
+@register("MoEExperts", aliases=("_contrib_MoEExperts",))
+def moe_experts(data, selection, gate, w1, w3, w2, first=0):
+    """The held experts' part of a routed gated-MLP layer
+    (``parallel.moe.experts_held``)."""
+    from ..parallel.moe import experts_held
+    return experts_held(data, selection, gate, w1, w3, w2, first=first)

@@ -45,19 +45,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
     """One (batch*head, q-block) program: stream K/V blocks through
     VMEM folding each into an online-softmax accumulator (Dao 2022)."""
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # (BQ, D)
+    # the products take the operands in their own type (bfloat16 rides
+    # the MXU in one pass) and accumulate in float32
+    q = q_ref[0]                                       # (BQ, D)
     t_k = k_ref.shape[1]
     n_k = t_k // block_k
 
     def body(j, carry):
         m, l, acc = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :] \
-            .astype(jnp.float32)                       # (BK, D)
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :] \
-            .astype(jnp.float32)
+        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]   # (BK, D)
+        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (BQ, BK)
+            preferred_element_type=jnp.float32) * scale    # (BQ, BK)
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -70,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
         alpha = jnp.exp(m - m_new)
         l_new = alpha * l + p.sum(axis=-1)
         acc_new = alpha[:, None] * acc + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
@@ -95,6 +95,9 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret):
 
     bh, t_q, d = q.shape
     t_k = k.shape[1]
+    # grouped-query heads: consecutive ``group`` query heads read one
+    # K/V head, picked by the index map; nothing is repeated in HBM
+    group = bh // k.shape[0]
     grid = (bh, t_q // block_q)
     kernel = functools.partial(_flash_kernel, block_q=block_q,
                                block_k=block_k, causal=causal, scale=scale)
@@ -108,8 +111,10 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0), **mem),
-            pl.BlockSpec((1, t_k, d), lambda b, i: (b, 0, 0), **mem),
-            pl.BlockSpec((1, t_k, d), lambda b, i: (b, 0, 0), **mem),
+            pl.BlockSpec((1, t_k, d), lambda b, i: (b // group, 0, 0),
+                         **mem),
+            pl.BlockSpec((1, t_k, d), lambda b, i: (b // group, 0, 0),
+                         **mem),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
                                **mem),
@@ -184,6 +189,17 @@ def _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q):
 
 def _flash_diff_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, out = res
+    group = q.shape[0] // k.shape[0]
+    if group > 1:
+        # the chunked pass wants a K/V row per query head; the groups'
+        # gradients are summed back onto the head they share
+        dq, dk, dv = _flash_diff_bwd(
+            causal, scale, block_q, block_k, interpret,
+            (q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
+             out), g)
+        fold = lambda a, like: a.astype(jnp.float32).reshape(
+            like.shape[0], group, *like.shape[1:]).sum(1).astype(like.dtype)
+        return dq, fold(dk, k), fold(dv, v)
     if q.shape[1] % block_q:
         # shapes the forward kernel accepted always tile; safety net
         _, vjp = jax.vjp(
@@ -597,7 +613,9 @@ def fused_adam(weight, grad, mean, var, lr=0.01, beta1=0.9,
 def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
                     block_k=512, interpret=None, force=False):
     """Blockwise attention, O(T) memory. q, k, v: (B, H, T, D) or
-    (BH, T, D). Dispatches to the Pallas kernel for long sequences
+    (BH, T, D); k and v may have fewer heads than q (grouped-query
+    attention: H a multiple of their head count, query head h reading
+    K/V head h // group). Dispatches to the Pallas kernel for long sequences
     (>= FLASH_MIN_SEQ, where it beats XLA's dense lowering by the
     measured margins above) and to the dense jnp path otherwise or when
     the sequence doesn't tile; `force=True` always takes the kernel
@@ -606,8 +624,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
     if q.ndim == 4:
         b, h, t, d = q.shape
         q = q.reshape(b * h, t, d)
-        k = k.reshape(b * h, k.shape[2], d)
-        v = v.reshape(b * h, v.shape[2], d)
+        k = k.reshape(b * k.shape[1], k.shape[2], d)
+        v = v.reshape(b * v.shape[1], v.shape[2], d)
         squeeze = (b, h)
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -628,6 +646,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
         out = _flash_diff(q, k, v, bool(causal), float(scale),
                           int(block_q), int(block_k), bool(interpret))
     else:
+        group = q.shape[0] // k.shape[0]
+        if group > 1:
+            k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
         out = _dense_reference(q, k, v, causal, scale)
     if squeeze:
         b, h = squeeze

@@ -101,7 +101,12 @@ def _step_program(cls, fields, name, rows=False):
     fn = row_leaf if rows else tree
     # the program runs as jit_<name>(<fingerprint>)
     fn.__name__ = name + "_rows" if rows else name
-    return jax.jit(fn)
+    # the optimizer's state is the Updater's alone and every step rewrites
+    # it whole: donated, it is updated in place, where a second copy of a
+    # large model's state (12 bytes a parameter under multi-precision Adam)
+    # would not fit beside the first. Weights are not donated: whoever
+    # holds the old array keeps it.
+    return jax.jit(fn, donate_argnums=() if rows else (2,))
 
 
 class Optimizer:
@@ -227,8 +232,9 @@ class Optimizer:
     def _apply(self, leaves, scalars):
         """One dispatch over ``leaves`` — ``(weight, grad, state)`` of
         NDArrays — with ``scalars`` their ``_step_scalars`` rows; the new
-        arrays land in the wrappers (no donation: the old ones stay
-        readable for whoever holds them)."""
+        arrays land in the wrappers. The state's old arrays are donated to
+        the program; the old weights stay readable for whoever holds
+        them."""
         outs = self._program()(
             [w._data for w, _, _ in leaves], [g._data for _, g, _ in leaves],
             [_arrays(st) for _, _, st in leaves],

@@ -32,7 +32,7 @@ from .ulysses import ulysses_attention
 from .tensor_parallel import (column_parallel_dense, row_parallel_dense,
                               tp_mlp)
 from .pipeline import pipeline_apply
-from .moe import moe_dispatch
+from .moe import experts_held, moe_ffn, moe_ffn_ep, route
 from .train_step import (make_sharded_train_step,
                          make_zero_train_step, sgd_update)
 
@@ -45,6 +45,6 @@ __all__ = [
     "ppermute_next", "reduce_scatter",
     "ring_attention", "ulysses_attention",
     "column_parallel_dense", "row_parallel_dense", "tp_mlp",
-    "pipeline_apply", "moe_dispatch",
+    "pipeline_apply", "route", "experts_held", "moe_ffn", "moe_ffn_ep",
     "make_sharded_train_step", "make_zero_train_step", "sgd_update",
 ]

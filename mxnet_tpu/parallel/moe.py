@@ -1,11 +1,28 @@
-"""Expert parallelism: GShard-style top-1 routed mixture-of-experts with
-fixed capacity, experts sharded over an `ep` mesh axis and tokens moved
-by a pair of all-to-alls.
+"""Expert parallelism: a top-k routed expert layer that drops no token and
+is told which experts it holds.
+
+Every chip of an expert-parallel job routes its tokens over ALL the
+experts (``route``: the router keeps its published width) and computes
+the part of the result that the experts it holds give
+(``experts_held``). What the absent experts would add arrives over the
+exchange in a job that spans chips; on one chip there is no exchange and
+the partial result is what goes on. The parts of all the shares add up
+to the whole layer (tests/test_moe.py).
+
+The held experts' products are grouped: the (token, slot) visits are
+sorted by expert, visits to absent experts last, and one
+``lax.ragged_dot`` per weight multiplies each group by its own expert.
+The buffer holds every visit there could be, tokens x k rows, so its
+shape does not depend on the routing and a routing that changes from
+step to step runs the same compiled program; the rows past the last
+group are never multiplied. On a TPU ``ragged_dot`` is the compiler's
+own grouped kernel (``ragged-dot`` in a capture), forward and in both
+gradients.
 
 New capability vs. the reference (SURVEY.md §2.3 item 7). The closest
 reference analogue is the sparse row_sparse parameter-server path
 (ref: src/kvstore/kvstore_dist.h:470 PullRowSparse) — sending only the
-needed rows; here the routing moves activations instead, over ICI.
+needed rows; here the routing moves activations instead.
 """
 from __future__ import annotations
 
@@ -13,67 +30,94 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.nn import swiglu
 
-def moe_dispatch(x, router_logits, expert_fn, axis_name="ep",
-                 capacity_factor=2.0):
-    """Top-1 routed MoE layer body (call inside `shard_map` over `ep`).
 
-    Parameters
-    ----------
-    x : [tokens_local, d_model] this device's tokens.
-    router_logits : [tokens_local, n_experts_total].
-    expert_fn : callable([n_local_experts, capacity_total, d], params-free)
-        Applies this device's experts; vmapped over its leading axis by
-        the caller's closure if needed.
-    capacity_factor : float
-        Per-expert buffer size multiplier; overflowing tokens are dropped
-        (standard GShard semantics) and pass through via the residual at
-        the call site.
+def route(x, router_w, expert_bias=None, k=1, norm_topk=True, scale=1.0):
+    """Top-k routing of ``x`` [tokens, d] over the ``router_w.shape[0]``
+    experts; ``router_w`` is [experts, d].
 
-    Returns
-    -------
-    [tokens_local, d_model] combined expert outputs (zeros for dropped
-    tokens).
+    Scores are float32: the sigmoid of the router's product.
+    ``expert_bias`` [experts] is added for the SELECTION only;
+    the weights come from the unbiased scores, divided by their sum over
+    the k selected (+ 1e-6) where ``norm_topk``, times ``scale``.
+
+    Returns ``(sel, gate, counts)``: int32 [tokens, k] expert ids,
+    float32 [tokens, k] weights (differentiable towards ``x`` and
+    ``router_w``), and int32 [experts] visits to each expert.
     """
-    T, D = x.shape
-    E = router_logits.shape[-1]
-    size = lax.psum(1, axis_name)
-    assert E % size == 0, "n_experts must divide the ep axis"
-    cap = int(max(1, capacity_factor * T / E))
-
-    gates = jax.nn.softmax(router_logits, axis=-1)           # [T, E]
-    expert_idx = jnp.argmax(gates, axis=-1)                  # [T]
-    gate_val = jnp.take_along_axis(gates, expert_idx[:, None], 1)[:, 0]
-
-    onehot = jax.nn.one_hot(expert_idx, E, dtype=x.dtype)    # [T, E]
-    pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0          # slot per token
-    keep = (pos < cap) & (onehot > 0)                        # capacity mask
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=x.dtype)
-    dispatch = keep[..., None].astype(x.dtype) * pos_oh      # [T, E, C]
-    combine = dispatch * gate_val[:, None, None]             # [T, E, C]
-
-    # [T, E, C] x [T, D] -> [E, C, D]
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, x)
-    # exchange: each device keeps its E/size experts, gathering the
-    # matching capacity slices from every peer -> [E/size, C*size, D]
-    expert_in = lax.all_to_all(expert_in, axis_name, split_axis=0,
-                               concat_axis=1, tiled=True)
-    expert_out = expert_fn(expert_in)
-    expert_out = lax.all_to_all(expert_out, axis_name, split_axis=1,
-                                concat_axis=0, tiled=True)   # [E, C, D]
-    return jnp.einsum("tec,ecd->td", combine, expert_out)
+    logits = lax.dot_general(x, router_w, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    biased = scores if expert_bias is None else \
+        scores + expert_bias.astype(jnp.float32)
+    _, sel = lax.top_k(lax.stop_gradient(biased), k)
+    gate = jnp.take_along_axis(scores, sel, axis=1)
+    if norm_topk:
+        gate = gate / (jnp.sum(gate, axis=1, keepdims=True) + 1e-6)
+    gate = gate * scale
+    n_experts = router_w.shape[0]
+    counts = jnp.sum(jax.nn.one_hot(sel.reshape(-1), n_experts,
+                                    dtype=jnp.int32), axis=0)
+    return sel.astype(jnp.int32), gate, counts
 
 
-def moe_ffn(x, router_w, w1, w2, axis_name="ep", capacity_factor=2.0,
-            act=jax.nn.gelu):
-    """Complete expert-parallel FFN: router + two-layer experts.
+def experts_held(x, sel, gate, w1, w3, w2, first=0):
+    """This share's part of a gated-MLP expert layer.
 
-    w1: [n_local_experts, d_model, d_hidden]; w2: [n_local_experts,
-    d_hidden, d_model]; router_w: [d_model, n_experts_total].
+    Differentiated, the layer keeps its arguments alone and recomputes the
+    grouped products in the backward pass (``jax.checkpoint``): the
+    visits' buffer is as long as every visit there could be, eight times
+    the live rows where a chip holds an eighth of the experts, and its
+    intermediates would be kept at that length.
+
+    ``x`` [tokens, d]; ``sel`` / ``gate`` [tokens, k] from :func:`route`
+    over all the experts; ``w1``, ``w3`` [held, d, f] and ``w2``
+    [held, f, d] are the experts ``first`` … ``first + held - 1``.
+    Returns ``sum_e gate_e * w2_e(silu(w1_e x) * w3_e x)`` over the
+    selected experts that are held: [tokens, d]. No visit is dropped.
     """
-    def experts(xs):  # [E_local, C_total, D]
-        h = act(jnp.einsum("ecd,edh->ech", xs, w1))
-        return jnp.einsum("ech,ehd->ecd", h, w2)
+    return jax.checkpoint(_experts_held)(x, sel, gate, w1, w3, w2, first)
 
-    return moe_dispatch(x, x @ router_w, experts, axis_name=axis_name,
-                        capacity_factor=capacity_factor)
+
+def _experts_held(x, sel, gate, w1, w3, w2, first):
+    n, k = sel.shape
+    held = w1.shape[0]
+    local = sel.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held)          # absent experts sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+    token = order // k
+    # rows past the last group are never multiplied; what a kernel leaves
+    # there is masked on the way in (so no gradient comes back through
+    # them) and on the way out
+    live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(live, jnp.take(x, token, axis=0), 0)   # [tokens * k, d]
+    h = swiglu(lax.ragged_dot(xs, w1, sizes), lax.ragged_dot(xs, w3, sizes))
+    y = jnp.where(live, lax.ragged_dot(h, w2, sizes), 0)
+    y = y.astype(jnp.float32) * jnp.take(gate.reshape(-1), order)[:, None]
+    back = jnp.argsort(order)                   # visit -> its sorted row
+    out = jnp.take(y, back, axis=0).reshape(n, k, -1).sum(axis=1)
+    return out.astype(x.dtype)
+
+
+def moe_ffn(x, router_w, w1, w3, w2, expert_bias=None, k=1, first=0,
+            norm_topk=True, scale=1.0):
+    """Router and held experts in one call: ``(out, counts)``."""
+    sel, gate, counts = route(x, router_w, expert_bias, k=k,
+                              norm_topk=norm_topk, scale=scale)
+    return experts_held(x, sel, gate, w1, w3, w2, first=first), counts
+
+
+def moe_ffn_ep(x, router_w, w1, w3, w2, axis_name="ep", **route_args):
+    """The layer across an ``ep`` mesh axis (call inside ``shard_map``;
+    ``x`` and the router replicated, the experts split over the axis):
+    every device computes its own experts' part for all the tokens and
+    one ``psum`` adds the parts. Correct for any routing and drops
+    nothing; a job whose tokens are split over the same axis wants the
+    all-to-all exchange instead (ROADMAP B4)."""
+    held = w1.shape[0]
+    first = lax.axis_index(axis_name) * held
+    out, counts = moe_ffn(x, router_w, w1, w3, w2, first=first, **route_args)
+    return lax.psum(out, axis_name), counts

@@ -66,6 +66,58 @@ def test_flash_compiles(chip, bh, t, d):
         interpret=False))
 
 
+def test_flash_compiles_grouped_heads_at_64_lanes(chip):
+    """LFM2-24B-A2B's attention at the benchmark's batch: 64 query heads
+    (2 x 32) of 64 lanes over 16 K/V heads, 4,096 tokens; the K/V head is
+    picked by the index map, and inside a jitted program the kernel keeps
+    the name ``benchmark/layer_metrics/flash_attn_roofline_pct.py`` finds
+    it by."""
+    q = chip((64, 4096, 64), jnp.bfloat16)
+    kv = chip((16, 4096, 64), jnp.bfloat16)
+    _is_kernel(pk._flash_call.lower(
+        q, kv, kv, causal=True, scale=0.125, block_q=256, block_k=512,
+        interpret=False))
+
+    def mx_attn(q, k, v):
+        with jax.named_scope("lfm2.attn"):
+            return pk._flash_call(q, k, v, causal=True, scale=0.125,
+                                  block_q=256, block_k=512, interpret=False)
+
+    text = jax.jit(mx_attn).lower(q, kv, kv).compile().as_text()
+    kernels = [line.split(" = ")[0].split("%")[-1]
+               for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(kernels) == 1 and kernels[0].startswith("_flash_call")
+
+
+def test_expert_layer_compiles_to_grouped_kernels_by_their_name(chip):
+    """The held experts' products at the published widths (8 experts,
+    2,048 x 1,536, 8,192 tokens x top-4 = 32,768 visit rows): XLA:TPU
+    makes ``lax.ragged_dot`` a grouped kernel of its own, named
+    ``ragged-dot…`` — the name ``moe_grouped_roofline_pct`` reads — in the
+    forward and in both gradients."""
+    from mxnet_tpu.parallel import moe
+
+    x = chip((8192, 2048), jnp.bfloat16)
+    sel = chip((8192, 4), jnp.int32)
+    gate = chip((8192, 4), jnp.float32)
+    w13 = chip((8, 2048, 1536), jnp.bfloat16)
+    w2 = chip((8, 1536, 2048), jnp.bfloat16)
+
+    def loss(x, gate, w1, w3, w2, sel):
+        out = moe.experts_held(x, sel, gate, w1, w3, w2, first=0)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, gate, w13, w13, w2, sel).compile().as_text()
+    kernels = [line.split(" = ")[0].split("%")[-1]
+               for line in text.splitlines()
+               if "tpu_custom_call" in line and " ragged-dot" in
+               " " + line.split(" = ")[0].split("%")[-1]]
+    products = [k for k in kernels if k.startswith("ragged-dot-none")]
+    # three forward (recomputed in the backward program), six backward
+    assert len(products) == 9, kernels
+
+
 # h16 x d128 is the smoke's decoder; h4 x d16 is GenerativeDecoder's
 # default. paged_attention's rule is one line — on the chip every head
 # shape takes the kernel — so the smallest shipped shape compiles too

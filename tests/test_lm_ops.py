@@ -1,0 +1,182 @@
+"""The sequence-model ops (RMSNorm, rotary encoding, SwiGLU, short causal
+convolution, grouped-query attention) against ``jax.numpy`` written out,
+forward and gradient, through the registered ops."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import autograd
+from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops import registry
+
+RNG = np.random.default_rng(11)
+
+
+def _arr(*shape):
+    return RNG.standard_normal(shape).astype(np.float32)
+
+
+# -- written out ------------------------------------------------------------
+
+def rms_norm(x, g, eps=1e-5):
+    return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
+
+
+def rope(x, theta=1e6):
+    _, t, _, d = x.shape
+    out = []
+    for i in range(d):
+        j = i % (d // 2)
+        ang = jnp.arange(t) * theta ** (-2.0 * j / d)
+        pair = -x[..., i + d // 2] if i < d // 2 else x[..., i - d // 2]
+        out.append(x[..., i] * jnp.cos(ang)[None, :, None]
+                   + pair * jnp.sin(ang)[None, :, None])
+    return jnp.stack(out, -1)
+
+
+def swiglu(a, b):
+    return a / (1 + jnp.exp(-a)) * b
+
+
+def causal_conv(x, w):
+    b, t, c = x.shape
+    width = w.shape[1]
+    out = jnp.zeros_like(x)
+    for j in range(width):
+        shift = width - 1 - j
+        piece = jnp.concatenate(
+            [jnp.zeros((b, shift, c)), x[:, :t - shift]], 1)
+        out = out + piece * w[:, j]
+    return out
+
+
+def gq_attention(q, k, v):
+    b, t, h, d = q.shape
+    group = h // k.shape[2]
+    out = []
+    for i in range(h):
+        s = jnp.einsum("btd,bsd->bts", q[:, :, i], k[:, :, i // group])
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s / d ** 0.5, -1e30)
+        out.append(jnp.einsum("bts,bsd->btd", jax.nn.softmax(s, -1),
+                              v[:, :, i // group]))
+    return jnp.stack(out, 2)
+
+
+CASES = {
+    "RMSNorm": (rms_norm, lambda: (_arr(2, 5, 16), 1 + 0.1 * _arr(16)), {}),
+    "RotaryEmbedding": (rope, lambda: (_arr(2, 7, 3, 8),),
+                        {"theta": 1e6}),
+    "SwiGLU": (swiglu, lambda: (_arr(3, 6, 10), _arr(3, 6, 10)), {}),
+    "CausalConv1D": (causal_conv, lambda: (_arr(2, 9, 6), _arr(6, 3)), {}),
+    "GQAttention": (gq_attention,
+                    lambda: (_arr(2, 6, 4, 8), _arr(2, 6, 2, 8),
+                             _arr(2, 6, 2, 8)), {"causal": True}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_forward_against_jax_numpy_written_out(name):
+    want_fn, make, attrs = CASES[name]
+    args = make()
+    got = getattr(mx.nd, name)(*[mx.nd.array(a) for a in args], **attrs)
+    want = want_fn(*[jnp.asarray(a) for a in args])
+    np.testing.assert_allclose(got.asnumpy(), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_gradient_against_jax_numpy_written_out(name):
+    """Through autograd's tape: every input's gradient of a weighted sum
+    of the output."""
+    want_fn, make, attrs = CASES[name]
+    args = make()
+    nds = [mx.nd.array(a) for a in args]
+    for a in nds:
+        a.attach_grad()
+    with autograd.record():
+        out = getattr(mx.nd, name)(*nds, **attrs)
+        head = mx.nd.array(_arr(*out.shape))
+        loss = (out * head).sum()
+    loss.backward()
+    want = jax.grad(
+        lambda *xs: (want_fn(*xs) * head._data).sum(),
+        argnums=tuple(range(len(args))))(*[jnp.asarray(a) for a in args])
+    for a, w in zip(nds, want):
+        np.testing.assert_allclose(a.grad.asnumpy(), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_bfloat16_keeps_its_type_and_stays_close(name):
+    """Storage type in, storage type out; statistics in float32 keep a
+    16-bit call within 16-bit rounding of the float32 one."""
+    want_fn, make, attrs = CASES[name]
+    args = make()
+    op = registry.get(name)
+    got = op(*[jnp.asarray(a, jnp.bfloat16) for a in args], **attrs)
+    assert got.dtype == jnp.bfloat16
+    want = want_fn(*[jnp.asarray(a) for a in args])
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) \
+        < 0.05 * scale
+
+
+def test_fully_connected_out_dtype_gives_float32_logits_and_gradients():
+    x, w = _arr(4, 6, 16), _arr(32, 16)
+    xb, wb = (mx.nd.array(a).astype("bfloat16") for a in (x, w))
+    for a in (xb, wb):
+        a.attach_grad()
+    with autograd.record():
+        out = mx.nd.FullyConnected(xb, wb, no_bias=True, flatten=False,
+                                   out_dtype="float32")
+        loss = (out * out).sum()
+    loss.backward()
+    assert out.dtype == np.float32
+    assert xb.grad.dtype == wb.grad.dtype == jnp.bfloat16
+    want = jnp.asarray(xb._data, jnp.float32) @ \
+        jnp.asarray(wb._data, jnp.float32).T
+    np.testing.assert_allclose(out.asnumpy(), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+
+
+# -- the flash kernel: grouped K/V heads through the index map ----------------
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("heads,kv", [(4, 4), (4, 1), (8, 2)])
+def test_flash_kernel_grouped_heads_forward_and_gradient(heads, kv, dtype,
+                                                         tol):
+    b, t, d = 2, 64, 16
+    q = jnp.asarray(_arr(b, heads, t, d), dtype)
+    k = jnp.asarray(_arr(b, kv, t, d), dtype)
+    v = jnp.asarray(_arr(b, kv, t, d), dtype)
+
+    def dense(q, k, v):
+        rep = lambda a: jnp.repeat(a, heads // kv, axis=1)
+        return pk._dense_reference(
+            q.reshape(b * heads, t, d).astype(jnp.float32),
+            rep(k).reshape(b * heads, t, d).astype(jnp.float32),
+            rep(v).reshape(b * heads, t, d).astype(jnp.float32),
+            True, d ** -0.5).reshape(b, heads, t, d)
+
+    def flash(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, block_q=16,
+                                  block_k=32, force=True, interpret=True)
+
+    got, want = flash(q, k, v), dense(q, k, v)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), rtol=tol, atol=tol)
+    head = jnp.asarray(_arr(b, heads, t, d))
+    g_got = jax.grad(lambda *a: (flash(*a).astype(jnp.float32) * head).sum(),
+                     (0, 1, 2))(q, k, v)
+    g_want = jax.grad(lambda *a: (dense(*a) * head).sum(), (0, 1, 2))(
+        q, k, v)
+    for a, w in zip(g_got, g_want):
+        assert a.shape == w.shape and a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=5 * tol, atol=5 * tol)

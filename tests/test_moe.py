@@ -1,0 +1,178 @@
+"""The routed expert layer (``parallel/moe.py``): top-k routing without
+drops, an expert layer that is told which experts it holds, the grouped
+product over the experts held. Each case against ``jax.numpy`` written
+out: a dense loop over the experts with a mask."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+import mxnet_tpu as mx
+from mxnet_tpu import autograd
+from mxnet_tpu import parallel as par
+from mxnet_tpu.parallel import moe
+
+T, D, F, E = 48, 16, 24, 8
+
+
+def make(seed=3, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    arr = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)
+    return {"x": arr(T, D), "router": arr(E, D) * 0.5,
+            "bias": jnp.asarray(rng.standard_normal(E) * 0.1, jnp.float32),
+            "w1": arr(E, D, F) / 4, "w3": arr(E, D, F) / 4,
+            "w2": arr(E, F, D) / 4}
+
+
+def dense_layer(p, k, held=(0, E), norm=True, bias=True):
+    """The layer written out: scores over all the experts, top-k with the
+    bias in the selection only, the held experts' part of the sum."""
+    logits = p["x"] @ p["router"].T
+    s = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(s + (p["bias"] if bias else 0.0), k)
+    w = jnp.take_along_axis(s, sel, 1)
+    if norm:
+        w = w / (w.sum(1, keepdims=True) + 1e-6)
+    out = jnp.zeros_like(p["x"])
+    for e in range(held[0], held[0] + held[1]):
+        we = jnp.where(sel == e, w, 0.0).sum(1)
+        h = p["x"] @ p["w1"][e]
+        y = (h / (1 + jnp.exp(-h)) * (p["x"] @ p["w3"][e])) @ p["w2"][e]
+        out = out + we[:, None] * y
+    return out, sel
+
+
+def layer(p, k, held=(0, E), **kw):
+    first, count = held
+    sl = slice(first, first + count)
+    return moe.moe_ffn(p["x"], p["router"], p["w1"][sl], p["w3"][sl],
+                       p["w2"][sl], expert_bias=p["bias"], k=k,
+                       first=first, **kw)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+@pytest.mark.parametrize("norm", [True, False])
+def test_whole_layer_against_the_dense_loop(k, norm):
+    p = make()
+    got, counts = layer(p, k, norm_topk=norm)
+    want, sel = dense_layer(p, k, norm=norm)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(sel).ravel(), minlength=E))
+    assert int(counts.sum()) == T * k          # no visit is dropped
+
+
+@pytest.mark.parametrize("share", range(4))
+def test_a_share_equals_the_dense_loop_given_the_same_share(share):
+    p, held = make(), (2 * share, 2)
+    got, _ = layer(p, 4, held=held)
+    want, _ = dense_layer(p, 4, held=held)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shares", [8, 4, 2])
+def test_the_shares_add_up_to_the_uncut_layer(shares):
+    """What shares 0 … n-1 of one expert layer give adds up to the whole
+    layer; every share routes over all the experts and counts alike."""
+    p, per = make(5), E // shares
+    whole, _ = dense_layer(p, 4)
+    parts = [layer(p, 4, held=(i * per, per)) for i in range(shares)]
+    np.testing.assert_allclose(sum(o for o, _ in parts), whole, rtol=2e-5,
+                               atol=2e-5)
+    for _, counts in parts:
+        np.testing.assert_array_equal(counts, parts[0][1])
+
+
+def test_gradients_against_the_dense_loop():
+    p = make(7)
+    names = ["x", "router", "w1", "w3", "w2"]
+
+    def loss(fn):
+        return lambda *a: (fn(dict(p, **dict(zip(names, a))), 4,
+                              held=(2, 4))[0] ** 2).sum()
+
+    got = jax.grad(loss(layer), tuple(range(5)))(*[p[n] for n in names])
+    want = jax.grad(loss(dense_layer), tuple(range(5)))(
+        *[p[n] for n in names])
+    for n, a, w in zip(names, got, want):
+        np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5, err_msg=n)
+
+
+@pytest.mark.parametrize("target", [0, 5])
+def test_no_drop_under_a_planted_skew(target):
+    """Every token is sent to one expert: the router's rows are nought
+    but the target's, and the bias tips the rest. All T visits arrive and
+    all are computed."""
+    p = make(9)
+    p["router"] = jnp.zeros((E, D)).at[target].set(0.0)
+    p["bias"] = jnp.zeros(E).at[target].set(1.0)
+    got, counts = layer(p, 1)
+    assert int(counts[target]) == T and int(counts.sum()) == T
+    want, _ = dense_layer(p, 1)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert float(jnp.abs(got).sum()) > 0
+
+
+def test_two_routings_reuse_one_compiled_program():
+    """Static shapes: the visits' buffer holds tokens x k rows whatever
+    the routing, so a routing that changes does not compile again."""
+    fn = jax.jit(lambda p: layer(p, 4, held=(0, 4)))
+    a, b = make(1), make(2)
+    b["bias"] = b["bias"].at[0].set(5.0)       # all tokens visit expert 0
+    (_, ca), (_, cb) = fn(a), fn(b)
+    assert fn._cache_size() == 1
+    assert int(cb[0]) == T and int(ca[0]) < T
+
+
+def test_rows_past_the_last_group_give_no_gradient():
+    """Held experts that no token selects: their weights' gradients are
+    nought and the tokens' are finite."""
+    p = make(4)
+    p["bias"] = jnp.zeros(E).at[6].set(9.0).at[7].set(9.0)
+    g = jax.grad(lambda w1, x: (layer(dict(p, w1=w1, x=x), 2,
+                                      held=(0, 4))[0] ** 2).sum(),
+                 (0, 1))(p["w1"], p["x"])
+    assert float(jnp.abs(g[0][:4]).max()) == 0.0
+    assert bool(jnp.isfinite(g[1]).all())
+
+
+def test_registered_ops_through_autograd():
+    p = make(8)
+    nd = {n: mx.nd.array(np.asarray(v)) for n, v in p.items()}
+    for n in ("x", "router", "w1"):
+        nd[n].attach_grad()
+    with autograd.record():
+        sel, gate, counts = mx.nd.MoERoute(nd["x"], nd["router"],
+                                           nd["bias"], k=4)
+        out = mx.nd.MoEExperts(nd["x"], sel, gate, nd["w1"][2:6],
+                               nd["w3"][2:6], nd["w2"][2:6], first=2)
+        loss = (out * out).sum()
+    loss.backward()
+    want, _ = dense_layer(p, 4, held=(2, 4))
+    np.testing.assert_allclose(out.asnumpy(), want, rtol=2e-5, atol=2e-5)
+    assert sel.dtype == np.int32 and counts.asnumpy().sum() == 4 * T
+    g = jax.grad(lambda x: (dense_layer(dict(p, x=x), 4,
+                                        held=(2, 4))[0] ** 2).sum())(p["x"])
+    np.testing.assert_allclose(nd["x"].grad.asnumpy(), g, rtol=2e-4,
+                               atol=2e-5)
+
+
+def test_expert_parallel_over_a_mesh_matches_local():
+    """Experts split over an ``ep`` axis of four devices, two a device:
+    each computes its own part and one psum adds the parts."""
+    mesh = par.create_mesh({"ep": 4}, devices=jax.devices()[:4])
+    p = make(6)
+
+    def fn(x, router, w1, w3, w2):
+        return moe.moe_ffn_ep(x, router, w1, w3, w2, axis_name="ep", k=2)
+
+    got, counts = par.shard_map(
+        fn, mesh=mesh,
+        in_specs=(P(), P(), P("ep"), P("ep"), P("ep")),
+        out_specs=(P(), P()), check_vma=False)(
+            p["x"], p["router"], p["w1"], p["w3"], p["w2"])
+    want, _ = dense_layer(p, 2, bias=False)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert int(counts.sum()) == 2 * T

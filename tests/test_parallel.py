@@ -164,33 +164,6 @@ def test_pipeline_matches_sequential():
                                atol=1e-5)
 
 
-def test_moe_expert_parallel_matches_local():
-    mesh = par.create_mesh({"ep": 4}, devices=jax.devices()[:4])
-    rng = np.random.RandomState(6)
-    T, D, Dh, E = 16, 8, 16, 8       # 2 experts per device
-    x = jnp.asarray(rng.randn(T, D), jnp.float32)
-    router_w = jnp.asarray(rng.randn(D, E), jnp.float32)
-    w1 = jnp.asarray(rng.randn(E, D, Dh) / np.sqrt(D), jnp.float32)
-    w2 = jnp.asarray(rng.randn(E, Dh, D) / np.sqrt(Dh), jnp.float32)
-
-    from mxnet_tpu.parallel.moe import moe_ffn
-    # capacity ample so nothing is dropped -> must equal dense routing
-    fn = functools.partial(moe_ffn, axis_name="ep", capacity_factor=8.0)
-    got = par.shard_map(
-        fn, mesh=mesh,
-        in_specs=(P(), P(), P("ep"), P("ep")), out_specs=P(),
-        check_vma=False)(x, router_w, w1, w2)
-
-    gates = jax.nn.softmax(x @ router_w, -1)
-    eidx = jnp.argmax(gates, -1)
-    gval = jnp.take_along_axis(gates, eidx[:, None], 1)[:, 0]
-    h = jax.nn.gelu(jnp.einsum("td,edh->teh", x, w1))
-    per_expert = jnp.einsum("teh,ehd->ted", h, w2)
-    want = (per_expert[jnp.arange(T), eidx] * gval[:, None])
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-4)
-
-
 def test_collectives_roundtrip():
     mesh = par.create_mesh({"dp": 8})
     x = jnp.arange(8.0)
