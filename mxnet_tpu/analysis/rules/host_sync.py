@@ -74,7 +74,8 @@ _SCOPES = (
     # recording methods (check / observe_loss / norm add+commit /
     # step_boundary) run inside executor forward/backward, Trainer
     # _update and the sharded step — they dispatch lazy reduces ONLY;
-    # folding reads long-retired buffers, and the sanctioned read
+    # folding reads retired buffers (the trainer probe's table one
+    # step late, in _fold_tables), and the sanctioned read
     # points (flush, snapshot_doc, nan_postmortem, the first-NaN
     # localizer) stay off this list by design
     ("mxnet_tpu/profiling/",
@@ -83,7 +84,7 @@ _SCOPES = (
       "group_by_op", "tag_role", "tag_tree", "role_of",
       "check", "check_scalar", "observe_loss", "_nonfinite_count",
       "_accumulate", "add", "commit", "step_probe", "step_boundary",
-      "_fold_entries", "_fold_loss", "_trip",
+      "_fold_entries", "_fold_tables", "_fold_loss", "_trip",
       "live_census", "buffer_intervals", "build_memory_ledger",
       "group_buffers_by_op", "_sweep_peak",
       "classify_spans", "collect", "_clip", "_overlap_ns",

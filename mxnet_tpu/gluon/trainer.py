@@ -110,7 +110,10 @@ class Trainer:
             # health boundary INSIDE the step span: lagged loss-EWMA /
             # grad-norm / nonfinite attrs land on the span so
             # trace_merge can name the rank that went unhealthy; a
-            # MXTPU_HEALTH=raise trip surfaces here, at the boundary
+            # MXTPU_HEALTH=raise trip surfaces here, at the boundary.
+            # Its one read-back, of the previous step's probe table,
+            # is also all that keeps this loop from running more than
+            # a step ahead of the device
             with _tracing.span("trainer.health"):
                 _health.step_boundary("trainer", span=sp)
         # one boundary per optimizer step: charges the data/comm/compile
@@ -156,11 +159,11 @@ class Trainer:
         from ..profiling import health as _health
         # one probe per step: the post-allreduce gradients, updated
         # weights and (for update-to-weight ratios) the pre-update
-        # weights — updates are functional and donate nothing, so the
+        # weights — updates are functional and donate no weight, so the
         # old array stays reachable with no copy. commit() is ONE cached
         # jitted dispatch covering the sentry counts AND the norm
-        # telemetry; the per-index Updater check is suppressed
-        # underneath it.
+        # telemetry, read back by the next step's boundary; the
+        # per-index Updater check is suppressed underneath it.
         probe = _health.step_probe()
         with _tracing.span("trainer.update"), _health.updater_covered():
             live = []

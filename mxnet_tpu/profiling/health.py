@@ -16,19 +16,35 @@ accumulate-on-device / drain-at-read pattern in four layers:
    trees in; the sentry dispatches ONE fused nonfinite-count reduce
    per seam (a lazy device scalar, never read here) into a bounded
    pending window. :func:`step_boundary` folds only entries older
-   than the window — dispatched many steps ago, so ``float()`` is a
-   ready-buffer read, not a pipeline stall. A nonzero fold *trips*
+   than the window — dispatched many steps ago, so ``int()`` is a
+   ready-buffer read, not a pipeline stall (the gluon trainer's two
+   counts travel in its probe's table instead: layer 2). A nonzero
+   fold *trips*
    the sentry: the first-NaN postmortem is written and the configured
    policy (warn / raise) applies.
 
 2. **Training-health telemetry**: global grad norm, per-parameter-
-   group weight/grad norms and update-to-weight ratios — computed as
-   lazy device scalars in ``Trainer._update`` and handed to the
-   ``mx_health_*`` gauge/histogram families via ``set_lazy`` /
-   ``observe_lazy`` (telemetry folds them at snapshot time). Loss
-   lands through :func:`observe_loss` and feeds an EWMA with z-score
-   **spike** and flat-line **plateau** anomaly detection on the folded
-   (host) values.
+   group weight/grad norms and update-to-weight ratios, and the
+   trainer seam's two nonfinite counts — computed by ONE jitted
+   program in ``Trainer._update`` (:class:`StepProbe`) that returns
+   ONE float32 table: the counts, the global norm, then a
+   ``[groups, 3]`` block. ``commit()`` dispatches it and queues the
+   table; nothing of the step is read there. :func:`step_boundary`
+   folds the table dispatched ONE boundary earlier (``_TABLE_LAG``)
+   with a single read-back and publishes everything from host
+   floats: trips for ``trainer_grad`` / ``trainer_param`` under the
+   table's own step, the ``mx_health_*`` gauges by ``set()``, one
+   ``mx_health_update_to_weight`` observation per group and step.
+   Between folds a gauge shows the step before last; ``flush()``
+   folds what is pending. The lag is one step on purpose: the host
+   never waits on the step it has just dispatched, a trip stays
+   within a step of its cause, and **this read is the only thing
+   that bounds a gluon loop's run-ahead** — a device-bound loop
+   waits here for step n-1, and with no wait at all it queues steps
+   until device memory runs out. Loss lands through
+   :func:`observe_loss` and feeds an EWMA with z-score **spike** and
+   flat-line **plateau** anomaly detection on the folded (host)
+   values.
 
 3. **First-NaN postmortem** (:func:`nan_postmortem`): the memory
    axis's OOM postmortem, for numerics. When the sentry trips at an
@@ -77,6 +93,25 @@ NAN_POSTMORTEM_VERSION = 1
 # in-flight compute (metric.py's _PENDING_WINDOW rationale, counted
 # in steps here because one step may hold many per-source checks)
 _FOLD_LAG = 4
+
+# the trainer probe's table folds ONE boundary after its dispatch: at
+# the boundary of step n the host fetches step n-1's table. One, on
+# purpose: the host never waits on the step it has just dispatched, a
+# trip or a raise stays within a step of its cause, and this fetch is
+# the only thing that bounds how far a gluon loop runs ahead of the
+# device (a device-bound loop waits here for step n-1; with no wait at
+# all it queues steps until device memory runs out)
+_TABLE_LAG = 1
+
+# tables a loop may queue without reaching a boundary (Trainer.update
+# called alone never does): commit() folds the overflow, each a read
+# of a program dispatched this many commits ago
+_MAX_TABLES = 8
+
+# a nonfinite count crosses in the float32 table as two 16-bit halves,
+# each exact in float32, so counts past 2**24 fold exact
+_COUNT_HALF = 1 << 16
+_TABLE_HEAD = 4          # grad count hi, lo; param count hi, lo
 
 
 class NonfiniteError(ArithmeticError):
@@ -233,7 +268,7 @@ class _HealthState:
         # last folded norm table {group: {...}} + global grad norm
         self.norm_groups = {}
         self.grad_norm = None
-        self.norm_pending = []           # [(step, lazy outs)] un-folded
+        self.norm_pending = []           # [(step, groups, table)]
         self.last_doc = None             # most recent postmortem doc
 
 
@@ -319,17 +354,15 @@ def _fold_entries(entries, boundary=None):
                 n = int(scalar)
             except (TypeError, ValueError, OverflowError):
                 continue
-            if n <= 0:
-                continue
-            with _state.lock:
-                localize = _state.latest_loc.get(source)
-            _trip(step, source, n, localize, boundary=boundary)
+            if n > 0:
+                _trip(step, source, n, boundary=boundary)
 
 
-def _trip(step, source, count, localize, boundary=None):
+def _trip(step, source, count, boundary=None):
     st = _state
     tm, met = _met()
     with st.lock:
+        localize = st.latest_loc.get(source)
         st.nonfinite_total += count
         st.by_source[source] = st.by_source.get(source, 0) + count
         first = st.first_trip is None
@@ -430,23 +463,54 @@ def group_of(name):
 
 
 @functools.lru_cache(maxsize=64)
+def _plan(names):
+    """``names`` (a trainer's leaves, in order) -> (group names in
+    first-occurrence order, each leaf's group as an index into them).
+    Resolved once per tuple of names: a loop hands the same one every
+    step."""
+    index = {}
+    group_idx = tuple(index.setdefault(group_of(n), len(index))
+                      for n in names)
+    return tuple(index), group_idx
+
+
+@functools.lru_cache(maxsize=64)
+def _group_series(groups):
+    """[(weight, grad, ratio) gauge series] per group of ``groups``.
+    Series are zeroed in place by a registry reset, so the handles
+    stay valid for the process."""
+    _tm, met = _met()
+    return [(met["group_weight"].labels(group=g),
+             met["group_grad"].labels(group=g),
+             met["group_ratio"].labels(group=g)) for g in groups]
+
+
+@functools.lru_cache(maxsize=64)
 def _probe_program(group_idx, want_norms):
-    """One jitted program computing the WHOLE per-step probe: grad and
-    weight nonfinite counts plus (``want_norms``) per-group weight/
-    grad norms, global grad norm and update-to-weight ratios.
+    """One jitted program computing the WHOLE per-step probe and
+    returning it as ONE float32 table, so a fold is one read-back:
+
+        [grad_nf hi, lo, param_nf hi, lo]          (_TABLE_HEAD)
+        [grad_norm]                                 (``want_norms``)
+        [weight_norm, grad_norm, update_ratio] * G  (``want_norms``)
+
     ``group_idx`` is the parameter→group partition as INDICES (not
     names — two nets whose layers differ only in auto-generated name
     counters share one executable; jit itself re-specializes on leaf
-    shapes/dtypes). After the first step this is ONE cached dispatch
-    per step — XLA fuses the dozens of tiny reduces the eager version
-    would dispatch one by one."""
+    shapes/dtypes), group g's row at 3g. After the first step this is
+    ONE cached dispatch per step — XLA fuses the dozens of tiny
+    reduces the eager version would dispatch one by one."""
     import jax
     import jax.numpy as jnp
+
+    def halves(count):
+        return [(count // _COUNT_HALF).astype(jnp.float32),
+                (count % _COUNT_HALF).astype(jnp.float32)]
 
     def fn(ws, gs, olds):
         gnf = pnf = jnp.int32(0)
         total_g2 = jnp.float32(0)
-        acc = {}
+        acc = [[jnp.float32(0)] * 3 for _ in range(max(group_idx) + 1)]
         for gi, w, g, old in zip(group_idx, ws, gs, olds):
             w32 = w.astype(jnp.float32)
             g32 = g.astype(jnp.float32)
@@ -458,20 +522,18 @@ def _probe_program(group_idx, want_norms):
                 u32 = w32 - old.astype(jnp.float32)
                 u2 = jnp.sum(u32 * u32)
                 total_g2 = total_g2 + g2
-                a = acc.setdefault(gi, [jnp.float32(0)] * 3)
+                a = acc[gi]
                 a[0] = a[0] + w2
                 a[1] = a[1] + g2
                 a[2] = a[2] + u2
-        out = {"grad_nf": gnf, "param_nf": pnf}
+        table = halves(gnf) + halves(pnf)
         if want_norms:
-            out["grad_norm"] = jnp.sqrt(total_g2)
-            out["groups"] = {
-                gi: {"weight_norm": jnp.sqrt(a[0]),
-                     "grad_norm": jnp.sqrt(a[1]),
-                     "update_ratio": jnp.sqrt(a[2]) / jnp.maximum(
-                         jnp.sqrt(a[0]), 1e-12)}
-                for gi, a in acc.items()}
-        return out
+            table.append(jnp.sqrt(total_g2))
+            for a in acc:
+                table += [jnp.sqrt(a[0]), jnp.sqrt(a[1]),
+                          jnp.sqrt(a[2]) / jnp.maximum(
+                              jnp.sqrt(a[0]), 1e-12)]
+        return jnp.stack(table)
 
     return jax.jit(fn)
 
@@ -479,10 +541,9 @@ def _probe_program(group_idx, want_norms):
 class StepProbe:
     """Per-step probe over a trainer's (weight, grad, pre-update
     weight) triples. ``add`` is a python list append; ``commit``
-    runs the cached jitted probe program — one dispatch — banks the
-    nonfinite counts into the ``trainer_grad``/``trainer_param``
-    sentry buckets, hands the lazy norms to the gauges, and queues
-    them for the lagged host fold at the boundary."""
+    runs the cached jitted probe program — one dispatch — and queues
+    its one table for the boundary that folds it, ``_TABLE_LAG``
+    steps later. Nothing of a step is read here."""
 
     __slots__ = ("_names", "_ws", "_gs", "_olds", "step", "_norms")
 
@@ -512,44 +573,23 @@ class StepProbe:
         if not self._ws:
             return
         want = self._norms and all(o is not None for o in self._olds)
-        groups = []           # first-occurrence order
-        group_idx = []
-        for n in self._names:
-            grp = group_of(n)
-            if grp not in groups:
-                groups.append(grp)
-            group_idx.append(groups.index(grp))
-        olds = self._olds if want else [w for w in self._ws]
+        groups, group_idx = _plan(tuple(self._names))
         try:
-            outs = _probe_program(tuple(group_idx), want)(
-                self._ws, self._gs, olds)
+            table = _probe_program(group_idx, want)(
+                self._ws, self._gs, self._olds if want else self._ws)
         except Exception:  # noqa: BLE001 — an unjittable leaf (host
             # numpy of odd dtype) degrades to the plain sentry count
             check("trainer_grad", self._gs)
             check("trainer_param", self._ws)
             return
-        _accumulate("trainer_grad", outs["grad_nf"])
-        _accumulate("trainer_param", outs["param_nf"])
-        if not want:
-            return
-        named = {groups[gi]: e for gi, e in outs["groups"].items()}
-        outs = {"grad_nf": outs["grad_nf"],
-                "param_nf": outs["param_nf"],
-                "grad_norm": outs["grad_norm"], "groups": named}
-        tm, met = _met()
-        if tm.enabled():
-            met["grad_norm"].set_lazy(outs["grad_norm"])
-            for grp, e in named.items():
-                met["group_weight"].labels(group=grp).set_lazy(
-                    e["weight_norm"])
-                met["group_grad"].labels(group=grp).set_lazy(
-                    e["grad_norm"])
-                met["group_ratio"].labels(group=grp).set_lazy(
-                    e["update_ratio"])
-                met["ratio_hist"].observe_lazy(e["update_ratio"])
-        with _state.lock:
-            _state.norm_pending.append((self.step, outs))
-            del _state.norm_pending[:-8]
+        st = _state
+        with st.lock:
+            st.norm_pending.append(
+                (self.step, groups if want else None, table))
+            over = st.norm_pending[:-_MAX_TABLES]
+            del st.norm_pending[:-_MAX_TABLES]
+        # only a loop that never reaches a boundary gets here
+        _fold_tables(over)
 
 
 def step_probe(step=None):
@@ -581,48 +621,57 @@ def updater_covered():
         _covered.depth -= 1
 
 
-def _fold_norms(all_pending=True, horizon=None):
-    """Fold queued lazy norm tables into host floats. At a boundary
-    only tables >= _FOLD_LAG steps old fold (ready buffers); read
-    paths (flush/postmortem) fold everything — syncs are the
-    contract there."""
+def _fold_tables(entries, boundary=None):
+    """Fold queued probe tables, oldest first, with ONE read-back
+    each. Everything the trainer seam publishes is set here from host
+    floats: the per-group norm table and the global gradient norm
+    (state and gauges: the newest folded wins), one histogram
+    observation per group and folded step, and a trip for a nonzero
+    ``trainer_grad`` / ``trainer_param`` count under the table's own
+    step. The norms land before the trip, so its postmortem ranks the
+    failing step's gradients."""
+    if not entries:
+        return
+    import jax
+
     st = _state
-    with st.lock:
-        if all_pending:
-            ready, st.norm_pending = st.norm_pending, []
-        else:
-            ready = [e for e in st.norm_pending if e[0] < horizon]
-            if ready:
-                st.norm_pending = st.norm_pending[len(ready):]
-    if not ready:
-        return
-    _step, outs = ready[-1]     # gauge semantics: newest wins
-    if "groups" not in outs:
-        return
-    groups = {}
-    for grp, entry in outs["groups"].items():
-        row = {}
-        for k, v in entry.items():
-            try:
-                row[k] = float(v)
-            except (TypeError, ValueError, OverflowError):
-                continue
-        groups[grp] = row
-    with st.lock:
-        st.norm_groups = groups
-        try:
-            st.grad_norm = float(outs["grad_norm"])
-        except (TypeError, ValueError, OverflowError, KeyError):
-            pass
+    tm, met = _met()
+    for step, groups, table in entries:
+        host = jax.device_get(table).tolist()  # the step's one read-back
+        if groups is not None:
+            body = host[_TABLE_HEAD + 1:]
+            rows = [body[i:i + 3] for i in range(0, len(body), 3)]
+            with st.lock:
+                st.grad_norm = host[_TABLE_HEAD]
+                st.norm_groups = {
+                    grp: {"weight_norm": w, "grad_norm": g,
+                          "update_ratio": r}
+                    for grp, (w, g, r) in zip(groups, rows)}
+            if tm.enabled():
+                met["grad_norm"].set(host[_TABLE_HEAD])
+                for (sw, sg, sr), (w, g, r) in zip(
+                        _group_series(groups), rows):
+                    sw.set(w)
+                    sg.set(g)
+                    sr.set(r)
+                    met["ratio_hist"].observe(r)
+        for source, hi, lo in (("trainer_grad", host[0], host[1]),
+                               ("trainer_param", host[2], host[3])):
+            n = int(hi) * _COUNT_HALF + int(lo)
+            if n > 0:
+                _trip(step, source, n, boundary=boundary)
 
 
 # -- boundaries / folding ---------------------------------------------------
 def step_boundary(source="trainer", span=None):
     """Close one health step: bank this step's per-source buckets,
-    fold every banked bucket ≥ _FOLD_LAG boundaries old (ready
-    buffers — their reduces retired steps ago), and stamp lagged
-    health attrs on the caller's step ``span`` so trace_merge can
-    show which rank went unhealthy. Trips (and the raise policy)
+    fold the trainer probe's table dispatched ``_TABLE_LAG`` boundaries
+    ago with one read-back (the loop's back-pressure: the host waits
+    here until the device has finished that step, never the one in
+    flight), fold every banked bucket ≥ _FOLD_LAG boundaries old
+    (ready buffers — their reduces retired steps ago), and stamp the
+    folded health attrs on the caller's step ``span`` so trace_merge
+    can show which rank went unhealthy. Trips (and the raise policy)
     surface HERE, at the boundary, never inside a seam's dispatch
     path."""
     if not enabled():
@@ -640,23 +689,29 @@ def step_boundary(source="trainer", span=None):
         loss_ready = [e for e in st.loss_pending if e[0] < horizon]
         if loss_ready:
             st.loss_pending = st.loss_pending[len(loss_ready):]
-        # span attrs are LAGGED host state (previous folds) — reading
-        # them costs nothing; the fresh entries fold below. Only
-        # FINITE values land: span attrs flow verbatim into chrome
-        # trace event args, where a bare NaN literal would make
-        # Perfetto reject the whole document (the nonfinite signal
-        # itself rides health_nonfinite)
-        if span is not None:
-            span.set_attr("health_nonfinite", st.nonfinite_total)
-            for key, v in (("loss_ewma", st.loss_ewma),
-                           ("grad_norm", st.grad_norm)):
-                if v is not None and v == v and \
-                        v not in (float("inf"), float("-inf")):
-                    span.set_attr(key, round(v, 6))
+        tables = [e for e in st.norm_pending
+                  if e[0] < st.step - _TABLE_LAG]
+        if tables:
+            st.norm_pending = st.norm_pending[len(tables):]
     for step, v in loss_ready:
         _fold_loss(step, v)
-    _fold_norms(all_pending=False, horizon=horizon)
+    _fold_tables(tables, boundary=source)
     _fold_entries(ready, boundary=source)
+    if span is not None:
+        # host state as of the folds above. Only FINITE values land:
+        # span attrs flow verbatim into chrome trace event args, where
+        # a bare NaN literal would make Perfetto reject the whole
+        # document (the nonfinite signal itself rides
+        # health_nonfinite)
+        with st.lock:
+            total = st.nonfinite_total
+            attrs = (("loss_ewma", st.loss_ewma),
+                     ("grad_norm", st.grad_norm))
+        span.set_attr("health_nonfinite", total)
+        for key, v in attrs:
+            if v is not None and v == v and \
+                    v not in (float("inf"), float("-inf")):
+                span.set_attr(key, round(v, 6))
     if policy() == "raise":
         with st.lock:
             fresh = st.nonfinite_total > st.raised_total
@@ -686,10 +741,11 @@ def flush():
             st.open = {}
         ready, st.pending = st.pending, []
         loss_ready, st.loss_pending = st.loss_pending, []
+        tables, st.norm_pending = st.norm_pending, []
     for step, v in loss_ready:
         _fold_loss(step, v)
+    _fold_tables(tables, boundary="flush")
     _fold_entries(ready, boundary="flush")
-    _fold_norms()
     return snapshot_doc(fold=False)
 
 
@@ -874,7 +930,8 @@ def nan_postmortem(step=None, source=None, count=None, error=None,
         except Exception as e:  # noqa: BLE001 — replay can itself NaN out
             doc["first_op_error"] = repr(e)[:200]
     try:
-        _fold_norms()
+        # folded state only: a trainer trip folds its own step's norm
+        # table just before it lands here
         summary = snapshot_doc(fold=False)
         doc["loss"] = summary["loss"]
         norms = summary["norms"]

@@ -420,12 +420,41 @@ def test_armed_monitor_adds_zero_syncs_to_training_step(monkeypatch):
     assert any("fc_output" in n for n in names)
 
 
-def test_gluon_trainer_step_no_syncs_with_health_armed(monkeypatch):
-    """Trainer.step with the full health plane on: zero host syncs
-    (the sentry/norm instrumentation is dispatch-only)."""
-    import jax
-    from mxnet_tpu.ndarray.ndarray import NDArray
+def _count_reads(monkeypatch):
+    """Every read of a device array's value — ``float()``, ``int()``,
+    ``tolist()``, ``jax.device_get``, ``__array__`` (all ``np.asarray``
+    has on a TPU; on the CPU it takes the buffer protocol instead) —
+    goes through the property ``ArrayImpl._value``. Returns the list
+    the patched property appends (phase, array) to, and
+    ``phase(name)``, a context manager naming the part of the step a
+    read happens in."""
+    import contextlib
+    from jax._src import array as jarray
 
+    real = jarray.ArrayImpl._value
+    reads, where = [], ["elsewhere"]
+
+    def fget(self):
+        reads.append((where[0], self))
+        return real.fget(self)
+
+    @contextlib.contextmanager
+    def phase(name):
+        prev, where[0] = where[0], name
+        try:
+            yield
+        finally:
+            where[0] = prev
+
+    monkeypatch.setattr(jarray.ArrayImpl, "_value", property(fget))
+    return reads, phase
+
+
+def test_gluon_trainer_step_no_syncs_with_health_armed(monkeypatch):
+    """Trainer.step with the full health plane on reads ONE device
+    array a step: nothing inside ``commit()``, and at the boundary the
+    table dispatched ``_TABLE_LAG`` steps before — never the one of the
+    step in flight."""
     net = gluon.nn.Dense(4)
     net.initialize(force_reinit=True)
     trainer = gluon.Trainer(net.collect_params(), "sgd",
@@ -434,32 +463,197 @@ def test_gluon_trainer_step_no_syncs_with_health_armed(monkeypatch):
     X = nd.array(rs.rand(16, 8).astype("float32"))
     Y = nd.array(rs.rand(16, 4).astype("float32"))
     loss_fn = gluon.loss.L2Loss()
-    # warm one step (jit compiles, kvstore init) outside the gate
-    with autograd.record():
-        loss = loss_fn(net(X), Y)
-    loss.backward()
-    trainer.step(16)
+    assert health._TABLE_LAG == 1
 
-    counts = {"sync": 0}
-    real_asnumpy = NDArray.asnumpy
-    real_device_get = jax.device_get
+    reads, phase = _count_reads(monkeypatch)
+    real_commit = health.StepProbe.commit
+    real_boundary = health.step_boundary
 
-    def c_asnumpy(self):
-        counts["sync"] += 1
-        return real_asnumpy(self)
+    def commit(self):
+        with phase("commit"):
+            return real_commit(self)
 
-    def c_device_get(x):
-        counts["sync"] += 1
-        return real_device_get(x)
+    def boundary(*a, **k):
+        with phase("boundary"):
+            return real_boundary(*a, **k)
 
-    with autograd.record():
-        loss = loss_fn(net(X), Y)
-    loss.backward()
-    health.observe_loss(loss.mean())
-    monkeypatch.setattr(NDArray, "asnumpy", c_asnumpy)
-    monkeypatch.setattr(jax, "device_get", c_device_get)
-    trainer.step(16)
-    assert counts["sync"] == 0
+    monkeypatch.setattr(health.StepProbe, "commit", commit)
+    monkeypatch.setattr(health, "step_boundary", boundary)
+
+    def pending_tables():
+        with health._state.lock:
+            return [t for _, _, t in health._state.norm_pending]
+
+    for step in range(4):
+        with autograd.record():
+            loss = loss_fn(net(X), Y)
+        loss.backward()
+        health.observe_loss(loss.mean())
+        before = pending_tables()
+        del reads[:]
+        with phase("step"):
+            trainer.step(16)
+        after = pending_tables()
+        assert len(after) == 1            # this step's, still unread
+        assert [ph for ph, _ in reads if ph != "boundary"] == []
+        folded = [arr for ph, arr in reads if ph == "boundary"]
+        if step < health._TABLE_LAG:
+            # (the first step also compiles; nothing is old enough)
+            assert folded == [] and before == []
+        else:
+            assert len(folded) == 1 and folded[0] is before[0]
+        assert all(arr is not after[0] for _, arr in reads)
+    # flush reads what is left: the last step's table and the losses
+    del reads[:]
+    health.flush()
+    assert sum(arr is after[0] for _, arr in reads) == 1
+
+
+# the probe's table against numpy: (leaves, their shapes, dtype,
+# optimizer, its parameters, MXTPU_HEALTH_NORMS)
+_TABLE_CASES = {
+    "one_group": (["fc_weight", "fc_bias"], [(5, 3), (5,)], "float32",
+                  "sgd", {"learning_rate": 0.1, "momentum": 0.9}, True),
+    # more groups than telemetry's pending window of 64 device scalars
+    "107_groups": (["l%d_weight" % i for i in range(107)],
+                   [(2, 3)] * 107, "float32",
+                   "sgd", {"learning_rate": 0.1, "wd": 1e-3}, True),
+    "norms_off": (["a_weight", "a_bias", "b_weight"],
+                  [(4, 4), (4,), (3, 4)], "float32",
+                  "sgd", {"learning_rate": 0.1}, False),
+    "bf16_multi_precision_adam": (
+        ["a_weight", "a_bias", "b_weight"], [(8, 8), (8,), (3, 8)],
+        "bfloat16", "adam",
+        {"learning_rate": 0.01, "multi_precision": True}, True),
+}
+
+
+def _table_trainer(case):
+    from mxnet_tpu.gluon.parameter import Parameter
+
+    names, shapes, dtype, opt, opt_params, norms = _TABLE_CASES[case]
+    health.set_norms_enabled(norms)
+    params = [Parameter(n, shape=s, dtype=dtype)
+              for n, s in zip(names, shapes)]
+    for p in params:
+        p.initialize(mx.init.Uniform(0.5))
+    return params, gluon.Trainer(params, opt, dict(opt_params)), norms
+
+
+def _f32(arr):
+    return np.asarray(arr.asnumpy(), dtype=np.float32).astype(np.float64)
+
+
+def _set_grads(params, rs, poison=None):
+    """Write a fresh random gradient into every leaf; ``poison`` =
+    (leaf index, how many values become NaN)."""
+    for i, p in enumerate(params):
+        g = rs.standard_normal(p.shape).astype("float32")
+        if poison is not None and poison[0] == i:
+            g.reshape(-1)[:poison[1]] = np.nan
+        p.grad()[:] = nd.array(g).astype(p.dtype)
+
+
+@pytest.mark.parametrize("case", sorted(_TABLE_CASES))
+def test_probe_table_folds_to_what_numpy_reads(case):
+    """After k steps and ``flush()``: the norm table is numpy's on the
+    last step's arrays, the histogram holds one observation per group
+    and step, the gauges show the last step."""
+    metrics.registry().reset()
+    params, trainer, norms = _table_trainer(case)
+    rs = np.random.RandomState(11)
+    k = 3
+    for _ in range(k):
+        _set_grads(params, rs)
+        olds = [_f32(p.data()) for p in params]
+        trainer.step(2)
+    doc = health.flush()
+    assert doc["sentry"]["verdict"] == "clean"
+    snap = export.snapshot()["metrics"]
+    hist = snap["mx_health_update_to_weight"]["series"]
+    if not norms:
+        assert doc["norms"] == {"grad_norm": None, "by_group": {}}
+        assert sum(s["count"] for s in hist) == 0
+        return
+    want = {}
+    for p, old in zip(params, olds):
+        w, g = _f32(p.data()), _f32(p.grad())
+        acc = want.setdefault(health.group_of(p.name), [0.0, 0.0, 0.0])
+        acc[0] += (w * w).sum()
+        acc[1] += (g * g).sum()
+        acc[2] += ((w - old) ** 2).sum()
+    got = doc["norms"]["by_group"]
+    assert list(got) == list(want)        # first-occurrence order
+    for grp, (w2, g2, u2) in want.items():
+        assert got[grp]["weight_norm"] == pytest.approx(w2 ** 0.5, rel=1e-5)
+        assert got[grp]["grad_norm"] == pytest.approx(g2 ** 0.5, rel=1e-5)
+        assert got[grp]["update_ratio"] == pytest.approx(
+            (u2 / w2) ** 0.5, rel=1e-4)
+        assert got[grp]["update_ratio"] > 0
+    assert doc["norms"]["grad_norm"] == pytest.approx(
+        sum(g2 for _, g2, _ in want.values()) ** 0.5, rel=1e-5)
+    assert sum(s["count"] for s in hist) == len(want) * k
+    ratio = {s["labels"]["group"]: s["value"]
+             for s in snap["mx_health_update_ratio"]["series"]}
+    # (a registry reset zeroes other tests' series, it keeps them)
+    assert {g: ratio[g] for g in got} == \
+        {g: v["update_ratio"] for g, v in got.items()}
+    assert snap["mx_health_grad_norm"]["series"][0]["value"] == \
+        doc["norms"]["grad_norm"]
+
+
+@pytest.mark.parametrize("case", sorted(_TABLE_CASES))
+def test_probe_table_trips_with_the_exact_count_at_the_poisoned_step(case):
+    """Three NaNs planted in one leaf's gradient at step 2: the count
+    is exact and carries that step; under ``raise`` the error surfaces
+    at the next boundary, ``_TABLE_LAG`` + 1 counting the step's own."""
+    params, trainer, _ = _table_trainer(case)
+    rs = np.random.RandomState(12)
+    leaf = len(params) - 1
+    for step in range(5):
+        _set_grads(params, rs, poison=(leaf, 3) if step == 2 else None)
+        trainer.step(2)
+        seen = health.snapshot_doc(fold=False)["sentry"]
+        if step < 2 + health._TABLE_LAG:
+            assert seen["nonfinite_total"] == 0
+        else:
+            assert seen["by_source"]["trainer_grad"] == 3
+    doc = health.flush()["sentry"]
+    assert doc["by_source"]["trainer_grad"] == 3
+    # the three weights they updated went NaN and stay so: steps 2, 3, 4
+    assert doc["by_source"]["trainer_param"] == 3 * 3
+    assert doc["first_trip"]["step"] == 2
+    assert doc["first_trip"]["source"] == "trainer_grad"
+    assert doc["first_trip"]["folded_by"] == "trainer"
+
+    health.reset()
+    health.set_enabled("raise")
+    params, trainer, _ = _table_trainer(case)
+    boundaries = 0
+    with pytest.raises(health.NonfiniteError) as ei:
+        for step in range(5):
+            _set_grads(params, rs, poison=(leaf, 3) if step == 2 else None)
+            boundaries = step
+            trainer.step(2)
+    assert boundaries == 2 + health._TABLE_LAG
+    assert "trainer_grad" in str(ei.value) and "step 2" in str(ei.value)
+
+
+def test_tables_stay_bounded_in_a_loop_without_a_boundary():
+    """``Trainer.update`` alone never reaches a boundary: the queue
+    stays at ``_MAX_TABLES`` and the overflow's counts are folded, not
+    dropped."""
+    params, trainer, _ = _table_trainer("one_group")
+    rs = np.random.RandomState(13)
+    n = health._MAX_TABLES + 3
+    for i in range(n):
+        _set_grads(params, rs, poison=(0, 2) if i == 0 else None)
+        trainer.update(2)
+    with health._state.lock:
+        assert len(health._state.norm_pending) == health._MAX_TABLES
+    assert health.snapshot_doc(fold=False)["sentry"]["by_source"][
+        "trainer_grad"] == 2
+    assert health.flush()["sentry"]["by_source"]["trainer_grad"] == 2
 
 
 def test_mxlint_health_scope_clean():
