@@ -16,3 +16,17 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8").strip()
+
+
+def pytest_configure(config):
+    """``pytest tests/`` (the tier-1 command) also collects the tests of
+    the benchmark that judges every PR, each case as a test of its own,
+    with their own ``conftest.py``. They come first so that the longest
+    file (the harness end to end, minutes on one worker) overlaps the
+    rest of the run. Every xdist worker runs this hook again on the
+    controller's arguments, hence the second condition."""
+    root = os.path.dirname(os.path.abspath(__file__))
+    bench_tests = os.path.join(root, "benchmark", "tests")
+    args = [os.path.abspath(a) for a in config.args]
+    if os.path.join(root, "tests") in args and bench_tests not in args:
+        config.args.insert(0, bench_tests)

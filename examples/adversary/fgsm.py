@@ -89,7 +89,12 @@ def main():
     args = ap.parse_args()
 
     t0 = time.time()
-    rng = np.random.default_rng(0)
+    # initializers draw from numpy's global stream: seed it, the
+    # framework's key chain and the data draw alike
+    seed = int(os.environ.get("MXNET_TEST_SEED", "0"))
+    np.random.seed(seed)
+    mx.random.seed(seed)
+    rng = np.random.default_rng(seed)
     net = build_net()
     net.initialize(mx.init.Xavier())
     net.hybridize()

@@ -40,7 +40,7 @@ def score(network, batch, num_iters=20, warmup=3):
     out = None
     for _ in range(num_iters):
         out = fwd(pvals, data)
-    float(reduce_fn(out))  # device fence (see bench.py measure())
+    float(reduce_fn(out))  # device fence: one sync bounds the queued chain
     dt = time.perf_counter() - t0
     return batch * num_iters / dt
 

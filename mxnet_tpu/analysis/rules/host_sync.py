@@ -63,12 +63,11 @@ _SCOPES = (
     ("mxnet_tpu/tracing/",
      {"__enter__", "__exit__", "span", "span_at", "record_span",
       "set_attr", "heartbeat", "_touch", "_observe_span"}, set()),
-    # profiling recorders: ledger pricing and the xplane join run on
+    # profiling recorders: ledger pricing and the xplane reader run on
     # artifacts AFTER measurement — a device sync creeping into them
-    # would perturb the very steps they attribute (attribution_run's
-    # per-step fence is the one sanctioned sync, and lives outside
-    # these methods). The PR 7 memory recorders join the list: role
-    # tagging runs inside optimizer updates and io __next__, and the
+    # would perturb the very steps they attribute. The PR 7 memory
+    # recorders join the list: role tagging runs inside optimizer
+    # updates and io __next__, and the
     # census reads shard METADATA only — an asnumpy in either would
     # stall every tagged hot path at once. The model-health sentry's
     # recording methods (check / observe_loss / norm add+commit /
@@ -79,8 +78,8 @@ _SCOPES = (
     # points (flush, snapshot_doc, nan_postmortem, the first-NaN
     # localizer) stay off this list by design
     ("mxnet_tpu/profiling/",
-     {"build_ledger", "instr_cost", "measure_ops", "join",
-      "summarize", "mfu_estimate", "attribute_op_name",
+     {"build_ledger", "instr_cost", "measure_ops",
+      "mfu_estimate", "attribute_op_name",
       "group_by_op", "tag_role", "tag_tree", "role_of",
       "check", "check_scalar", "observe_loss", "_nonfinite_count",
       "_accumulate", "add", "commit", "step_probe", "step_boundary",

@@ -10,10 +10,9 @@ kernel family (elementwise, reduction, matmul/MXU, conv, norm, indexing,
 sorting, linalg, sequence, loss) plus one model-zoo forward — run on the
 real chip and compared against CPU jax within dtype-scaled tolerance.
 
-``bench.py`` folds ``run_sweep()`` into the driver bench so every chip
-window revalidates numerics (bf16 MXU matmul semantics, conv algorithm
-differences, int8 saturation) alongside throughput; the pass/fail tally
-ships in the bench JSON.
+``run_sweep()`` revalidates numerics on the chip (bf16 MXU matmul
+semantics, conv algorithm differences, int8 saturation) and returns the
+pass/fail tally.
 """
 from __future__ import annotations
 

@@ -588,7 +588,7 @@ def infer_shapes(block, *input_shapes, dtype=None):
     ``jax.eval_shape`` runs the eager path on shape tracers, so each
     layer's shape inference fires and deferred initializers materialize
     real (concrete — see ndarray._materialize) parameter arrays. This is
-    the shared warm-up used by bench.py, __graft_entry__.entry() and
+    the shared warm-up used by __graft_entry__.entry() and
     contrib.quantization.quantize_net; the reference's analogue is the
     deferred-init first pass of HybridBlock (gluon/block.py:860
     infer_shape)."""

@@ -86,7 +86,7 @@ The recovery half lives in :class:`BackoffSchedule` (exponential
 backoff with deterministic-seedable jitter under a total budget — the
 client-side retry clock, unit-testable on a fake clock) and
 :class:`RecoveryTelemetry` (what happened, surfaced through
-profiler.py so bench.py can report WHY a run degraded).
+profiler.py so a run can report WHY it degraded).
 """
 from __future__ import annotations
 

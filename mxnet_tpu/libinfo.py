@@ -150,35 +150,10 @@ _ENV_VARS = {
     "MXTPU_HANG_TIMEOUT_SEC": (
         ">0 arms the hang watchdog at flight-recorder install: a step "
         "with no span activity for this long dumps in-flight spans + "
-        "thread stacks (tracing/flight.py; bench.py arms it per run)"),
+        "thread stacks (tracing/flight.py)"),
     "MXTPU_FLIGHT_PATH": (
         "flight-recorder dump destination (atomic file write; default "
-        "stderr). bench.py points it at a per-run file it embeds in "
-        "failure JSON (tracing/flight.py)"),
-    "MXTPU_PROFILE_ATTRIB": (
-        "0 disables the performance-attribution passes: bench.py's "
-        "CPU cost-ledger subprocess and the post-capture xplane join "
-        "(default on; profiling/, bench.py)"),
-    "MXTPU_PROFILE_DIR": (
-        "base directory for jax.profiler attribution captures "
-        "(default <tmp>/mxtpu_profile; profiling/capture.py, "
-        "tools/mfu_report.py --capture)"),
-    "MXTPU_BENCH_BATCH": (
-        "bench harness batch size; the cost-ledger pass compiles its "
-        "stage programs at this batch (default 128; bench.py, "
-        "profiling/bench_ledger.py)"),
-    "MXTPU_LEDGER_OUT": (
-        "cost-ledger pass output path; bench.py points it at a "
-        "per-run file whose stage summaries every artifact embeds "
-        "(profiling/bench_ledger.py)"),
-    "MXTPU_LEDGER_STAGES": (
-        "comma-separated bench_ledger stages to compile+price "
-        "(default infer_bf16,train_bf16; 'tiny' is the seconds-fast "
-        "test stage; profiling/bench_ledger.py)"),
-    "MXTPU_LEDGER_DEADLINE_SEC": (
-        "how long bench.py waits for the cost-ledger subprocess "
-        "before killing it at final-artifact time (default 300; "
-        "bench.py)"),
+        "stderr; tracing/flight.py)"),
     "MXTPU_MEMORY_CENSUS": (
         "0 disables the live-array memory census: role tagging at the "
         "NDArray/optimizer/io seams and the mx_memory_* snapshot "
@@ -188,8 +163,7 @@ _ENV_VARS = {
         "OOM postmortem destination — an XLA RESOURCE_EXHAUSTED at "
         "the executor/trainer/sharded-step seams writes the ranked "
         "peak-liveness table + census + flight dump here (default "
-        "oom_postmortem.json; bench.py points it at a per-run file "
-        "it embeds in failure artifacts; profiling/memory.py)"),
+        "oom_postmortem.json; profiling/memory.py)"),
     "MXTPU_SERVING_MAX_WAIT_MS": (
         "default continuous-batcher coalescing window per model: a "
         "request never waits longer than this for batch-mates before "

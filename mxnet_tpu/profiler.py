@@ -99,38 +99,7 @@ def set_state(state="stop", profile_process="worker"):
         import jax
         jax.profiler.stop_trace()
         _xla_session = None
-        # the capture is now on disk: remember where, so
-        # last_xplane_dir()/op_attribution() can analyze it without
-        # the caller re-plumbing the directory
-        _last_xplane_dir[0] = _config["xla_trace_dir"]
     _state = state
-
-
-_last_xplane_dir = [None]
-
-
-def last_xplane_dir():
-    """The most recent completed ``xla_trace_dir`` capture (set_config
-    + set_state('run'→'stop')), or None."""
-    return _last_xplane_dir[0]
-
-
-def op_attribution(compiled=None, hlo_text=None, profile_dir=None,
-                   **kwargs):
-    """Measured per-op attribution for the last (or given) xplane
-    capture, joined against a cost ledger built from ``compiled`` /
-    ``hlo_text`` — the profiler-side door into
-    ``mxnet_tpu.profiling`` (docs/observability.md "MFU accounting &
-    roofline")."""
-    from . import profiling
-    profile_dir = profile_dir or last_xplane_dir()
-    if profile_dir is None:
-        raise MXNetError(
-            "no xplane capture recorded: run with "
-            "set_config(xla_trace_dir=...) + set_state('run'/'stop'), "
-            "or pass profile_dir=")
-    return profiling.analyze_dir(profile_dir, compiled=compiled,
-                                 hlo_text=hlo_text, **kwargs)
 
 
 def state():
@@ -234,10 +203,10 @@ def dumps(reset=False, format="table"):
 # -- kvstore recovery telemetry -------------------------------------------
 # The dist transport reports every recovery incident (reconnect storms,
 # budget exhaustions) here, independent of the run/stop profiling state —
-# bench.py needs to answer "WHY did this distributed run
-# degrade" even when nobody armed the profiler. When the profiler IS
-# running, each incident also lands in the chrome trace (category
-# "kvstore_recovery") so waits line up against the op timeline.
+# "WHY did this distributed run degrade" must be answerable even when
+# nobody armed the profiler. When the profiler IS running, each incident
+# also lands in the chrome trace (category "kvstore_recovery") so waits
+# line up against the op timeline.
 #
 # Since PR 4 the COUNTERS live on the telemetry metrics registry
 # (mx_recovery_* families, so they ride every snapshot/Prometheus
@@ -303,7 +272,7 @@ def recovery_incidents():
 
 def recovery_summary():
     """Aggregate recovery telemetry: the structured 'why it degraded'
-    record bench.py folds into its JSON artifact.
+    record.
 
     Compatibility shim since PR 4: the counts come from the telemetry
     registry's mx_recovery_* families (unbounded, exported everywhere),

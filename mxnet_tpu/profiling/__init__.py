@@ -2,36 +2,28 @@
 
 The third observability layer (telemetry = how much, tracing =
 why/when): per-op/per-fusion FLOPs, HBM bytes and time, keyed back to
-framework op names and fusion rules, reconciled against measured
-reality.
+framework op names and fusion rules.
 
 - :mod:`~mxnet_tpu.profiling.hlo` — optimized-HLO parser + analytic
   per-instruction cost model (stdlib-only),
 - :mod:`~mxnet_tpu.profiling.ledger` — the cost ledger: build, price,
-  attribute, summarize, diff,
+  attribute, diff,
 - :mod:`~mxnet_tpu.profiling.xplane` — ``jax.profiler`` xplane
   protobuf reader (stdlib-only) + measured per-op device time,
-- :mod:`~mxnet_tpu.profiling.capture` — run-under-capture harness
-  joining measured time onto the ledger with a >= 90% reconciliation
-  gate against telemetry ``mx_step_time_seconds``,
 - :mod:`~mxnet_tpu.profiling.memory` — the memory axis: static
   liveness ledger over compiled HLO (peak live bytes + ranked buffer
   table), live-array census with role tagging (per device shard), and
   the OOM postmortem artifact,
-- :mod:`~mxnet_tpu.profiling.bench_ledger` — the ``python -m``
-  subprocess ``bench.py`` uses to compute a CPU cost-model ledger
-  beside the process that holds the chip,
 - :mod:`~mxnet_tpu.profiling.health` — the numerics axis: sync-free
   nonfinite sentry at the framework seams, gradient/update-ratio
   telemetry, loss-anomaly detection, the first-NaN postmortem, and
   drift fingerprints.
 
-CLI: ``tools/mfu_report.py`` (table / --diff / --capture / --chrome),
+CLI: ``tools/mfu_report.py`` (table / --diff / --hlo),
 ``tools/memory_report.py`` (table / --diff / --capture / --hlo) and
 ``tools/health_report.py`` (table / --diff / --postmortem).
-Env: ``MXTPU_PROFILE_ATTRIB``, ``MXTPU_PROFILE_DIR``,
-``MXTPU_MEMORY_CENSUS``, ``MXTPU_OOM_DUMP_PATH``, ``MXTPU_HEALTH``,
-``MXTPU_HEALTH_DUMP_PATH``, ``MXTPU_HEALTH_NORMS``,
+Env: ``MXTPU_MEMORY_CENSUS``, ``MXTPU_OOM_DUMP_PATH``,
+``MXTPU_HEALTH``, ``MXTPU_HEALTH_DUMP_PATH``, ``MXTPU_HEALTH_NORMS``,
 ``MXTPU_HEALTH_ANOMALY_Z`` — registered in ``libinfo._ENV_VARS``,
 documented in ``docs/observability.md`` ("MFU accounting & roofline",
 "Memory accounting", "Model health").
@@ -41,20 +33,17 @@ from __future__ import annotations
 from . import hlo
 from . import ledger
 from . import xplane
-from . import capture
 from . import memory
 from . import health
-from .capture import analyze_dir, attribution_run
 from .ledger import build_ledger, from_compiled, from_fn, mfu_estimate
 from .memory import (build_memory_ledger, live_census, tag_role,
                      tag_tree, maybe_oom_postmortem, oom_postmortem)
 from .health import (fingerprint_params, nan_postmortem,
                      localize_first_nonfinite, NonfiniteError)
 
-__all__ = ["hlo", "ledger", "xplane", "capture", "memory", "health",
+__all__ = ["hlo", "ledger", "xplane", "memory", "health",
            "build_ledger", "from_compiled", "from_fn", "mfu_estimate",
-           "analyze_dir", "attribution_run", "build_memory_ledger",
-           "live_census", "tag_role", "tag_tree",
+           "build_memory_ledger", "live_census", "tag_role", "tag_tree",
            "maybe_oom_postmortem", "oom_postmortem",
            "fingerprint_params", "nan_postmortem",
            "localize_first_nonfinite", "NonfiniteError"]

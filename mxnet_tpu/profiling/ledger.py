@@ -22,8 +22,7 @@ flops/peak dominates the estimated time, ``hbm`` when bytes/bandwidth
 does, ``comms`` for collectives, ``trivial`` for costless plumbing.
 
 The ledger document is plain JSON (versioned) so ``tools/
-mfu_report.py`` renders and diffs it standalone, and ``bench.py``
-embeds its top-10 in every artifact — success, stale, or failure.
+mfu_report.py`` renders and diffs it standalone.
 """
 from __future__ import annotations
 
@@ -231,7 +230,7 @@ def from_compiled(compiled, hlo_text=None, **kwargs):
     """Ledger from a ``jax.stages.Compiled`` — folds in XLA's own
     aggregate ``cost_analysis`` as a cross-check. Pass ``hlo_text``/
     ``module=`` to share one serialization/parse with other passes
-    over the same executable (bench_ledger prices flops AND memory)."""
+    over the same executable (flops AND memory)."""
     if hlo_text is None:
         hlo_text = compiled.as_text()
     doc = build_ledger(hlo_text, **kwargs)
@@ -259,13 +258,12 @@ def from_fn(fn, *args, **kwargs):
     return from_compiled(jitted.lower(*args).compile(), **kwargs)
 
 
-def mfu_estimate(doc, items_per_step=None, step_s=None):
+def mfu_estimate(doc, items_per_step=None):
     """Cost-model MFU numbers from a ledger document alone.
 
     - ``mfu_at_roofline``: flops_total / (est_s * peak) — the MFU the
       roofline model says this module could reach if every op hit its
       bound: a ceiling that needs no chip run.
-    - with ``step_s``: ``mfu_measured`` = flops_total / (step_s * peak).
     - with ``items_per_step``: ``gflops_per_item`` for throughput math.
     """
     peak_fs = doc["peak_tflops"] * 1e12
@@ -278,38 +276,12 @@ def mfu_estimate(doc, items_per_step=None, step_s=None):
            if est_s > 0 else 0.0}
     if items_per_step:
         out["gflops_per_item"] = round(flops / items_per_step / 1e9, 3)
-    if step_s:
-        out["mfu_measured"] = round(flops / (step_s * peak_fs), 4)
-    return out
-
-
-def summarize(doc, top=10):
-    """Bounded summary for embedding in bench artifacts: MFU estimate
-    + the top-N by_op rows, short keys, no raw instruction table."""
-    est = mfu_estimate(doc)
-    rows = []
-    tot_t = doc["totals"]["est_s"] or 1e-30
-    for a in doc.get("by_op", [])[:top]:
-        rows.append({
-            "op": a["op"],
-            "gflops": round(a["flops"] / 1e9, 3),
-            "mb": round(a["bytes"] / 1e6, 3),
-            "est_ms": round(a["est_s"] * 1e3, 4),
-            "share": round(a["est_s"] / tot_t, 4),
-            "bound": a.get("bound", "?"),
-        })
-    out = {"mfu_at_roofline": est["mfu_at_roofline"],
-           "gflops_total": est["gflops_total"],
-           "est_step_s": est["est_step_s"],
-           "top": rows}
-    if "flops_vs_xla" in doc:
-        out["flops_vs_xla"] = doc["flops_vs_xla"]
     return out
 
 
 def diff(before, after):
-    """Ranked per-op delta between two ledger (or attribution)
-    documents — the mfu_report --diff payload."""
+    """Ranked per-op delta between two ledger documents — the
+    mfu_report --diff payload."""
     def index(doc):
         return {a["op"]: a for a in doc.get("by_op", [])}
 
@@ -318,8 +290,8 @@ def diff(before, after):
     for op in sorted(set(ia) | set(ib)):
         a = ia.get(op, {})
         b = ib.get(op, {})
-        ta = a.get("measured_s", a.get("est_s", 0.0))
-        tb = b.get("measured_s", b.get("est_s", 0.0))
+        ta = a.get("est_s", 0.0)
+        tb = b.get("est_s", 0.0)
         out.append({
             "op": op,
             "before_s": ta, "after_s": tb, "delta_s": tb - ta,

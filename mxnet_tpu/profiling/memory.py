@@ -357,19 +357,6 @@ def from_fn(fn, *args, **kwargs):
     return from_compiled(jitted.lower(*args).compile(), **kwargs)
 
 
-def summarize(doc, top=5):
-    """Bounded summary for embedding in bench artifacts."""
-    out = {
-        "peak_live_mb": round(doc["peak_live_bytes"] / 1e6, 3),
-        "peak_instr": doc.get("peak_instr"),
-        "top": [{"op": g["op"], "mb": round(g["bytes"] / 1e6, 3)}
-                for g in doc.get("by_op", [])[:top]],
-    }
-    if "peak_vs_xla" in doc:
-        out["peak_vs_xla"] = doc["peak_vs_xla"]
-    return out
-
-
 def diff(before, after):
     """Ranked per-op delta of live-at-peak bytes between two memory
     ledgers — the ``memory_report --diff`` payload, mirroring

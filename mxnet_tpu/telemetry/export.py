@@ -119,17 +119,13 @@ def _json_safe(v):
 
 
 def merge_chrome_trace(snap=None, events=None, spans=None,
-                       attribution=None, memory=None, health=None,
-                       timeline=None):
+                       memory=None, health=None, timeline=None):
     """One chrome://tracing document carrying every observability
     layer: the profiler's trace events, the tracing spans (causal
-    layer, PR 5), the metric snapshot — counters/gauges as 'C'
-    samples on the same clock, the full snapshot under metadata —
-    and, when ``attribution`` is a profiling ledger/attribution
-    document (PR 6), its ranked per-op rows as a flame strip on a
-    dedicated pid plus the raw document under metadata. ``memory``
-    (PR 7) takes a live-array census document — or ``True`` to take
-    one now — rendered as per-role/per-device counter tracks.
+    layer, PR 5) and the metric snapshot — counters/gauges as 'C'
+    samples on the same clock, the full snapshot under metadata.
+    ``memory`` (PR 7) takes a live-array census document — or ``True``
+    to take one now — rendered as per-role/per-device counter tracks.
     ``health`` takes a model-health summary (``profiling.health
     .snapshot_doc``) — or ``True`` to fold one now — rendered as
     loss/grad-norm/nonfinite counter tracks beside the memory track.
@@ -166,13 +162,6 @@ def merge_chrome_trace(snap=None, events=None, spans=None,
             merged.append({"name": ev_name, "ph": "C", "ts": ts,
                            "pid": 0, "args": {name: v}})
     metadata = {"telemetry": snap}
-    if attribution is not None:
-        merged.extend(_tracing.export.attribution_events(attribution))
-        metadata["attribution"] = {
-            k: attribution.get(k)
-            for k in ("kind", "module", "totals", "reconciliation",
-                      "mfu", "peak_tflops", "peak_hbm_gbs")
-            if k in attribution}
     if memory is not None:
         if memory is True:
             from ..profiling import memory as _mem
@@ -230,11 +219,10 @@ def merge_chrome_trace(snap=None, events=None, spans=None,
             "metadata": _json_safe(metadata)}
 
 
-def dump_chrome_trace(path, snap=None, events=None, attribution=None,
-                      memory=None, health=None, timeline=None):
-    trace = merge_chrome_trace(snap, events, attribution=attribution,
-                               memory=memory, health=health,
-                               timeline=timeline)
+def dump_chrome_trace(path, snap=None, events=None, memory=None,
+                      health=None, timeline=None):
+    trace = merge_chrome_trace(snap, events, memory=memory,
+                               health=health, timeline=timeline)
     _atomic_text(path, json.dumps(trace))
     return trace
 

@@ -124,42 +124,12 @@ def pull_server_trace(kv, path, timeout=10.0, poll=0.05):
         % (nonce_path, timeout))
 
 
-def attribution_events(attrib_doc, pid=90, tid=0):
-    """Cost-attribution rows (a ``profiling`` ledger/attribution
-    document) rendered as a chrome-trace flame strip: one 'X' event
-    per op, laid end-to-end in rank order on a dedicated pid, sized by
-    measured (preferred) or roofline-estimated per-step seconds. Not a
-    timeline — a proportional-width ranking that sits next to the real
-    spans in the same Perfetto view, so "where does the step go" and
-    "when did it go there" read off one artifact."""
-    events = [{"name": "process_name", "ph": "M", "pid": pid, "tid": tid,
-               "args": {"name": "op attribution (per step)"}}]
-    cursor = 0.0
-    for g in attrib_doc.get("by_op", []):
-        dur_us = (g.get("measured_s") or g.get("est_s") or 0.0) * 1e6
-        if dur_us <= 0:
-            continue
-        args = {"flops": g.get("flops", 0), "bytes": g.get("bytes", 0),
-                "bound": g.get("bound", "?")}
-        if g.get("rule"):
-            args["rule"] = g["rule"]
-        if g.get("mfu") is not None:
-            args["mfu"] = g.get("mfu")
-        events.append({
-            "name": g.get("op") or "?", "cat": "attribution", "ph": "X",
-            "ts": cursor, "dur": dur_us, "pid": pid, "tid": tid,
-            "args": args})
-        cursor += dur_us
-    return events
-
-
 def memory_counter_events(census_doc, pid=91, ts=0.0):
     """A live-array census (``profiling.memory.live_census`` document)
     rendered as Perfetto counter tracks: one stacked 'C' counter of
-    live bytes by role, plus one counter per device with its total —
-    the memory analogue of :func:`attribution_events`. ``ts`` places
-    the sample on the shared clock (callers pass the profiler's
-    now)."""
+    live bytes by role, plus one counter per device with its total.
+    ``ts`` places the sample on the shared clock (callers pass the
+    profiler's now)."""
     events = [{"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
                "args": {"name": "HBM live bytes (census)"}}]
     by_role = census_doc.get("by_role", {})
@@ -188,8 +158,7 @@ def health_counter_events(health_doc, pid=92, ts=0.0):
         # Perfetto reject the whole trace — drop the sample, keep the
         # nonfinite-count track as the signal. (Local copy by design:
         # tracing/ must import standalone, without telemetry; the
-        # sibling guards live in telemetry/export._json_safe and
-        # tools/perf_gate._is_finite_number.)
+        # sibling guard lives in telemetry/export._json_safe.)
         return isinstance(v, (int, float)) and not isinstance(v, bool) \
             and v == v and v not in (float("inf"), float("-inf"))
 

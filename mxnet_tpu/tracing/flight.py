@@ -12,15 +12,14 @@ gets that record OUT of a process that is about to die or already hung:
   C-level crashes (SIGSEGV/SIGABRT print stacks), a chained SIGTERM
   handler and a chained ``sys.excepthook`` that write the dump first.
   NOT installed at import: signal handlers are process policy, so the
-  entrypoints that own the process (bench.py, tools/launch.py roles)
-  opt in.
+  entrypoint that owns the process opts in.
 - :class:`Watchdog` / :func:`arm` — a daemon thread that fires a dump
   when no span opens/closes for ``MXTPU_HANG_TIMEOUT_SEC`` seconds (a
   healthy training loop closes spans constantly; a wedged one goes
   silent). One dump per stall: it re-arms when activity resumes.
 
 The dump is bounded (``max_spans`` per thread) so it can be embedded
-in a failure artifact — bench.py folds it into the failure JSON.
+in a failure artifact.
 """
 from __future__ import annotations
 
@@ -166,7 +165,7 @@ def install(signals=True, excepthook=True, watchdog=None):
     """Arm the flight recorder's exits (idempotent). ``watchdog``:
     None honors MXTPU_HANG_TIMEOUT_SEC (>0 arms), a number arms with
     that timeout, False skips. Call from process entrypoints that own
-    signal policy (bench.py does)."""
+    signal policy."""
     if not _installed[0]:
         _installed[0] = True
         import faulthandler

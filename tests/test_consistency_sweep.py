@@ -5,7 +5,7 @@ the sweep must pass 100% — this validates the table (every op callable,
 shapes coherent, tolerances sane) exactly the way the reference's gpu
 suite degenerates on a CPU-only build (ref:
 tests/python/gpu/test_operator_gpu.py:1). The real cross-device diff
-runs inside bench.py on the chip.
+needs a chip.
 """
 from mxnet_tpu.consistency import (OP_TABLE, model_forward_consistency,
                                    run_sweep)

@@ -1,6 +1,6 @@
 """Channel-last (NHWC) layout machinery + optimize_for fusion
-(VERDICT r3 #2): the round's perf lever must be covered on the CPU mesh,
-not only by bench.py on the chip."""
+(VERDICT r3 #2): the round's perf lever must be covered on the CPU
+mesh."""
 import warnings
 
 import numpy as np
@@ -157,9 +157,8 @@ def test_mobilenet_nhwc_matches_nchw():
 
 
 def test_nhwc_gradients_match_nchw():
-    """The training path differentiates through NHWC conv/pool/BN
-    (bench's train section uses the best layout); gradients must match
-    the NCHW lowering parameter-for-parameter."""
+    """The training path differentiates through NHWC conv/pool/BN;
+    gradients must match the NCHW lowering parameter-for-parameter."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.gluon.block import _flatten

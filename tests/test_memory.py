@@ -13,7 +13,7 @@ Covers the three cooperating pieces chip-free on the CPU backend:
   per-device collector regression fix, the chrome-trace counter track,
 - the OOM postmortem artifact (simulated allocation failure through
   the executor seam), and
-- the CLIs: memory_report table/diff/hlo, perf_gate's memory section.
+- the CLIs: memory_report table/diff/hlo.
 """
 import json
 import os
@@ -139,9 +139,6 @@ def test_memory_ledger_roundtrip_and_diff(tmp_path):
     assert d["peak_delta"] == -(_S // 2)
     fc = next(r for r in d["by_op"] if r["op"] == "FullyConnected")
     assert fc["delta_bytes"] == -(_S // 2)
-    summ = memory.summarize(doc, top=2)
-    assert summ["peak_live_mb"] == round(4 * _S / 1e6, 3)
-    assert len(summ["top"]) <= 2
     # top= bounds only the stored buffer table; the aggregates still
     # cover the full live-at-peak set
     bounded = memory.build_memory_ledger(_HLO_FIXTURE, top=1)
@@ -170,11 +167,11 @@ def test_resnet50_peak_within_15pct_of_memory_analysis():
     compiled.memory_analysis() within ±15% on a ResNet-50 trace."""
     import jax.numpy as jnp
 
-    sys.path.insert(0, REPO)
-    import bench
+    sys.path.insert(0, TOOLS)
+    import programs
 
     batch = 2
-    fwd, pvals = bench.build_forward(batch)
+    fwd, pvals = programs.build_forward(batch)
     data = jnp.zeros((batch, 3, 224, 224), jnp.bfloat16)
     doc = memory.from_compiled(fwd.lower(pvals, data).compile())
     assert doc["peak_live_bytes"] > 10e6  # a real network's footprint
@@ -474,56 +471,6 @@ def test_oom_postmortem_coalesces(tmp_path, monkeypatch):
     assert memory.maybe_oom_postmortem(err, source="b") is None
 
 
-# ------------------------------------------------------------ bench seam
-def test_bench_ledger_stage_embeds_memory(tmp_path):
-    """The bench cost-ledger subprocess attaches a bounded memory
-    summary per stage — the vehicle that puts peak-live-bytes into
-    every success/stale/failure artifact."""
-    import subprocess
-
-    out = str(tmp_path / "ledger.json")
-    env = dict(os.environ)
-    env["MXTPU_LEDGER_OUT"] = out
-    env["MXTPU_LEDGER_STAGES"] = "tiny"
-    env["MXTPU_TELEMETRY"] = "0"
-    proc = subprocess.run(
-        [sys.executable, "-m", "mxnet_tpu.profiling.bench_ledger"],
-        cwd=REPO, env=env, timeout=240,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-    assert proc.returncode == 0
-    doc = json.loads(open(out).read())
-    memdoc = doc["stages"]["tiny"]["memory"]
-    assert memdoc["peak_live_mb"] > 0
-    assert len(memdoc["top"]) <= 3
-    assert 0.85 <= memdoc.get("peak_vs_xla", 1.0) <= 1.15
-    assert len(json.dumps(doc)) < 8192  # still rides a metric line
-
-
-def test_bench_diag_embeds_memory_and_oom(tmp_path, monkeypatch):
-    """Child-side failure diagnostics carry the live-memory summary,
-    and an OOM postmortem left on disk is embedded as diag.oom."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    diag = bench._diag_snapshot()
-    assert "memory" in diag, diag.get("telemetry_error")
-    assert diag["memory"]["live_mb"] >= 0
-    assert isinstance(diag["memory"]["by_role_mb"], dict)
-
-    path = str(tmp_path / "oom.json")
-    monkeypatch.setenv("MXTPU_OOM_DUMP_PATH", path)
-    memory._LAST_POSTMORTEM[0] = -10.0
-    memory.oom_postmortem(
-        error=RuntimeError("RESOURCE_EXHAUSTED: oom"),
-        hlo_text=_HLO_FIXTURE, source="bench_child", path=path)
-    diag = bench._diag_snapshot()
-    assert diag["oom"]["source"] == "bench_child"
-    assert diag["oom"]["peak_live_mb"] == round(4 * _S / 1e6, 2)
-    assert diag["oom"]["top"], diag["oom"]
-    # bounded: the whole diag must still ride a 16KB metric line
-    assert len(json.dumps(diag["oom"])) < 2000
-
-
 # ----------------------------------------------------------------- CLIs
 def test_memory_report_table_and_hlo(tmp_path, capsys):
     sys.path.insert(0, TOOLS)
@@ -564,43 +511,6 @@ def test_memory_report_diff_cli(tmp_path, capsys):
     assert "peak live bytes" in stdout
     # exactly-two-documents contract
     assert memory_report.main(["--diff", before]) == 2
-
-
-def test_perf_gate_memory_section(tmp_path):
-    sys.path.insert(0, TOOLS)
-    import perf_gate
-
-    def artifact(peak_mb, value=100.0):
-        return {"metric": "resnet50_inference_bf16_bs128",
-                "value": value, "backend": "tpu",
-                "cost_ledger": {"stages": {"infer_bf16": {
-                    "mfu_at_roofline": 0.5,
-                    "memory": {"peak_live_mb": peak_mb}}}}}
-
-    good = artifact(100.0)
-    # within tolerance: ok
-    rc, msgs = perf_gate.gate(artifact(110.0), good)
-    assert rc == 0, msgs
-    assert any("memory[infer_bf16]" in m for m in msgs)
-    # grown past 15%: regression
-    rc, msgs = perf_gate.gate(artifact(200.0), good)
-    assert rc == 1
-    assert any("REGRESSION memory" in m for m in msgs)
-    # --mem-tol loosens it
-    rc, _ = perf_gate.gate(artifact(200.0), good, mem_tolerance=1.5)
-    assert rc == 0
-    # via the CLI files
-    gp = tmp_path / "good.json"
-    cp = tmp_path / "cand.json"
-    gp.write_text(json.dumps(good))
-    cp.write_text(json.dumps(artifact(200.0)))
-    assert perf_gate.main([str(cp), "--last-good", str(gp)]) == 1
-    assert perf_gate.main([str(cp), "--last-good", str(gp),
-                           "--mem-tol", "1.5"]) == 0
-    # stages missing on either side: the section is silent, not fatal
-    rc, msgs = perf_gate.gate({"metric": "m", "value": 50.0},
-                              {"metric": "m", "value": 50.0})
-    assert rc == 0
 
 
 # ------------------------------------------------------------ kv_cache

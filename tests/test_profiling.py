@@ -6,15 +6,12 @@ seam is that every one of these runs chip-free:
 
 - the HLO parser + analytic cost model (unit fixtures, and the
   committed acceptance bound: ResNet-50's ledger FLOPs agree with the
-  analytic ``RESNET50_GFLOPS`` within 15%),
+  benchmark's analytic forward count within 15%),
 - framework-op attribution through all three channels (dispatch-layer
   ``jit(<fn>)`` scopes, executor ``mx.<Op>`` named scopes, fusion-rule
   mapping for ``_sg_xla_conv``),
-- the xplane wire parser (synthetic protobuf fixtures + a real
-  capture) and the measured join's >= 90% reconciliation gate,
-- the CLIs: mfu_report (table/diff/capture), perf_gate over the
-  committed BENCH artifacts, trace_merge single-rank behavior,
-- bench.py's failure-injection path embedding the cost ledger.
+- the xplane wire parser (synthetic protobuf fixtures),
+- the CLIs: mfu_report (table/diff), trace_merge single-rank behavior.
 """
 import json
 import os
@@ -25,7 +22,7 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu.profiling import capture, hlo, ledger, xplane
+from mxnet_tpu.profiling import hlo, ledger, xplane
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOLS = os.path.join(REPO, "tools")
@@ -114,29 +111,30 @@ def test_ledger_fixture_rows_and_bounds():
     assert "Arg_0.1" not in rows and "w" not in rows
     est = ledger.mfu_estimate(doc, items_per_step=4)
     assert est["gflops_per_item"] >= 0
-    summary = ledger.summarize(doc, top=3)
-    assert len(summary["top"]) <= 3
-    assert summary["mfu_at_roofline"] > 0
+    assert est["mfu_at_roofline"] > 0
 
 
 # --------------------------------------------------- ResNet-50 acceptance
 def test_resnet50_ledger_flops_within_15pct_of_analytic():
     """Satellite acceptance: the cost-ledger FLOPs for the ResNet-50
-    forward agree with bench.py's analytic RESNET50_GFLOPS within 15%.
-    RESNET50_GFLOPS counts MAC-pairs (the standard '4.1 GFLOPs'
-    convention), the ledger counts 2 flops per MAC — compare GMACs."""
+    forward agree within 15% with the benchmark's analytic count
+    (benchmark/lib/flops.py: convolutions and the classifier, 2 FLOPs
+    a multiply-accumulate, as the ledger counts them)."""
     import jax.numpy as jnp
 
-    sys.path.insert(0, REPO)
-    import bench
+    sys.path.insert(0, TOOLS)
+    import programs
+    from benchmark.lib import flops
 
     batch = 2
-    fwd, pvals = bench.build_forward(batch)
+    fwd, pvals = programs.build_forward(batch)
     data = jnp.zeros((batch, 3, 224, 224), jnp.bfloat16)
     doc = ledger.from_compiled(fwd.lower(pvals, data).compile())
-    gmacs_per_img = doc["totals"]["flops"] / 2 / batch / 1e9
-    assert abs(gmacs_per_img - bench.RESNET50_GFLOPS) \
-        <= 0.15 * bench.RESNET50_GFLOPS, gmacs_per_img
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "resnet50_v1.json")) as f:
+        analytic = flops.resnet_v1_forward_flops(json.load(f), batch)
+    assert abs(doc["totals"]["flops"] - analytic) <= 0.15 * analytic, \
+        (doc["totals"]["flops"], analytic)
     # the analytic model must also agree with XLA's own aggregate
     assert 0.8 <= doc.get("flops_vs_xla", 1.0) <= 1.25
     # attribution lands on framework ops, not raw primitives
@@ -264,90 +262,6 @@ def test_measure_ops_self_time_and_window():
     assert m2["window_s"] == pytest.approx(14000 / 1e12)
 
 
-# ------------------------------------------------ capture + reconciliation
-def _tiny_step():
-    from mxnet_tpu.profiling.bench_ledger import _tiny_train_step
-    return _tiny_train_step()
-
-
-def test_attribution_run_reconciles_with_telemetry(tmp_path):
-    """The acceptance loop: run a train step under capture, join, and
-    the attributed device time must cover >= 90% of the telemetry
-    mx_step_time_seconds wall-time for the same steps."""
-    step, args, items = _tiny_step()
-    doc = capture.attribution_run(
-        step, args, steps=3, profile_dir=str(tmp_path / "cap"),
-        items_per_step=items)
-    rec = doc["reconciliation"]
-    assert doc["reconciled"] is True, rec
-    assert rec["ratio"] >= 0.9
-    assert rec["step_wall_s"] > 0
-    assert doc["measured"]["matched_events"] > 0
-    # measured rows exist and conv cost is attributed
-    measured_ops = [g for g in doc["by_op"]
-                    if g.get("measured_s") is not None]
-    assert measured_ops
-    assert doc["totals"]["flops"] > 0
-    assert doc["mfu"] >= 0
-    # the ledger totals reconcile with the telemetry step time: the
-    # roofline estimate can never exceed the measured wall
-    assert doc["totals"]["est_s"] <= rec["step_wall_s"] * 1.5
-
-
-def test_attribution_unattributed_row_is_explicit(tmp_path):
-    """On the CPU backend Eigen offloads conv work without per-op
-    tracemes; the join must surface that as an _unattributed row, not
-    silently shrink the table."""
-    step, args, _ = _tiny_step()
-    doc = capture.attribution_run(step, args, steps=2,
-                                  profile_dir=str(tmp_path / "cap"))
-    named = doc["measured"]["named_s_per_step"]
-    window = doc["measured"]["device_window_s_per_step"]
-    if window > named:
-        una = [g for g in doc["by_op"] if g["op"] == "_unattributed"]
-        assert una and una[0]["measured_s"] == pytest.approx(
-            doc["measured"]["unattributed_s_per_step"], rel=1e-6)
-
-
-def test_merge_chrome_trace_folds_attribution(tmp_path):
-    step, args, _ = _tiny_step()
-    doc = capture.attribution_run(step, args, steps=2,
-                                  profile_dir=str(tmp_path / "cap"))
-    trace = mx.telemetry.export.merge_chrome_trace(attribution=doc)
-    attrib = [e for e in trace["traceEvents"]
-              if e.get("cat") == "attribution"]
-    assert attrib, "no attribution strip in the merged trace"
-    assert trace["metadata"]["attribution"]["kind"] == \
-        "mfu_attribution"
-    # flame strip is contiguous from 0 in rank order
-    assert attrib[0]["ts"] == 0
-
-
-def test_profiler_op_attribution_roundtrip(tmp_path):
-    """profiler.set_config(xla_trace_dir=...) + run/stop leaves a
-    capture that profiler.op_attribution can join."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu import profiler
-
-    step, args, _ = _tiny_step()
-    compiled = step.lower(*args).compile()
-    cap_dir = str(tmp_path / "xla_cap")
-    profiler.set_config(xla_trace_dir=cap_dir)
-    profiler.set_state("run")
-    out = step(*args)
-    jax.tree_util.tree_map(
-        lambda leaf: leaf.block_until_ready()
-        if hasattr(leaf, "block_until_ready") else leaf, out)
-    profiler.set_state("stop")
-    profiler.set_config(xla_trace_dir=None)
-    assert profiler.last_xplane_dir() == cap_dir
-    doc = profiler.op_attribution(compiled=compiled)
-    assert doc["kind"] == "mfu_attribution"
-    assert doc["measured"]["matched_events"] > 0
-
-
 # ------------------------------------------------------------- mfu_report
 def test_mfu_report_table_and_diff(tmp_path):
     sys.path.insert(0, TOOLS)
@@ -373,165 +287,6 @@ def test_mfu_report_table_and_diff(tmp_path):
     fc = next(r for r in d if r["op"] == "FullyConnected")
     assert fc["delta_s"] < 0
     assert mfu_report.main(["--diff", before, after]) == 0
-
-
-def test_mfu_report_capture_cli_resnet(tmp_path, capsys):
-    """The acceptance CLI path: mfu_report --capture on a CPU-mesh
-    ResNet forward step produces the per-op table and reconciles to
-    >= 90% of the telemetry step wall-time (exit 0 proves the gate)."""
-    sys.path.insert(0, TOOLS)
-    import mfu_report
-
-    out = str(tmp_path / "attrib.json")
-    rc = mfu_report.main([
-        "--capture", "resnet50-infer", "--batch", "2", "--hw", "112",
-        "--steps", "2", "-o", out])
-    stdout = capsys.readouterr().out
-    assert rc == 0, stdout
-    assert "reconciliation" in stdout
-    doc = json.loads(open(out).read())
-    assert doc["reconciled"] is True
-    assert doc["reconciliation"]["ratio"] >= 0.9
-    ops = {g["op"] for g in doc["by_op"]}
-    assert "Convolution" in ops
-
-
-@pytest.mark.slow
-def test_mfu_report_capture_cli_resnet_train(tmp_path):
-    """Full acceptance shape (slow: ~1 min CPU compile): the ResNet-50
-    TRAIN step through the same CLI."""
-    sys.path.insert(0, TOOLS)
-    import mfu_report
-
-    out = str(tmp_path / "attrib_train.json")
-    rc = mfu_report.main([
-        "--capture", "resnet50-train", "--batch", "1", "--steps", "2",
-        "-o", out])
-    doc = json.loads(open(out).read())
-    assert rc == 0, doc.get("reconciliation")
-    assert doc["reconciliation"]["ratio"] >= 0.9
-
-
-# -------------------------------------------------------------- perf_gate
-# the shapes of a bench artifact, with made-up round numbers: no bench
-# measurement is committed (the driver's ledger is the record)
-_BENCH_GOOD = {"metric": "resnet50_inference_bf16_bs128", "value": 1000.0,
-               "unit": "img/s/chip", "vs_baseline": 0.25, "mfu_bf16": 0.25}
-_BENCH_BARE_ZERO = {"n": 5, "rc": 124, "parsed": {
-    "metric": "resnet50_inference_bf16_bs128", "value": 0.0,
-    "unit": "img/s/chip", "vs_baseline": 0.0,
-    "error": "backend init failed"}}
-
-
-def _bench_good(tmp_path):
-    p = tmp_path / "good.json"
-    p.write_text(json.dumps(_BENCH_GOOD))
-    return str(p)
-
-
-def test_perf_gate_bench_artifact_shapes(tmp_path):
-    sys.path.insert(0, TOOLS)
-    import perf_gate
-
-    good = _bench_good(tmp_path)
-    # a reference gates against itself: PASS
-    assert perf_gate.main([good, "--last-good", good]) == 0
-    # a driver round file around value 0.0 with no diag and no
-    # cost_ledger is the bare-zero shape: rejected
-    bare = tmp_path / "bare.json"
-    bare.write_text(json.dumps(_BENCH_BARE_ZERO))
-    assert perf_gate.main([str(bare), "--last-good", good]) == 3
-    # a bench artifact has no committed reference to fall back to
-    assert perf_gate.main([good]) == 2
-
-
-def test_perf_gate_regression_and_tolerance(tmp_path):
-    sys.path.insert(0, TOOLS)
-    import perf_gate
-
-    ref = _bench_good(tmp_path)
-    cand = dict(_BENCH_GOOD)
-    cand["value"] = _BENCH_GOOD["value"] * 0.5
-    p = tmp_path / "cand.json"
-    p.write_text(json.dumps(cand))
-    assert perf_gate.main([str(p), "--last-good", ref]) == 1
-    # a generous headline tolerance turns the same artifact green
-    assert perf_gate.main([str(p), "--last-good", ref,
-                           "--tolerance", "0.6"]) == 0
-    # per-metric regression still caught under a loose default
-    cand2 = dict(_BENCH_GOOD)
-    cand2["mfu_bf16"] = _BENCH_GOOD["mfu_bf16"] * 0.1
-    p2 = tmp_path / "cand2.json"
-    p2.write_text(json.dumps(cand2))
-    assert perf_gate.main([str(p2), "--last-good", ref]) == 1
-    assert perf_gate.main([str(p2), "--last-good", ref,
-                           "--tol", "mfu_bf16=0.95"]) == 0
-
-
-def test_perf_gate_diagnosed_zero_is_not_bare(tmp_path):
-    sys.path.insert(0, TOOLS)
-    import perf_gate
-
-    zero = {"metric": "resnet50_inference_bf16_bs128", "value": 0.0,
-            "error": "wedged", "cost_ledger": {"stages": {}}}
-    p = tmp_path / "zero.json"
-    p.write_text(json.dumps(zero))
-    # failed, but not signal-free
-    assert perf_gate.main([str(p), "--last-good",
-                           _bench_good(tmp_path)]) == 1
-
-
-# ---------------------------------------------------- bench cost ledger
-def test_bench_failure_artifact_embeds_cost_ledger(
-        tmp_path, monkeypatch, capsys):
-    """Acceptance: a bench failure line carries the cost_ledger the
-    CPU-pinned child computed — the cost-model MFU estimate and
-    top-10."""
-    import bench
-
-    monkeypatch.setattr(bench, "_LEDGER_PATH",
-                        str(tmp_path / "ledger.json"))
-    # conftest defaults the attribution pass OFF for the suite (a real
-    # ledger subprocess costs minutes); this test is the one that
-    # proves the wiring, so it opts back in on the fast tiny stage
-    monkeypatch.setenv("MXTPU_PROFILE_ATTRIB", "1")
-    monkeypatch.setenv("MXTPU_LEDGER_STAGES", "tiny")
-    assert bench._ledger_start() is not None
-    bench._ledger_finish(wait_s=180)
-    bench._fail_json("section failed")
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("{")][-1]
-    parsed = json.loads(line)
-    assert parsed["value"] == 0.0 and "error" in parsed
-    led = parsed.get("cost_ledger")
-    assert led, "failure artifact carries no cost_ledger"
-    tiny = led["stages"]["tiny"]
-    assert tiny["mfu_at_roofline"] > 0
-    assert len(tiny["top"]) >= 3
-    assert tiny["gflops_per_item"] > 0
-    assert any(r["op"] in ("Convolution", "convolution",
-                           "conv_general_dilated", "call")
-               for r in tiny["top"])
-
-
-def test_bench_ledger_stage_summaries_are_bounded(tmp_path,
-                                                  monkeypatch):
-    """The bench_ledger subprocess writes per-stage summaries small
-    enough to ride a 16KB metric line."""
-    out = str(tmp_path / "ledger.json")
-    env = dict(os.environ)
-    env["MXTPU_LEDGER_OUT"] = out
-    env["MXTPU_LEDGER_STAGES"] = "tiny"
-    env["MXTPU_TELEMETRY"] = "0"
-    proc = subprocess.run(
-        [sys.executable, "-m", "mxnet_tpu.profiling.bench_ledger"],
-        cwd=REPO, env=env, timeout=240,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-    assert proc.returncode == 0
-    doc = json.loads(open(out).read())
-    assert doc["kind"] == "bench_cost_ledger"
-    assert len(json.dumps(doc)) < 8192
-    assert doc["stages"]["tiny"]["est_step_s"] >= 0
 
 
 # ------------------------------------------------ trace_merge single rank
@@ -608,18 +363,15 @@ def test_trace_merge_multi_rank_still_names_straggler(tmp_path):
 
 
 # ------------------------------------------------------ env registration
-def test_new_env_vars_registered():
+def test_env_registry_and_docs_agree():
+    """docs/env_vars.md lists exactly the registered names."""
+    import re
+
     from mxnet_tpu import libinfo
 
-    new = ("MXTPU_PROFILE_ATTRIB", "MXTPU_PROFILE_DIR",
-           "MXTPU_BENCH_BATCH",
-           "MXTPU_LEDGER_OUT", "MXTPU_LEDGER_STAGES",
-           "MXTPU_LEDGER_DEADLINE_SEC")
-    for name in new:
-        assert name in libinfo._ENV_VARS, name
     docs = open(os.path.join(REPO, "docs", "env_vars.md")).read()
-    for name in new:
-        assert name in docs, "%s missing from docs/env_vars.md" % name
+    documented = set(re.findall(r"^\| `([A-Z0-9_]+)` \|", docs, re.M))
+    assert documented == set(libinfo._ENV_VARS)
 
 
 def test_mxl002_scope_covers_profiling(tmp_path):

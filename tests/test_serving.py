@@ -812,15 +812,3 @@ def test_serving_env_vars_registered():
                 "MXTPU_SERVING_HEALTH_SEC"):
         assert var in libinfo._ENV_VARS, var
         assert var in docs, var
-
-
-def test_bench_embeds_serving_summary():
-    sys.path.insert(0, REPO)
-    import bench
-    summary = bench._serving_summary()
-    assert summary is not None
-    assert summary["source"] == "last_good_artifact"
-    assert summary["ratios"]["batching_gain"] >= 3.0
-    assert summary["dispatch"]["python_dispatch_ms"] >= 0
-    # bounded: rides a metric line without blowing the 16KB cap
-    assert len(json.dumps(summary)) < 2048

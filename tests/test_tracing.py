@@ -613,42 +613,6 @@ def test_tracing_disabled_overhead_bounded():
         % ((best - 1) * 100, on, off)
 
 
-def test_bench_fail_json_embeds_flight_dump(tmp_path, capsys,
-                                            monkeypatch):
-    """The satellite: a bench failure line carries the flight-recorder
-    dump (in-flight spans + stacks) the hang watchdog left — the
-    failure tail is self-diagnosing."""
-    import bench
-
-    def last_line(out):
-        return [ln for ln in out.splitlines() if ln.startswith("{")][-1]
-
-    with tracing.span("wedged_backend_init", cat="comm"):
-        doc = flight.dump("hang: no span activity for 240.0s",
-                          path=str(tmp_path / "flight.json"))
-    assert doc["threads"]
-    monkeypatch.setattr(bench, "_FLIGHT_PATH",
-                        str(tmp_path / "flight.json"))
-    bench._fail_json("no span activity (wedged backend init?)")
-    line = last_line(capsys.readouterr().out)
-    parsed = json.loads(line)
-    ff = parsed["diag"]["flight_file"]
-    assert "hang: no span activity" in ff["reason"]
-    flat = json.dumps(ff["in_flight"])
-    assert "wedged_backend_init" in flat
-    assert ff["stacks"]
-    assert len(line) <= 16384
-    # the live snapshot of this process also rides along
-    assert "flight" in parsed["diag"]
-    # raw faulthandler text (not JSON) embeds as a tail
-    (tmp_path / "flight.json").write_text(
-        "Thread 0x01 (most recent call first):\n  File \"x.py\"...")
-    bench._fail_json("hang watchdog fired")
-    line = last_line(capsys.readouterr().out)
-    ff = json.loads(line)["diag"]["flight_file"]
-    assert "most recent call first" in ff["raw_tail"]
-
-
 def test_mxl006_fires_on_synced_span_attrs(tmp_path):
     import textwrap
 

@@ -59,8 +59,9 @@ def extract_summary(doc):
         return None
     if "sentry" in h:
         return h
-    # bench embeds are flattened (bench.py _health_summary): lift
-    # them back into the summary shape so one renderer serves both
+    # artifact embeds are flattened (docs/artifacts/
+    # HEALTH_LAST_GOOD.json): lift them back into the summary shape so
+    # one renderer serves both
     out = {
         "kind": "health_summary",
         "steps": h.get("steps"),

@@ -39,10 +39,10 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
+    import programs
 
-    fwd, pvals = bench.build_forward(args.batch, layout=args.layout,
-                                     fuse=args.fuse, stem=args.stem)
+    fwd, pvals = programs.build_forward(args.batch, layout=args.layout,
+                                        fuse=args.fuse, stem=args.stem)
     pvals = jax.device_put(pvals)
     data = jnp.zeros((args.batch, 3, 224, 224), jnp.bfloat16)
 

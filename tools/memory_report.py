@@ -168,19 +168,18 @@ def _capture_program(name, batch, hw):
     import numpy as np
 
     sys.path.insert(0, REPO)
+    import programs
     if name == "tiny-train":
-        from mxnet_tpu.profiling.bench_ledger import _tiny_train_step
-        step, args, _items = _tiny_train_step()
+        step, args, _items = programs._tiny_train_step()
         return step, args
-    import bench
     rng = np.random.default_rng(0)
     if name in ("resnet50-infer", "resnet50"):
-        fwd, pvals = bench.build_forward(batch, hw=hw)
+        fwd, pvals = programs.build_forward(batch, hw=hw)
         data = jnp.asarray(rng.standard_normal(
             (batch, 3, hw, hw), dtype=np.float32), jnp.bfloat16)
         return fwd, (jax.device_put(pvals), data)
     if name == "resnet50-train":
-        step, params, moms = bench.build_train(batch)
+        step, params, moms = programs.build_train(batch)
         data = jnp.asarray(rng.standard_normal(
             (batch, 3, 224, 224), dtype=np.float32), jnp.bfloat16)
         labels = jnp.asarray(

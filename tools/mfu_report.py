@@ -1,31 +1,19 @@
 #!/usr/bin/env python
-"""mfu_report — render, diff, or produce per-op MFU attribution.
+"""mfu_report — render or diff per-op cost ledgers.
 
-    python tools/mfu_report.py attrib.json              # ranked table
+    python tools/mfu_report.py ledger.json              # ranked table
     python tools/mfu_report.py --diff before.json after.json
-    python tools/mfu_report.py --capture resnet50-train --steps 3 \\
-        --batch 4 -o attrib.json                        # run + join
-    python tools/mfu_report.py attrib.json --chrome merged.json
     python tools/mfu_report.py --hlo compiled.hlo.txt   # price a dump
 
-Input files are ``mxnet_tpu.profiling`` ledger/attribution documents
-(``bench.py`` embeds their summaries in every BENCH artifact; a live
-capture commits the full document under ``docs/profiles/``). The
-``--diff`` mode is the perf-PR workflow: attribute on main, attribute
-on the branch, attach the ranked per-op delta — the cost-attributed
-analogue of ``telemetry_dump.py --diff``
+Input files are ``mxnet_tpu.profiling`` cost-ledger documents
+(``profiling/ledger.py``) and the partition cost reports of
+``subgraph/cost.py``. The ``--diff`` mode is the perf-PR workflow:
+price on main, price on the branch, attach the ranked per-op delta —
+the cost-attributed analogue of ``telemetry_dump.py --diff``
 (docs/observability.md "MFU accounting & roofline").
 
-``--capture`` compiles and runs a named step program under
-``jax.profiler``, joins measured per-op device time onto the cost
-ledger, and prints the table plus the reconciliation line; exit code
-1 when attributed time covers < 90% of the telemetry step wall-time
-(the table would be lying about where the step goes). Programs:
-``resnet50-infer`` / ``resnet50-train`` (the bench stage programs)
-and ``tiny-train`` (seconds-fast smoke).
-
-Rendering and diffing import only the stdlib-side of the profiling
-package (no jax); --capture initializes the backend.
+Everything here imports only the stdlib side of the profiling package
+(no jax).
 """
 from __future__ import annotations
 
@@ -38,17 +26,10 @@ import types
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _load_profiling(standalone=True):
+def _load_profiling():
     """The profiling package without executing mxnet_tpu/__init__.py
     (which initializes the jax backend) — same pattern as
-    telemetry_dump. With ``standalone=False`` the real package is
-    imported (capture mode needs the full framework anyway)."""
-    if not standalone:
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import mxnet_tpu  # noqa: F401 — registers ops for attribution
-        from mxnet_tpu import profiling
-        return profiling
+    telemetry_dump."""
     import importlib
     name = "_mfu_mxtpu"
     if name not in sys.modules:
@@ -119,12 +100,10 @@ def format_partition_report(doc, top=25):
 
 
 def format_table(doc, top=25):
-    """Ranked per-op attribution table + reconciliation footer."""
+    """Ranked per-op cost table."""
     if doc.get("kind") == "partition_cost_report":
         return format_partition_report(doc, top=top)
     lines = []
-    measured = "measured" in doc or any(
-        "measured_s" in g for g in doc.get("by_op", []))
     lines.append("# %s: %s  (peak %.0f TFLOP/s, %.0f GB/s HBM)"
                  % (doc.get("kind", "ledger"),
                     doc.get("module", "?"), doc["peak_tflops"],
@@ -133,41 +112,16 @@ def format_table(doc, top=25):
     lines.append("# totals: %.3f GFLOP, %s, roofline est %.3f ms"
                  % (t["flops"] / 1e9, _fmt_bytes(t["bytes"]),
                     t["est_s"] * 1e3))
-    hdr = "%-28s %6s %10s %10s %10s %8s %8s" % (
-        "op", "instrs", "GFLOP", "bytes", "est_ms",
-        "meas_ms" if measured else "-", "bound")
-    if measured:
-        hdr += " %7s" % "mfu"
-    lines.append(hdr)
-    total_est = t["est_s"] or 1e-30
+    lines.append("%-28s %6s %10s %10s %10s %8s" % (
+        "op", "instrs", "GFLOP", "bytes", "est_ms", "bound"))
     for g in doc.get("by_op", [])[:top]:
-        row = "%-28s %6d %10.3f %10s %10.4f %8s %8s" % (
+        row = "%-28s %6d %10.3f %10s %10.4f %8s" % (
             (g.get("op") or "?")[:28], g.get("instrs", 0),
             g["flops"] / 1e9, _fmt_bytes(g["bytes"]),
-            g["est_s"] * 1e3,
-            ("%.3f" % (g["measured_s"] * 1e3))
-            if g.get("measured_s") is not None else "-",
-            g.get("bound", "?"))
-        if measured:
-            row += " %7s" % (("%.4f" % g["mfu"])
-                             if g.get("mfu") is not None else "-")
+            g["est_s"] * 1e3, g.get("bound", "?"))
         if g.get("rule"):
             row += "  rule=%s" % g["rule"]
         lines.append(row)
-    rec = doc.get("reconciliation")
-    if rec:
-        lines.append(
-            "# reconciliation: attributed %.3f ms of %.3f ms step "
-            "wall (ratio %.3f, idle %.3f ms)%s"
-            % (rec["attributed_s"] * 1e3, rec["step_wall_s"] * 1e3,
-               rec["ratio"], rec["idle_s"] * 1e3,
-               "" if doc.get("reconciled") else
-               "  ** BELOW the 0.90 gate — table under-attributes **"))
-    if doc.get("mfu") is not None:
-        line = "# MFU (measured step wall): %.4f" % doc["mfu"]
-        if doc.get("items_per_s"):
-            line += "  (%.1f items/s)" % doc["items_per_s"]
-        lines.append(line)
     return "\n".join(lines)
 
 
@@ -189,56 +143,14 @@ def format_diff(before, after, prof, top=25):
     return "\n".join(lines)
 
 
-def _capture_program(name, batch, hw):
-    """(jitted step fn, args, items_per_step) for --capture."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    sys.path.insert(0, REPO)
-    if name == "tiny-train":
-        from mxnet_tpu.profiling.bench_ledger import _tiny_train_step
-        return _tiny_train_step()
-    import bench
-    rng = np.random.default_rng(0)
-    if name in ("resnet50-infer", "resnet50"):
-        fwd, pvals = bench.build_forward(batch, hw=hw)
-        data = jnp.asarray(rng.standard_normal(
-            (batch, 3, hw, hw), dtype=np.float32), jnp.bfloat16)
-        pvals = jax.device_put(pvals)
-        return fwd, (pvals, data), batch
-    if name == "resnet50-train":
-        step, params, moms = bench.build_train(batch)
-        data = jnp.asarray(rng.standard_normal(
-            (batch, 3, 224, 224), dtype=np.float32), jnp.bfloat16)
-        labels = jnp.asarray(
-            rng.integers(0, 1000, batch).astype(np.int32))
-        return step, (params, moms, data, labels), batch
-    print("mfu_report: unknown capture program %r (try "
-          "resnet50-infer, resnet50-train, tiny-train)" % name,
-          file=sys.stderr)
-    raise SystemExit(2)
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(prog="mfu_report",
                                  description=__doc__.splitlines()[0])
-    ap.add_argument("paths", nargs="*", help="attribution document(s)")
+    ap.add_argument("paths", nargs="*", help="ledger document(s)")
     ap.add_argument("--diff", action="store_true",
                     help="diff two documents (before after)")
-    ap.add_argument("--capture", metavar="PROGRAM",
-                    help="run PROGRAM under jax.profiler and join "
-                         "(resnet50-infer | resnet50-train | "
-                         "tiny-train)")
-    ap.add_argument("--steps", type=int, default=3)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--hw", type=int, default=224,
-                    help="input resolution for resnet50-infer")
-    ap.add_argument("-o", "--out", help="write the (joined) document "
-                                        "here as JSON")
-    ap.add_argument("--chrome", metavar="PATH",
-                    help="write a merged chrome-trace (telemetry + "
-                         "spans + attribution strip) to PATH")
+    ap.add_argument("-o", "--out", help="write the document here as "
+                                        "JSON")
     ap.add_argument("--hlo", metavar="PATH",
                     help="price a raw optimized-HLO text dump")
     ap.add_argument("--json", action="store_true",
@@ -261,15 +173,6 @@ def main(argv=None):
             print(format_diff(before, after, prof, top=args.top))
         return 0
 
-    if args.capture:
-        prof = _load_profiling(standalone=False)
-        step_fn, fn_args, items = _capture_program(
-            args.capture, args.batch, args.hw)
-        doc = prof.attribution_run(step_fn, fn_args, steps=args.steps,
-                                   items_per_step=items)
-        _finish(doc, args, prof)
-        return 0 if doc.get("reconciled", True) else 1
-
     if args.hlo:
         prof = _load_profiling()
         with open(args.hlo, "r", encoding="utf-8") as f:
@@ -278,8 +181,8 @@ def main(argv=None):
         return 0
 
     if len(args.paths) != 1:
-        print("mfu_report: exactly one document unless --diff/"
-              "--capture/--hlo", file=sys.stderr)
+        print("mfu_report: exactly one document unless --diff/--hlo",
+              file=sys.stderr)
         return 2
     prof = _load_profiling()
     doc = _read_doc(args.paths[0])
@@ -290,12 +193,6 @@ def main(argv=None):
 def _finish(doc, args, prof):
     if args.out:
         prof.ledger.dump(doc, args.out)
-    if args.chrome:
-        # full-framework path only: the merged trace needs the live
-        # telemetry registry + span rings
-        import mxnet_tpu as mx
-        mx.telemetry.export.dump_chrome_trace(args.chrome,
-                                              attribution=doc)
     if args.json:
         print(json.dumps(doc, indent=1, sort_keys=True))
     else:

@@ -1,9 +1,6 @@
 #!/usr/bin/env python
-"""perf_gate — fail CI on benchmark regressions and signal-free zeros.
+"""perf_gate — fail CI on artifact regressions and signal-free zeros.
 
-    python tools/perf_gate.py BENCH_r06.json
-    python tools/perf_gate.py bench_out.json --tolerance 0.2 \\
-        --tol mfu_bf16=0.1 --tol resnet50_inference_int8_bs128=0.3
     python tools/perf_gate.py io_bench.json --io
     python tools/perf_gate.py serving_bench.json --serving
     python tools/perf_gate.py kernel_bench.json --kernels
@@ -11,7 +8,7 @@
     python tools/perf_gate.py lockgraph.json --locks
     python tools/perf_gate.py goodput.json --goodput
 
-``--io`` gates a tools/io_bench.py version-2 artifact instead: every
+``--io`` gates a tools/io_bench.py version-2 artifact: every
 stage's img/s must stay within tolerance of the committed last-good
 (``docs/artifacts/IO_LAST_GOOD.json``), the multi-process pipeline
 must hold its ratio over the single-process DataLoader baseline, and
@@ -32,17 +29,6 @@ tokens/s floor vs last-good, inter-token p99 growth inverted, paged
 greedy == unpaged reference, the cache-occupancy histogram present —
 and an artifact that DROPS the stage while last-good carries it is
 itself a regression.
-
-``--health`` adds the model-health section to the default bench
-gate: the ``health`` embed (profiling/health.py — sentry verdict,
-loss EWMA, params drift fingerprint) must be present whenever the
-last-good artifact carries one, any run that trained must be
-nonfinite-free with its fingerprint pinned, and a disabled sentry is
-itself a regression (an ungated artifact cannot claim clean
-numerics). The committed health-bearing artifact lives at
-``docs/artifacts/HEALTH_LAST_GOOD.json`` and the example first-NaN
-postmortem at ``docs/artifacts/NAN_POSTMORTEM_EXAMPLE.json``
-(tier-1 self-tested in tests/test_health.py).
 
 ``--chaos`` gates a tools/chaos_bench.py version-1 artifact against
 ``docs/artifacts/CHAOS_LAST_GOOD.json`` — the elasticity SLOs as CI
@@ -104,27 +90,20 @@ exists the kernel/fallback speedup must hold ``--kernels-min-ratio``
 (a compiled kernel that LOSES to its fallback is a regression; a CPU
 artifact records ``null`` and the ratio gate notes it).
 
-Compares a bench artifact against a reference artifact given with
-``--last-good`` (no bench measurement is committed: the driver's
-``PERF_LEDGER.jsonl`` is the record of what ran on the chip) with
-per-metric tolerances. The artifact may be any of the shapes the
-bench pipeline produces: a driver round file ({"parsed": {...}}), a
-raw result line (dict), or a last-good wrapper ({"line": "..."}).
-A ``memory`` section additionally gates the per-stage static peak
-live bytes embedded by the cost-ledger pass (growth beyond
-``--mem-tol`` is the regression — direction inverted vs throughput).
+One mode flag is required: every mode compares its artifact with the
+committed ``docs/artifacts/*_LAST_GOOD.json`` of that mode (or
+``--last-good``). Chip measurements are not gated here: the driver
+judges ``benchmark/run.py`` and records it in ``PERF_LEDGER.jsonl``.
 
 Exit codes:
   0  within tolerance,
-  1  regression: headline or a compared metric fell more than its
-     tolerance below last-good, or a zero-value artifact that at
-     least carries diagnostics,
-  2  usage / unreadable artifact,
-  3  bare-zero: value 0.0 with NO diag and NO cost_ledger — the
-     signal-free artifact shape PR 6 exists to abolish.
+  1  regression: a compared metric fell more than its tolerance
+     below last-good, or a truth contract of the mode is broken,
+  2  usage (no mode flag) / unreadable artifact,
+  3  bare-zero: an artifact that measured nothing.
 
-Stdlib only; wired as a tier-1 test over the committed artifacts
-(tests/test_profiling.py), so the gate itself cannot rot.
+Stdlib only; wired as tier-1 tests over the committed artifacts, so
+the gate itself cannot rot.
 """
 from __future__ import annotations
 
@@ -134,8 +113,8 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# --last-good not given: each artifact mode then falls back to its own
-# committed reference; the bench mode has none and asks for one
+# --last-good not given: each mode falls back to its own committed
+# reference
 DEFAULT_LAST_GOOD = None
 DEFAULT_IO_LAST_GOOD = os.path.join(REPO, "docs", "artifacts",
                                     "IO_LAST_GOOD.json")
@@ -156,199 +135,6 @@ DEFAULT_TAIL_LAST_GOOD = os.path.join(REPO, "docs", "artifacts",
 # missing one of these has not exercised the SLO it claims to gate
 REQUIRED_CHAOS_FAMILIES = ("preemption_storm", "straggler",
                            "replica_kill", "decode", "colocation")
-
-# metrics compared when both sides carry them; values are "bigger is
-# better" throughputs/ratios
-_DEFAULT_METRICS = (
-    "mfu_bf16",
-    "resnet50_inference_fp32_bs128",
-    "resnet50_inference_int8_bs128",
-    "resnet50_train_bf16_bs128",
-    "allreduce_gbps",
-    "transformer_train_tokens_per_s",
-)
-
-
-def parse_artifact(doc):
-    """Normalize any bench artifact shape to the result dict."""
-    if not isinstance(doc, dict):
-        raise ValueError("artifact is not a JSON object")
-    if isinstance(doc.get("parsed"), dict):     # driver round file
-        doc = doc["parsed"]
-    if isinstance(doc.get("line"), str):        # last-good wrapper
-        doc = json.loads(doc["line"])
-    if "metric" not in doc or "value" not in doc:
-        raise ValueError("no metric/value in artifact")
-    return doc
-
-
-def load_artifact(path):
-    with open(path, "r", encoding="utf-8") as f:
-        return parse_artifact(json.load(f))
-
-
-def _stage_memory(doc):
-    """{stage: peak_live_mb} from an artifact's embedded cost-ledger
-    stage summaries (PR 7: bench_ledger attaches a bounded memory
-    section per stage)."""
-    out = {}
-    stages = (doc.get("cost_ledger") or {}).get("stages") or {}
-    for stage, s in stages.items():
-        if not isinstance(s, dict):
-            continue
-        memory = s.get("memory")
-        if isinstance(memory, dict) and \
-                isinstance(memory.get("peak_live_mb"), (int, float)):
-            out[stage] = float(memory["peak_live_mb"])
-    return out
-
-
-def gate_memory(candidate, last_good, mem_tolerance=0.15):
-    """(rc, [messages]) for the memory section: per-stage static peak
-    live bytes must not GROW beyond tolerance (direction inverted vs
-    the throughput metrics — more resident bytes is the regression;
-    arXiv 2004.13336's point is exactly that the bytes, not the math,
-    are the scaling ceiling)."""
-    rc = 0
-    msgs = []
-    mine, good = _stage_memory(candidate), _stage_memory(last_good)
-    for stage in sorted(set(mine) & set(good)):
-        a, b = good[stage], mine[stage]
-        if a <= 0:
-            continue
-        if b > (1.0 + mem_tolerance) * a:
-            rc = 1
-            msgs.append(
-                "REGRESSION memory[%s]: peak live %.2fMB > %.2fMB "
-                "(last good %.2fMB, tolerance %.0f%%)"
-                % (stage, b, (1.0 + mem_tolerance) * a, a,
-                   mem_tolerance * 100))
-        else:
-            msgs.append("memory[%s]: peak live %.2fMB vs %.2fMB (ok)"
-                        % (stage, b, a))
-    return rc, msgs
-
-
-def _is_finite_number(v):
-    return isinstance(v, (int, float)) and not isinstance(v, bool) \
-        and v == v and v not in (float("inf"), float("-inf"))
-
-
-def gate_health(candidate, last_good):
-    """(rc, [messages]) for the model-health section: the ``health``
-    embed (profiling/health.py + bench.py) must be PRESENT when
-    last-good carries one (a dropped verdict cannot silently leave
-    the gate), the sentry verdict must be nonfinite-free for any run
-    that trained (steps > 0), the trained-params drift fingerprint
-    must be pinned whenever a training stage produced a number, and
-    the loss EWMA — when carried — must be finite."""
-    rc = 0
-    msgs = []
-    mine = candidate.get("health")
-    good = last_good.get("health")
-    if not isinstance(mine, dict):
-        if isinstance(good, dict):
-            return 1, ["REGRESSION health: artifact carries no "
-                       "'health' embed but last-good does (the "
-                       "model-health verdict cannot silently drop "
-                       "out of the artifact chain)"]
-        return 0, ["health: no embed on either side (pre-health "
-                   "artifacts — ok)"]
-    verdict = mine.get("verdict")
-    nonfinite = mine.get("nonfinite_total", 0)
-    steps = mine.get("steps", 0)
-    if verdict == "nonfinite" or (isinstance(nonfinite, (int, float))
-                                  and nonfinite > 0):
-        rc = 1
-        trip = mine.get("first_trip") or {}
-        msgs.append(
-            "REGRESSION health: training went nonfinite (%s values, "
-            "first at seam %s step %s) — a number measured on NaN "
-            "weights is not a measurement"
-            % (nonfinite, trip.get("source"), trip.get("step")))
-    elif verdict == "disabled":
-        rc = 1
-        msgs.append("REGRESSION health: sentry was DISABLED for the "
-                    "run (verdict 'disabled') — an ungated artifact "
-                    "cannot claim nonfinite-free training")
-    else:
-        msgs.append("health: verdict %s, %s nonfinite across %s "
-                    "steps (ok)" % (verdict, nonfinite, steps))
-    trained = steps and steps > 0
-    good_fp = isinstance(good, dict) and good.get("fingerprint")
-    fp = mine.get("fingerprint")
-    if trained or good_fp:
-        if not (isinstance(fp, str) and fp):
-            rc = 1
-            msgs.append(
-                "REGRESSION health: params fingerprint missing (%r) "
-                "— the drift vocabulary (resume/chaos/consistency) "
-                "requires every trained artifact to pin its weights"
-                % (fp,))
-        else:
-            msgs.append("health: params fingerprint %s (pinned)" % fp)
-    ewma = mine.get("loss_ewma")
-    if ewma is not None and not _is_finite_number(ewma):
-        rc = 1
-        msgs.append("REGRESSION health: loss EWMA %r is not finite"
-                    % (ewma,))
-    elif ewma is not None:
-        msgs.append("health: loss ewma %.6g (%s anomalies)"
-                    % (ewma, mine.get("loss_anomalies", 0)))
-    return rc, msgs
-
-
-def gate(candidate, last_good, tolerance=0.25, per_metric=None,
-         metrics=_DEFAULT_METRICS, mem_tolerance=0.15,
-         health=False):
-    """(exit_code, [messages]) for a candidate vs last-good pair."""
-    per_metric = per_metric or {}
-    msgs = []
-    value = float(candidate.get("value") or 0.0)
-    if value == 0.0:
-        has_signal = bool(candidate.get("diag")
-                          or candidate.get("cost_ledger"))
-        if not has_signal:
-            return 3, ["bare-zero artifact: value=0.0 with no diag "
-                       "and no cost_ledger (signal-free — rejected)"]
-        return 1, ["zero-value artifact (diagnosed: %s)"
-                   % ("error=" + str(candidate.get("error"))[:120]
-                      if candidate.get("error") else "see diag")]
-    rc = 0
-    good_value = float(last_good.get("value") or 0.0)
-    tol = per_metric.get("value", per_metric.get(
-        str(candidate.get("metric")), tolerance))
-    if good_value > 0 and value < (1.0 - tol) * good_value:
-        rc = 1
-        msgs.append(
-            "REGRESSION %s: %.2f < %.2f (last good %.2f, tolerance "
-            "%.0f%%)" % (candidate.get("metric"), value,
-                         (1.0 - tol) * good_value, good_value,
-                         tol * 100))
-    else:
-        msgs.append("headline %s: %.2f vs last good %.2f (ok)"
-                    % (candidate.get("metric"), value, good_value))
-    for key in metrics:
-        a, b = last_good.get(key), candidate.get(key)
-        if not isinstance(a, (int, float)) or \
-                not isinstance(b, (int, float)) or a <= 0:
-            continue
-        tol = per_metric.get(key, tolerance)
-        if b < (1.0 - tol) * a:
-            rc = 1
-            msgs.append("REGRESSION %s: %.4g < %.4g (tolerance %.0f%%)"
-                        % (key, b, (1.0 - tol) * a, tol * 100))
-        else:
-            msgs.append("%s: %.4g vs %.4g (ok)" % (key, b, a))
-    mem_rc, mem_msgs = gate_memory(candidate, last_good,
-                                   mem_tolerance=mem_tolerance)
-    rc = rc or mem_rc
-    msgs.extend(mem_msgs)
-    if health:
-        h_rc, h_msgs = gate_health(candidate, last_good)
-        rc = rc or h_rc
-        msgs.extend(h_msgs)
-    return rc, msgs
 
 
 def _io_stage_rates(doc):
@@ -1481,19 +1267,12 @@ def gate_kernels(candidate, last_good, tolerance=0.25, min_ratio=1.0):
 def main(argv=None):
     ap = argparse.ArgumentParser(prog="perf_gate",
                                  description=__doc__.splitlines()[0])
-    ap.add_argument("artifact", help="bench artifact JSON to gate")
+    ap.add_argument("artifact", help="artifact JSON to gate")
     ap.add_argument("--last-good", default=DEFAULT_LAST_GOOD,
                     help="reference artifact (default: the mode's "
-                         "committed docs/artifacts/*_LAST_GOOD.json; "
-                         "required for a bench artifact)")
+                         "committed docs/artifacts/*_LAST_GOOD.json)")
     ap.add_argument("--tolerance", type=float, default=0.25,
                     help="default allowed fractional drop (0.25)")
-    ap.add_argument("--tol", action="append", default=[],
-                    metavar="METRIC=FRAC",
-                    help="per-metric tolerance override (repeatable)")
-    ap.add_argument("--mem-tol", type=float, default=0.15,
-                    help="allowed fractional GROWTH of per-stage peak "
-                         "live bytes (memory section; 0.15)")
     ap.add_argument("--io", action="store_true",
                     help="gate a tools/io_bench.py v2 artifact "
                          "(stages + pipeline ratio + input-wait)")
@@ -1533,11 +1312,6 @@ def main(argv=None):
                     help="required compiled-kernel / fallback speedup "
                          "where a compiled timing exists (1.0 — a "
                          "kernel must never LOSE to its fallback)")
-    ap.add_argument("--health", action="store_true",
-                    help="additionally gate the model-health embed: "
-                         "presence vs last-good, nonfinite-free "
-                         "training, pinned params fingerprint, "
-                         "finite loss EWMA (profiling/health.py)")
     ap.add_argument("--locks", action="store_true",
                     help="gate a lock_witness artifact "
                          "(analysis/witness.py dump): any acquisition "
@@ -1714,43 +1488,11 @@ def main(argv=None):
               % {0: "PASS", 1: "REGRESSION", 2: "UNREADABLE",
                  3: "BARE-ZERO"}.get(rc, rc))
         return rc
-    per_metric = {}
-    for spec in args.tol:
-        if "=" not in spec:
-            print("perf_gate: --tol wants METRIC=FRAC, got %r" % spec,
-                  file=sys.stderr)
-            return 2
-        k, v = spec.split("=", 1)
-        try:
-            per_metric[k] = float(v)
-        except ValueError:
-            print("perf_gate: bad tolerance %r" % spec,
-                  file=sys.stderr)
-            return 2
-    try:
-        candidate = load_artifact(args.artifact)
-    except (OSError, ValueError) as e:
-        print("perf_gate: cannot read artifact %s: %s"
-              % (args.artifact, e), file=sys.stderr)
-        return 2
-    if args.last_good is None:
-        print("perf_gate: a bench artifact needs --last-good (no bench "
-              "measurement is committed)", file=sys.stderr)
-        return 2
-    try:
-        last_good = load_artifact(args.last_good)
-    except (OSError, ValueError) as e:
-        print("perf_gate: cannot read last-good %s: %s"
-              % (args.last_good, e), file=sys.stderr)
-        return 2
-    rc, msgs = gate(candidate, last_good, tolerance=args.tolerance,
-                    per_metric=per_metric, mem_tolerance=args.mem_tol,
-                    health=args.health)
-    for m in msgs:
-        print(m)
-    print("perf_gate: %s"
-          % {0: "PASS", 1: "REGRESSION", 3: "BARE-ZERO"}.get(rc, rc))
-    return rc
+    ap.print_usage(sys.stderr)
+    print("perf_gate: one mode flag is required (--io, --serving, "
+          "--chaos, --goodput, --tail, --locks, --kernels)",
+          file=sys.stderr)
+    return 2
 
 
 if __name__ == "__main__":
