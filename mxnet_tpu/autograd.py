@@ -7,6 +7,15 @@ subgraph as one pure JAX function and differentiates it with jax.vjp — the
 FGradient attribute table is replaced by JAX AD, and XLA compiles/fuses the
 whole backward. RNG keys drawn during forward are recorded as constants so the
 replay is bit-identical (dropout masks match between forward and backward).
+
+A recorded call of a hybridized block is not run again: its forward program
+already wrote the residuals, and its node keeps the pullback (``_Node.
+pullback``). The replay sees such a node as a ``jax.custom_vjp`` whose forward
+is the outputs it gave and whose backward is that pullback, so the rest of the
+tape chains through it. The node's plain closure is replayed instead where the
+pullback cannot serve: ``create_graph=True`` (it is closed over concrete
+residuals, so it has no derivative of its own), or a leaf asked for now that
+the forward did not differentiate.
 """
 from __future__ import annotations
 
@@ -44,7 +53,7 @@ class _Entry:
 class _Node:
     """One recorded op application (nnvm Node + AGInfo analogue)."""
 
-    __slots__ = ("op", "attrs", "slots", "out_entries", "n_out")
+    __slots__ = ("op", "attrs", "slots", "out_entries", "n_out", "pullback")
 
     def __init__(self, op, attrs, slots, n_out):
         self.op = op
@@ -52,6 +61,10 @@ class _Node:
         self.slots = slots  # list of ("e", entry, snapshot) | ("c", value)
         self.out_entries = []
         self.n_out = n_out
+        # (outs, diff, run) where the forward kept its pullback: the raw
+        # outputs, which slots it differentiated, and run(cts of the
+        # inexact outputs) -> the cotangents of those slots
+        self.pullback = None
 
 
 class _ClosureOp:
@@ -152,6 +165,20 @@ def mark_variables(variables, gradients=None, grad_reqs="write"):
         _mark_variable(v)
 
 
+def _inexact(data):
+    return jnp.issubdtype(data.dtype, jnp.inexact)
+
+
+def _differentiated(nd):
+    """Whether a forward that keeps its pullback has to differentiate
+    ``nd``: it is on the tape, as a variable that wants a gradient or as
+    a recorded node's output (the rule of ``_collect``'s ``grad_leaves``,
+    applied when the forward runs)."""
+    e = nd._entry
+    return (e is not None and (e.node is not None or nd._grad_req != "null")
+            and _inexact(nd._data))
+
+
 def _slot_for(nd):
     if nd._entry is not None:
         return ("e", nd._entry, nd._data)
@@ -218,8 +245,35 @@ def _collect(head_entries):
     return nodes, grad_leaves
 
 
-def _build_replay(nodes, grad_leaves, head_entries):
-    """Pure function leaf_values -> head_values replaying the tape."""
+def _held_fn(node):
+    """A node's forward as the replay runs it when the stored pullback
+    serves: the outputs the forward program gave, whatever the inputs,
+    and for their derivative the pullback that program kept."""
+    outs, diff, run = node.pullback
+    live = [i for i, o in enumerate(outs) if _inexact(o)]
+    live_outs = tuple(outs[i] for i in live)
+    held = jax.custom_vjp(lambda *diff_ins: live_outs)
+    held.defvjp(lambda *diff_ins: (live_outs, None), lambda _, cts: run(cts))
+
+    def fn(*ins):
+        vals = list(outs)
+        for i, v in zip(live, held(*[x for x, d in zip(ins, diff) if d])):
+            vals[i] = v
+        return vals
+
+    return fn
+
+
+def _pullback_serves(node, wanted):
+    """False where a leaf asked for now (``wanted``: ids of the grad
+    leaves' entries) feeds a slot the forward did not differentiate."""
+    return all(d or s[0] != "e" or id(s[1]) not in wanted
+               for s, d in zip(node.slots, node.pullback[1]))
+
+
+def _build_replay(nodes, grad_leaves, head_entries, held):
+    """Pure function leaf_values -> head_values replaying the tape;
+    ``held`` maps id(node) to the function that stands in for its op."""
 
     def f(*leaf_vals):
         env = {id(e): v for e, v in zip(grad_leaves, leaf_vals)}
@@ -230,7 +284,7 @@ def _build_replay(nodes, grad_leaves, head_entries):
                     ins.append(env.get(id(s[1]), s[2]))
                 else:
                     ins.append(s[1])
-            raw = node.op.fn(*ins, **node.attrs)
+            raw = held.get(id(node), node.op.fn)(*ins, **node.attrs)
             raws = list(raw) if isinstance(raw, (tuple, list)) else [raw]
             for e, v in zip(node.out_entries, raws):
                 env[id(e)] = v
@@ -267,7 +321,12 @@ def _compute_gradients(heads, head_grads, create_graph=False):
     if not grad_leaves:
         raise MXNetError("no variables with grad attached found in the graph")
 
-    f = _build_replay(nodes, grad_leaves, head_entries)
+    held = {}
+    if not create_graph:
+        wanted = {id(e) for e in grad_leaves}
+        held = {id(n): _held_fn(n) for n in nodes
+                if n.pullback is not None and _pullback_serves(n, wanted)}
+    f = _build_replay(nodes, grad_leaves, head_entries, held)
     leaf_vals = [e.nd_ref()._data for e in grad_leaves]
 
     if head_grads is None:
@@ -279,8 +338,9 @@ def _compute_gradients(heads, head_grads, create_graph=False):
         ]
 
     def gradfn(*lv):
-        # the replay is traced anew by every call: the span holds that
-        # re-trace and the dispatch of the linearised forward
+        # the replay is traced anew by every call: the span holds the
+        # linearisation of what the tape holds besides the nodes that
+        # kept their pullback, and the next span runs the pullbacks
         with _tracing.span("autograd.vjp"):
             _, vjp_fn = jax.vjp(f, *lv)
         with _tracing.span("autograd.pullback"):
@@ -301,11 +361,13 @@ def _free_graph():
     """Drop the recorded graph and everything it holds. A node and its
     output entries refer to each other, so left to themselves the arrays
     snapshotted in a node's slots (every parameter a cached op read, its
-    inputs and its outputs) stay on the device until the cyclic collector
+    inputs and its outputs) and the residuals its pullback is closed over
+    stay on the device until the cyclic collector
     happens to run: at a large model's size, several steps' worth."""
     tape = _st().tape
     for node in tape:
         node.slots = node.out_entries = ()
+        node.pullback = None
     tape.clear()
 
 

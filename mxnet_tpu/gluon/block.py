@@ -391,27 +391,39 @@ class HybridBlock(Block):
 
         entry = self._cached_jit.get(sig)
         if entry is None:
-            entry = self._build_cached(plist, in_spec, training)
+            entry = (*self._build_cached(plist, in_spec, training), {})
             self._cached_jit[sig] = entry
-        jfn, out_spec_box, aux_params_box = entry
+        jfn, out_spec_box, aux_params_box, recorded = entry
 
         key = _random.next_key()
-
-        def run(*datas):
-            return jfn(tuple(datas[:len(pvals)]), key,
-                       *datas[len(pvals):])
-
-        raw = run(*pvals, *in_datas)
-        flat_out_data, aux_data = raw
+        pvals = tuple(pvals)
+        pullback = None
+        if autograd.is_recording():
+            nds = [p.data() for _, p in plist] + flat_in
+            diff = tuple(autograd._differentiated(a) for a in nds)
+        if autograd.is_recording() and any(diff):
+            # one program gives the outputs and writes the residuals; the
+            # pullback it returns waits on the tape for backward
+            if diff not in recorded:
+                recorded[diff] = self._build_recorded(jfn, diff, training)
+            flat_out_data, aux_data, pullback = recorded[diff][0](
+                pvals, key, in_datas)
+        else:
+            flat_out_data, aux_data = jfn(pvals, key, *in_datas)
         outs = [NDArray(d) for d in flat_out_data]
 
         if autograd.is_recording():
-            param_nds = [p.data() for _, p in plist]
-            autograd._record_closure(
+            # the plain program stays on the node for what the pullback
+            # cannot serve (autograd._compute_gradients); never called,
+            # it is never compiled
+            n_params = len(pvals)
+            node = autograd._record_closure(
                 f"cachedop_{self.name}",
-                lambda *datas: jfn(tuple(datas[:len(pvals)]), key,
-                                   *datas[len(pvals):])[0],
-                param_nds + flat_in, outs)
+                lambda *datas: jfn(tuple(datas[:n_params]), key,
+                                   *datas[n_params:])[0],
+                nds, outs)
+            if pullback is not None:
+                node.pullback = (flat_out_data, diff, pullback)
 
         # write back functional aux updates (running stats)
         for p, d in zip(aux_params_box[0], aux_data):
@@ -487,18 +499,72 @@ class HybridBlock(Block):
 
         return jax.jit(self._named(pure_fn, False)), [out_spec], [[]]
 
-    def _named(self, pure_fn, training):
+    def _named(self, pure_fn, training, part=""):
         """Name the traced program after the block and the mode, so a
         capture's ``XLA Modules`` line reads ``jit_mx_<block>_train`` /
-        ``_eval`` and not ``jit_pure_fn``. ``<block>`` is the prefix the
+        ``_eval`` and not ``jit_pure_fn``; a recorded call's two programs
+        add ``part``: ``_fwd`` and ``_bwd``. ``<block>`` is the prefix the
         user gave this block, else its class: never gluon's numbered
         name, which counts the blocks built before it in the process.
         The persistent compilation cache holds the module's name in its
         key, so a name that moved with the count would compile cold."""
-        pure_fn.__name__ = pure_fn.__qualname__ = "mx_%s_%s" % (
+        pure_fn.__name__ = pure_fn.__qualname__ = "mx_%s_%s%s" % (
             re.sub(r"\W", "_", self._program_alias),
-            "train" if training else "eval")
+            "train" if training else "eval", part)
         return pure_fn
+
+    def _build_recorded(self, jfn, diff, training):
+        """``(call, fwd, bwd)`` for a recorded call that differentiates
+        the arguments ``diff`` marks (parameters, then inputs). ``fwd``
+        and ``bwd`` are its two programs: the forward that also writes
+        the residuals, and the pullback over them.
+        ``call(param_vals, key, in_datas)`` runs ``fwd`` and gives the
+        outputs, the aux updates and ``pullback(cts)``, which runs
+        ``bwd`` on the cotangents of the inexact outputs.
+
+        ``fwd`` returns only those leaves of ``jax.vjp``'s pullback that
+        it computed. A leaf that is one of its arguments or outputs (a
+        weight kept for the input's gradient, an output its own
+        derivative needs) would be copied to be returned a second time:
+        the trace notes where each leaf comes from, and ``call`` puts
+        the tree together from the arrays it already holds."""
+        pure_fn = jfn.__wrapped__
+        vjp_tree = sources = None
+
+        def forward(param_vals, key, *in_datas):
+            nonlocal vjp_tree, sources
+            args = (*param_vals, *in_datas)
+
+            def g(*diff_args):
+                it = iter(diff_args)
+                full = [next(it) if d else a for d, a in zip(diff, args)]
+                outs, aux = pure_fn(tuple(full[:len(param_vals)]), key,
+                                    *full[len(param_vals):])
+                return (tuple(o for o in outs if autograd._inexact(o)),
+                        (outs, aux))
+
+            _, vjp_fn, (outs, aux) = jax.vjp(
+                g, *[a for d, a in zip(diff, args) if d], has_aux=True)
+            leaves, vjp_tree = jax.tree_util.tree_flatten(vjp_fn)
+            passed = {id(v): i for i, v in enumerate((*args, *outs))}
+            sources = [passed.get(id(leaf)) for leaf in leaves]
+            return outs, aux, [leaf for leaf, i in zip(leaves, sources)
+                               if i is None]
+
+        def backward(vjp_fn, cts):
+            return vjp_fn(cts)
+
+        fwd = jax.jit(self._named(forward, training, "_fwd"))
+        bwd = jax.jit(self._named(backward, training, "_bwd"))
+
+        def call(param_vals, key, in_datas):
+            outs, aux, computed = fwd(param_vals, key, *in_datas)
+            passed, computed = (*param_vals, *in_datas, *outs), iter(computed)
+            vjp_fn = vjp_tree.unflatten(
+                next(computed) if i is None else passed[i] for i in sources)
+            return outs, aux, lambda cts: bwd(vjp_fn, cts)
+
+        return call, fwd, bwd
 
     def _build_cached(self, plist, in_spec, training):
         """Trace the whole subtree once into a jitted pure function."""

@@ -204,3 +204,101 @@ def test_training_flag_injection():
     assert_almost_equal(y, x.asnumpy())  # identity in predict mode
     y = nd.Dropout(x, p=0.9)  # outside record: predict mode
     assert_almost_equal(y, x.asnumpy())
+
+
+# -- the pullback a recorded hybridized call keeps, and when it is not used --
+def _tanh_dense(hybridize):
+    from mxnet_tpu.gluon import nn
+    np.random.seed(3)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(4, activation="tanh", in_units=3))
+    net.initialize()
+    if hybridize:
+        net.hybridize()
+    return net
+
+
+@pytest.fixture
+def served(monkeypatch):
+    """The nodes whose kept pullback a backward used, as it goes."""
+    nodes, held_fn = [], autograd._held_fn
+    monkeypatch.setattr(autograd, "_held_fn",
+                        lambda node: nodes.append(node) or held_fn(node))
+    return nodes
+
+
+def test_create_graph_replays_the_hybridized_call_and_is_right(served):
+    # the kept pullback is closed over concrete residuals and has no
+    # derivative of its own: second order replays the node's closure
+    got = []
+    for hybridize in (True, False):
+        net = _tanh_dense(hybridize)
+        x = nd.array([[0.3, -0.2, 0.5]])
+        x.attach_grad()
+        with autograd.record():
+            y = net(x).sum()
+            g = autograd.grad([y], [x], create_graph=True,
+                              retain_graph=True)[0]
+            z = (g * g).sum()
+        z.backward()
+        got.append((g.asnumpy(), x.grad.asnumpy()))
+    assert np.abs(got[0][1]).sum() > 0
+    for a, b in zip(*got):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    assert served == []
+
+
+@pytest.mark.parametrize("req_at_forward,replayed", [("null", 1),
+                                                     ("write", 0)])
+def test_grad_of_a_leaf_the_forward_did_not_differentiate(req_at_forward,
+                                                          replayed, served):
+    # autograd.grad turns a "null" request into "write" after the forward
+    # ran: the kept pullback has no cotangent for that input, so the node
+    # is replayed; a leaf that wanted its gradient all along is served
+    want = None
+    for hybridize in (False, True):
+        net = _tanh_dense(hybridize)
+        x = nd.array([[0.3, -0.2, 0.5]])
+        x.attach_grad(grad_req=req_at_forward)
+        with autograd.record():
+            y = (net(x) ** 2).sum()
+        if hybridize:
+            node = autograd._st().tape[0]
+            assert node.pullback[1][-1] is (req_at_forward != "null")
+        g = autograd.grad([y], [x])[0].asnumpy()
+        assert x._grad_req == req_at_forward
+        if want is None:
+            want = g
+    assert np.abs(want).sum() > 0
+    np.testing.assert_allclose(g, want, rtol=1e-6, atol=1e-7)
+    assert len(served) == 1 - replayed
+
+
+def test_backward_leaves_nothing_of_the_step_alive():
+    import jax
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import nn
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu", in_units=8),
+            nn.BatchNorm(in_channels=16), nn.Dropout(0.5),
+            nn.Dense(4, in_units=16))
+    net.initialize()
+    net.hybridize()
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    loss_fn.hybridize()
+    x = nd.array(np.random.randn(5, 8).astype(np.float32))
+    y = nd.array(np.array([0, 1, 2, 3, 0], dtype=np.float32))
+    alive = []
+    for _ in range(6):
+        with autograd.record():
+            out = net(x)
+            loss = loss_fn(out, y)
+        node = autograd._st().tape[0]
+        assert node.pullback is not None
+        loss.backward()
+        # the residuals went with the graph; parameters, gradients, this
+        # step's outputs and the generator's key are what stays
+        assert node.pullback is None and autograd._st().tape == []
+        alive.append((len(jax.live_arrays()),
+                      sum(a.nbytes for a in jax.live_arrays())))
+    assert len(set(alive[1:])) == 1, alive

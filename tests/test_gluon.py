@@ -488,3 +488,174 @@ def test_libinfo():
     ev = li.env_vars()
     assert "MXNET_ENGINE_TYPE" in ev and len(ev["MXNET_ENGINE_TYPE"]) == 2
     assert any(p.endswith(".so") for p in li.find_lib_path())
+
+
+# -- a recorded hybridized call runs its forward once and keeps the pullback --
+class _WithIndex(gluon.HybridBlock):
+    """A float output and an integer one: only the first is differentiated."""
+
+    def __init__(self):
+        super().__init__()
+        with self.name_scope():
+            self.dense = nn.Dense(4, in_units=5)
+
+    def hybrid_forward(self, F, x):
+        out = self.dense(x)
+        return out, F.argmax(out, axis=1).astype("int32")
+
+
+def _seq(*layers):
+    def build():
+        net = nn.HybridSequential()
+        net.add(*[make() for make in layers])
+        return net
+    return build
+
+
+_X = np.random.RandomState(0).randn(6, 5).astype(np.float32)
+_X2 = np.random.RandomState(1).randn(6, 5).astype(np.float32)
+_Y = np.array([0, 1, 2, 0, 1, 2], dtype=np.float32)
+
+
+def _hybrid_loss(net, x):
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    loss_fn.hybridize()
+    return loss_fn(net(x), nd.array(_Y))
+
+
+# name -> (the net, the heads from the net and the input, record's mode)
+_RECORDED = {
+    "batchnorm": (_seq(lambda: nn.Dense(8, activation="relu", in_units=5),
+                       lambda: nn.BatchNorm(in_channels=8),
+                       lambda: nn.Dense(3, in_units=8)),
+                  lambda net, x: (net(x) ** 2).sum(), True),
+    "dropout": (_seq(lambda: nn.Dense(16, in_units=5),
+                     lambda: nn.Dropout(0.5),
+                     lambda: nn.Dense(3, in_units=16)),
+                lambda net, x: (net(x) ** 2).sum(), True),
+    "called_twice": (_seq(lambda: nn.Dense(3, activation="tanh", in_units=5)),
+                     lambda net, x: net(x).sum()
+                     + (net(nd.array(_X2)) * net(x)).sum(), True),
+    "hybrid_loss": (_seq(lambda: nn.Dense(8, activation="relu", in_units=5),
+                         lambda: nn.Dense(3, in_units=8)),
+                    _hybrid_loss, True),
+    "predict_mode": (_seq(lambda: nn.Dense(8, in_units=5),
+                          lambda: nn.BatchNorm(in_channels=8),
+                          lambda: nn.Dropout(0.5)),
+                     lambda net, x: (net(x) ** 2).sum(), False),
+    "integer_output": (_WithIndex,
+                       lambda net, x: (net(x)[0] ** 2).sum(), True),
+}
+
+
+def _recorded_step(build, heads_of, train_mode, as_before, x_wants_grad):
+    """One recorded step of a fresh net with the same weights each time:
+    heads, gradients, aux states. ``as_before`` makes the call differentiate
+    nothing, so it runs the plain program and backward replays the node's
+    closure under jax.vjp: the path every recorded call took before."""
+    np.random.seed(5)
+    mx.random.seed(5)
+    net = build()
+    net.initialize()
+    net.hybridize()
+    x = nd.array(_X)
+    if x_wants_grad:
+        x.attach_grad()
+    with pytest.MonkeyPatch.context() as patch:
+        if as_before:
+            patch.setattr(autograd, "_differentiated", lambda a: False)
+        with autograd.record(train_mode=train_mode):
+            heads = heads_of(net, x)
+    kept = [n.pullback is not None for n in autograd._st().tape
+            if n.op.name.startswith("cachedop_")]
+    heads.backward()
+    params = net.collect_params().values()
+    got = [heads.asnumpy()]
+    got += [p.grad().asnumpy() for p in params if p.grad_req != "null"]
+    got += [p.data().asnumpy() for p in params if p.grad_req == "null"]
+    if x_wants_grad:
+        got.append(x.grad.asnumpy())
+    return got, kept, net
+
+
+@pytest.mark.parametrize("x_wants_grad", [False, True])
+@pytest.mark.parametrize("case", sorted(_RECORDED))
+def test_recorded_hybrid_call_equals_the_replay(case, x_wants_grad):
+    build, heads_of, train_mode = _RECORDED[case]
+    got, kept, net = _recorded_step(build, heads_of, train_mode, False,
+                                    x_wants_grad)
+    want, kept_before, _ = _recorded_step(build, heads_of, train_mode, True,
+                                          x_wants_grad)
+    assert kept and all(kept) and not any(kept_before)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+    # every recorded call took the forward that writes the residuals: the
+    # plain program of that mode was never run, so never compiled
+    for jfn, _, _, recorded in net._cached_jit.values():
+        assert jfn._cache_size() == 0
+        assert all(fwd._cache_size() == 1 and bwd._cache_size() == 1
+                   for _, fwd, bwd in recorded.values())
+
+
+def test_recorded_call_differentiates_only_what_is_on_the_tape():
+    np.random.seed(5)
+    net = _RECORDED["batchnorm"][0]()
+    net.initialize()
+    net.hybridize()
+    params = list(net.collect_params().values())
+    params[0].grad_req = "null"            # a frozen leaf
+    frozen = params[0].data().asnumpy()
+    x = nd.array(_X)                       # the batch carries no entry
+    with autograd.record():
+        out = net(x)
+    outs, diff, run = autograd._st().tape[-1].pullback
+    by_name = dict(zip([n for n, _ in net._cached_plist] + ["x"], diff))
+    assert by_name == {n: p.grad_req != "null"
+                       for n, p in net._cached_plist} | {"x": False}
+    assert sum(diff) == 5 and by_name[params[0].name] is False
+    # the pullback has no output for the batch, the frozen leaf or the
+    # running statistics: nothing computes a gradient nobody reads
+    cts = run((nd.ones(out.shape)._data,))
+    assert len(cts) == sum(diff)
+    out.backward()
+    np.testing.assert_array_equal(params[0].data().asnumpy(), frozen)
+    with pytest.raises(Exception):
+        params[0].grad()
+
+
+def test_recorded_call_honours_add_and_a_second_backward():
+    np.random.seed(5)
+    net = _RECORDED["called_twice"][0]()
+    net.initialize()
+    net.hybridize()
+    weight, bias = net.collect_params().values()
+    weight.grad_req = "add"
+    x = nd.array(_X)
+    with autograd.record():
+        loss = (net(x) ** 2).sum()
+    loss.backward(retain_graph=True)
+    once_w, once_b = weight.grad().asnumpy(), bias.grad().asnumpy()
+    assert np.abs(once_w).sum() > 0
+    loss.backward()                         # the kept pullback, again
+    np.testing.assert_allclose(weight.grad().asnumpy(), 2 * once_w,
+                               rtol=1e-6)
+    np.testing.assert_allclose(bias.grad().asnumpy(), once_b, rtol=1e-6)
+    assert next(iter(net._cached_jit.values()))[0]._cache_size() == 0
+    with pytest.raises(mx.MXNetError, match="already been freed"):
+        loss.backward()
+
+
+def test_dropout_mask_is_the_same_forward_and_backward():
+    net = nn.HybridSequential()
+    net.add(nn.Dropout(0.5))
+    net.hybridize()
+    x = nd.ones((64, 32))
+    x.attach_grad()
+    with autograd.record():
+        y = net(x)
+    y.backward()
+    kept = y.asnumpy() != 0
+    assert 0.2 < kept.mean() < 0.8
+    np.testing.assert_array_equal(x.grad.asnumpy() != 0, kept)
+    np.testing.assert_allclose(x.grad.asnumpy(), y.asnumpy())

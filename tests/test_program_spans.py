@@ -113,6 +113,16 @@ def _holder(events, parent, s, e):
             and e <= ev[2]]
 
 
+def _gluon_dispatches(events):
+    """The outermost ``PjitFunction(mx_...)`` events of one thread."""
+    out, end = [], -1
+    for name, s, e in events:
+        if name.startswith("PjitFunction(mx_") and s >= end:
+            out.append((name, s, e))
+            end = e
+    return out
+
+
 # -- (a) the same spans in the capture and in the ring ----------------------
 def test_train_spans_on_the_host_plane_nested_as_the_table_says(captured):
     lines = [line for line in spans.host_lines(captured["planes"])
@@ -130,8 +140,15 @@ def test_train_spans_on_the_host_plane_nested_as_the_table_says(captured):
     assert captured["n_params"] > 1
     assert spans.dispatches_per_step(captured["planes"], "trainer.update") \
         == 1
-    fns = {n for n, _, _ in events if n.startswith("PjitFunction(mx_")}
-    assert fns == {"PjitFunction(mx_hybridsequential_train)"}
+    # and a recorded call's two programs, the forward that writes the
+    # residuals and the pullback over them: no plain forward beside them
+    fns = _gluon_dispatches(events)
+    assert [n for n, _, _ in fns] == [
+        "PjitFunction(mx_hybridsequential_train_fwd)",
+        "PjitFunction(mx_hybridsequential_train_bwd)"] * STEPS
+    for (_, s, e), span in zip(fns, ["block.call", "autograd.pullback"]
+                               * STEPS):
+        assert len(_holder(events, span, s, e)) == 1
 
 
 def test_ring_holds_the_same_spans_with_parent_links(captured):
@@ -159,33 +176,51 @@ def test_host_reader_on_a_capture(captured, name):
         assert value == 1       # one program, one table read back
 
 
+@pytest.mark.parametrize("name", ("train_gluon_device_ms",
+                                  "train_gluon_executions"))
+def test_gluon_device_reader_on_the_captured_dispatches(captured, name):
+    """A CPU capture has no ``XLA Modules`` line. Lay one out with an
+    execution for every gluon program the capture saw dispatched, named
+    as a device names it: the readers find two a step."""
+    events = next(line for line in spans.host_lines(captured["planes"])
+                  if any(n == "trainer_step" for n, _, _ in line))
+    modules = [("jit_%s(1)" % n[len("PjitFunction("):-1], s, e, None)
+               for n, s, e in _gluon_dispatches(events)]
+    planes = captured["planes"] + [
+        {"name": xplane.DEVICE_PLANE + "0",
+         "lines": [{"name": spans.MODULES_LINE, "events": modules}]}]
+    value = reader(name)({"planes": planes})
+    assert value is not None and value > 0
+    if name == "train_gluon_executions":
+        assert value == 2.0
+
+
 def _made_planes(drop_an_execution=False):
     """Two steps as a chip capture shows them. Times in ns; the forward
-    runs 2 ms from ``block.call`` and 3 ms again under ``jax.vjp``, the
-    transposed program 5 ms, three updates 0.5 ms each; autograd spends
-    10 - 4 - 2 = 4 ms itself."""
+    that writes the residuals runs 5 ms from ``block.call``, the
+    transposed program 5 ms from ``autograd.pullback``, three updates
+    0.5 ms each; autograd spends 10 - 4 - 2 = 4 ms itself."""
     ms = 1_000_000
     host, modules = [(xplane.WINDOW_SPAN, 0, 100 * ms, None)], []
-    fn, mod = "PjitFunction(mx_net0_train)", "jit_mx_net0_train"
+    fwd, bwd = "mx_net0_train_fwd", "mx_net0_train_bwd"
     for k in range(2):
         t, d = 50 * k * ms, 50 * k * ms + 20 * ms
         host += [
             ("block.call", t + 1 * ms, t + 3 * ms, None),
-            (fn, t + 2 * ms, t + 2 * ms + 400, None),
-            (fn, t + 2 * ms + 10, t + 2 * ms + 390, None),   # jaxlib's twin
+            ("PjitFunction(%s)" % fwd, t + 2 * ms, t + 2 * ms + 400, None),
+            # jaxlib's twin
+            ("PjitFunction(%s)" % fwd, t + 2 * ms + 10, t + 2 * ms + 390,
+             None),
             ("autograd.backward", t + 4 * ms, t + 14 * ms, None),
             ("autograd.vjp", t + 5 * ms, t + 9 * ms, None),
-            # the call under the trace holds the dispatch it leads to
-            (fn, t + 6 * ms, t + 8 * ms, None),
-            (fn, t + 7 * ms, t + 7 * ms + 500, None),
             ("autograd.pullback", t + 10 * ms, t + 12 * ms, None),
-            (fn, t + 11 * ms, t + 11 * ms + 300, None),
+            ("PjitFunction(%s)" % bwd, t + 11 * ms, t + 11 * ms + 300, None),
             ("trainer_step", t + 15 * ms, t + 19 * ms, None),
             ("trainer.update", t + 16 * ms, t + 18 * ms, None),
         ]
-        modules += [(mod + "(11)", d, d + 2 * ms, mod + "(11)"),
-                    (mod + "(22)", d + 2 * ms, d + 5 * ms, mod + "(22)"),
-                    (mod + "(33)", d + 5 * ms, d + 10 * ms, mod + "(33)")]
+        modules += [("jit_%s(11)" % fwd, d, d + 5 * ms, "jit_%s(11)" % fwd),
+                    ("jit_%s(33)" % bwd, d + 5 * ms, d + 10 * ms,
+                     "jit_%s(33)" % bwd)]
         for i in range(3):
             s = d + 10 * ms + i * ms
             host.append(("PjitFunction(_step_mom)", t + 16 * ms + i * 1000,
@@ -193,7 +228,7 @@ def _made_planes(drop_an_execution=False):
             modules.append(("jit__step_mom(7)", s, s + ms // 2,
                             "jit__step_mom(7)"))
     if drop_an_execution:
-        modules = [m for m in modules if m[0] != mod + "(22)"]
+        modules = [m for m in modules if m[0] != "jit_%s(33)" % bwd]
     return [{"name": "/host:CPU",
              "lines": [{"name": "python", "events": host}]},
             {"name": "/device:TPU:0",
@@ -202,7 +237,7 @@ def _made_planes(drop_an_execution=False):
 
 
 @pytest.mark.parametrize("name,want", [
-    ("train_gluon_device_ms", 10.0), ("train_gluon_executions", 3.0),
+    ("train_gluon_device_ms", 10.0), ("train_gluon_executions", 2.0),
     ("train_update_device_ms", 1.5)])
 def test_device_reader_on_made_planes(name, want):
     assert reader(name)({"planes": _made_planes()}) == pytest.approx(want)
@@ -212,8 +247,8 @@ def test_readers_count_what_ran_and_say_nothing_without_spans(captured):
     # every execution counts: one fewer reads one fewer, by its own time
     planes = _made_planes(drop_an_execution=True)
     assert reader("train_gluon_device_ms")({"planes": planes}) == \
-        pytest.approx(7.0)
-    assert reader("train_gluon_executions")({"planes": planes}) == 2
+        pytest.approx(5.0)
+    assert reader("train_gluon_executions")({"planes": planes}) == 1
     assert reader("train_update_dispatches")({"planes": planes}) == 3
     assert reader("train_tape_host_ms")({"planes": planes}) == \
         pytest.approx(4.0)
@@ -368,6 +403,9 @@ def _module_names(tmp_path, k):
 def test_program_names_are_the_same_in_two_fresh_processes(tmp_path):
     first, second = (_module_names(tmp_path, k) for k in range(2))
     assert first == second
+    # a recorded call compiles its two programs and no plain forward
     assert first == ["jit_mx_decode", "jit_mx_encoder_eval",
-                     "jit_mx_encoder_train", "jit_mx_hybridsequential_eval",
-                     "jit_mx_hybridsequential_train", "jit_mx_prefill"]
+                     "jit_mx_encoder_train_bwd", "jit_mx_encoder_train_fwd",
+                     "jit_mx_hybridsequential_eval",
+                     "jit_mx_hybridsequential_train_bwd",
+                     "jit_mx_hybridsequential_train_fwd", "jit_mx_prefill"]
