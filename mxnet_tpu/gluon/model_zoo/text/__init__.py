@@ -1,8 +1,9 @@
 """Language models built from a published configuration (a dict with the
 keys of the model's ``config.json``)."""
 from .lfm2_moe import LFM2MoE, lfm2_moe
+from .qwen3_next import Qwen3Next, qwen3_next
 
-_models = {"lfm2_moe": lfm2_moe}
+_models = {"lfm2_moe": lfm2_moe, "qwen3_next": qwen3_next}
 
 
 def get_model(name, **kwargs):

@@ -130,9 +130,10 @@ class Router(HybridBlock):
     ``(selection, gate, counts)`` of ``F.MoERoute``."""
 
     def __init__(self, hidden, experts, k, norm_topk, scale, use_bias, dtype,
-                 prefix=None, params=None):
+                 score="sigmoid", prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
-        self._attrs = {"k": k, "norm_topk": norm_topk, "scale": scale}
+        self._attrs = {"k": k, "norm_topk": norm_topk, "scale": scale,
+                       "score": score}
         with self.name_scope():
             self.weight = self.params.get("weight", shape=(experts, hidden),
                                           dtype=dtype)

@@ -315,12 +315,15 @@ class LayerNorm(HybridBlock):
 
 class RMSNorm(HybridBlock):
     """Root-mean-square normalisation over the last axis:
-    ``x / sqrt(mean(x^2) + epsilon) * gamma``, the mean in float32."""
+    ``x / sqrt(mean(x^2) + epsilon) * gamma``, the mean in float32;
+    ``zero_centered``: the factor is ``1 + gamma`` (give
+    ``gamma_initializer="zeros"``)."""
 
     def __init__(self, epsilon=1e-5, gamma_initializer="ones",
-                 in_channels=0, dtype="float32", prefix=None, params=None):
+                 in_channels=0, dtype="float32", zero_centered=False,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
-        self._epsilon = epsilon
+        self._epsilon, self._zero_centered = epsilon, zero_centered
         with self.name_scope():
             self.gamma = self.params.get(
                 "gamma", shape=(in_channels,), init=gamma_initializer,
@@ -330,7 +333,8 @@ class RMSNorm(HybridBlock):
         self.gamma.shape_inferred((x.shape[-1],))
 
     def hybrid_forward(self, F, x, gamma):
-        return F.RMSNorm(x, gamma, eps=self._epsilon)
+        return F.RMSNorm(x, gamma, eps=self._epsilon,
+                         zero_centered=self._zero_centered)
 
 
 class Embedding(HybridBlock):

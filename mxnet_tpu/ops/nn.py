@@ -819,8 +819,9 @@ def legacy_crop(data, crop_like=None, offset=(0, 0), h_w=(0, 0),
 
 # ---------------------------------------------------------------------------
 # Sequence-model layers: RMSNorm, rotary encoding, gated MLP product, short
-# causal convolution, grouped-query attention, routed experts. Statistics,
-# softmax and router scores are float32 whatever the storage type.
+# causal convolution, grouped-query attention, the gated delta rule, routed
+# experts. Statistics, softmax, router scores, the rule's state and decay are
+# float32 whatever the storage type.
 #
 # The elementwise ones compute in float32 and are ``jax.checkpoint``ed:
 # differentiated, they keep their 16-bit inputs and recompute the float32
@@ -839,28 +840,34 @@ def _recomputed(fn):
 
 @register("RMSNorm", aliases=("_contrib_RMSNorm",))
 @_recomputed
-def rms_norm(data, gamma, eps=1e-5):
+def rms_norm(data, gamma, eps=1e-5, zero_centered=False):
     """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, the mean in
-    float32."""
+    float32. ``zero_centered``: the weight is stored about zero and the
+    factor is ``1 + gamma``."""
     x = data.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return (x * inv * gamma.astype(jnp.float32)).astype(data.dtype)
+    w = gamma.astype(jnp.float32)
+    return (x * inv * (1.0 + w if zero_centered else w)).astype(data.dtype)
 
 
 @register("RotaryEmbedding", aliases=("_contrib_RotaryEmbedding",))
 @_recomputed
-def rotary_embedding(data, theta=10000.0, offset=0):
+def rotary_embedding(data, theta=10000.0, offset=0, rotary_dim=0):
     """Rotary position encoding of ``data`` [batch, seq, heads, dim], the
-    rotate-half form over all ``dim``: with ``a_t,i = (offset + t) *
-    theta^(-2i/dim)``, ``out = x * cos(a) + rotate_half(x) * sin(a)`` and
-    ``rotate_half(x) = concat(-x[dim/2:], x[:dim/2])``."""
-    t, d = data.shape[1], data.shape[3]
+    rotate-half form over the first ``rotary_dim`` lanes (all ``dim`` where
+    0), the other lanes untouched: with ``d = rotary_dim``, ``a_t,i =
+    (offset + t) * theta^(-2i/d)``, ``out = x * cos(a) + rotate_half(x) *
+    sin(a)`` and ``rotate_half(x) = concat(-x[d/2:d], x[:d/2])``."""
+    t, d = data.shape[1], rotary_dim or data.shape[3]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = (offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv_freq
     ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
-    x = data.astype(jnp.float32)
+    x = data[..., :d].astype(jnp.float32)
     half = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
-    return (x * jnp.cos(ang) + half * jnp.sin(ang)).astype(data.dtype)
+    out = (x * jnp.cos(ang) + half * jnp.sin(ang)).astype(data.dtype)
+    if d == data.shape[3]:
+        return out
+    return jnp.concatenate([out, data[..., d:]], axis=-1)
 
 
 @register("SwiGLU", aliases=("_contrib_SwiGLU",))
@@ -874,16 +881,21 @@ def swiglu(gate, up):
 
 @register("CausalConv1D", aliases=("_contrib_CausalConv1D",))
 @_recomputed
-def causal_conv1d(data, weight):
+def causal_conv1d(data, weight, activation=None):
     """Depthwise causal convolution over the sequence axis: ``data``
     [batch, seq, channels], ``weight`` [channels, width];
     ``out_t = sum_j weight[:, j] * data_{t - (width - 1) + j}`` with zeros
-    left of the sequence, summed in float32."""
+    left of the sequence, summed in float32; ``activation="silu"`` is
+    applied to the sum before it is rounded."""
     width = weight.shape[1]
     t = data.shape[1]
     x = jnp.pad(data.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     w = weight.astype(jnp.float32)
     out = sum(x[:, j:j + t, :] * w[:, j] for j in range(width))
+    if activation == "silu":
+        out = out * jax.nn.sigmoid(out)
+    elif activation is not None:
+        raise MXNetError(f"CausalConv1D activation {activation!r} unsupported")
     return out.astype(data.dtype)
 
 
@@ -902,15 +914,119 @@ def gq_attention(query, key, value, causal=True, scale=None):
     return jnp.swapaxes(out, 1, 2)
 
 
+RULE_GROUP = 8       # chunks of the gated delta rule kept per checkpoint
+
+
+@register("GatedDeltaRule", aliases=("_contrib_GatedDeltaRule",))
+def gated_delta_rule(query, key, value, g, beta, chunk=64, eps=1e-6):
+    """The gated delta rule (Gated DeltaNet's linear attention), causal, in
+    chunks of ``chunk`` tokens. ``query`` / ``key`` [batch, seq, key_heads,
+    dk], ``value`` [batch, seq, value_heads, dv], ``g`` (log decay, <= 0)
+    and ``beta`` (write strength) [batch, seq, value_heads]; each key head
+    serves ``value_heads // key_heads`` consecutive value heads. Returns
+    ``o`` [batch, seq, value_heads, dv].
+
+    With ``q^ = q / sqrt(sum q^2 + eps) / sqrt(dk)``, ``k^ = k / sqrt(sum
+    k^2 + eps)`` and a state ``S`` [dk, dv] per value head, zero before a
+    row's first token, for t = 1 ... T::
+
+        S <- exp(g_t) S;  d_t = beta_t (v_t - S^T k^_t);
+        S <- S + k^_t d_t^T;  o_t = S^T q^_t
+
+    Computed a chunk of C tokens at a time (``gamma_i = sum_{j <= i} g_j``
+    inside the chunk): ``A_ij = beta_i (k^_i . k^_j) exp(gamma_i -
+    gamma_j)`` for i > j; ``W = (I + A)^-1 (beta e^gamma * K^)``, ``U = (I +
+    A)^-1 (beta * V)`` by forward substitution; then chunk after chunk
+    ``V' = U - W S``, ``O = (Q^ * e^gamma) S + tril(Q^ K^T * e^(gamma_i -
+    gamma_j)) V'``, ``S <- e^(gamma_C) S + (K^ * e^(gamma_C - gamma))^T V'``.
+    The state, the decays, the substitution and every sum are float32; the
+    products take their operands in ``value``'s type. A sequence that is
+    no multiple of C is padded with tokens that leave the state alone.
+
+    Differentiated, the scan over groups of ``RULE_GROUP`` chunks keeps
+    each group's incoming state beside the arguments, and a group's own
+    arrays are computed again in the backward pass (``jax.checkpoint``):
+    one state per group and one group's chunk-local arrays are held,
+    never a state per token."""
+    b, t, hk, dk = query.shape
+    hv, dv = value.shape[2:]
+    f32, lo = jnp.float32, value.dtype
+    c = min(int(chunk), t)
+    n = -(-t // c)
+    per = min(RULE_GROUP, n)
+    groups = -(-n // per)
+    pad = groups * per * c - t
+
+    def grouped(x):
+        """[batch, seq, heads, ...] -> [groups, per, batch, heads, c, ...];
+        a padded token has q = k = v = 0, beta = 0 and g = 0: it leaves
+        the state alone."""
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((b, groups, per, c) + x.shape[2:])
+        return jnp.transpose(x, (1, 2, 0, 4, 3) + tuple(range(5, x.ndim)))
+
+    def unit(x):
+        x = x.astype(f32)
+        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+    def dot(spec, x, y):
+        return jnp.einsum(spec, x.astype(lo), y.astype(lo),
+                          preferred_element_type=f32)
+
+    rows = jnp.arange(c)
+    lower = rows[:, None] >= rows[None, :]
+
+    def step(state, xs):
+        w, u, p, q_in, k_out, keep = xs
+        v_new = u - dot("...ck,...kv->...cv", w, state)
+        o = dot("...ck,...kv->...cv", q_in, state) + \
+            dot("...ij,...jv->...iv", p, v_new)
+        state = keep * state + dot("...ck,...cv->...kv", k_out, v_new)
+        return state, o.astype(lo)
+
+    def group(state, xs):
+        """``per`` chunks: what is local to a chunk for all of them at
+        once, then the state through them one after another."""
+        q, k, v, g, beta = xs
+        # each key head serves hv // hk consecutive value heads
+        q, k = (jnp.repeat(x, hv // hk, axis=2)
+                for x in (unit(q) * dk ** -0.5, unit(k)))
+        gamma = jnp.cumsum(g.astype(f32), axis=-1)        # [per, b, hv, c]
+        beta = beta.astype(f32)
+        # exp(gamma_i - gamma_j) for i >= j, 0 above the diagonal
+        decay = jnp.exp(jnp.where(lower, gamma[..., :, None] -
+                                  gamma[..., None, :], -jnp.inf))
+        a = jnp.where(rows[:, None] > rows[None, :],
+                      beta[..., None] * dot("...id,...jd->...ij", k, k) *
+                      decay, 0.0)
+        rhs = jnp.concatenate([k * (beta * jnp.exp(gamma))[..., None],
+                               v.astype(f32) * beta[..., None]], axis=-1)
+        # (I + a)^-1 rhs: forward substitution in float32
+        wu = lax.linalg.triangular_solve(a, rhs, left_side=True, lower=True,
+                                         unit_diagonal=True).astype(lo)
+        p = (dot("...id,...jd->...ij", q, k) * decay).astype(lo)
+        q_in = (q * jnp.exp(gamma)[..., None]).astype(lo)
+        k_out = (k * jnp.exp(gamma[..., -1:] - gamma)[..., None]).astype(lo)
+        keep = jnp.exp(gamma[..., -1])[..., None, None]
+        return lax.scan(step, state,
+                        (wu[..., :dk], wu[..., dk:], p, q_in, k_out, keep))
+
+    _, o = lax.scan(jax.checkpoint(group), jnp.zeros((b, hv, dk, dv), f32),
+                    tuple(grouped(x) for x in (query, key, value, g, beta)))
+    # [groups, per, b, hv, c, dv] -> [b, seq, hv, dv]
+    o = jnp.transpose(o, (2, 0, 1, 4, 3, 5))
+    return o.reshape(b, groups * per * c, hv, dv)[:, :t]
+
+
 @register("MoERoute", aliases=("_contrib_MoERoute",), num_outputs=3,
           optional_arrays=("expert_bias",))
 def moe_route(data, router_weight, expert_bias=None, k=1, norm_topk=True,
-              scale=1.0):
+              scale=1.0, score="sigmoid"):
     """Top-k routing without drops over all the experts
     (``parallel.moe.route``): ``(selection, gate, counts)``."""
     from ..parallel.moe import route
     return route(data, router_weight, expert_bias, k=k, norm_topk=norm_topk,
-                 scale=scale)
+                 scale=scale, score=score)
 
 
 @register("MoEExperts", aliases=("_contrib_MoEExperts",))

@@ -33,14 +33,17 @@ from jax import lax
 from ..ops.nn import swiglu
 
 
-def route(x, router_w, expert_bias=None, k=1, norm_topk=True, scale=1.0):
+def route(x, router_w, expert_bias=None, k=1, norm_topk=True, scale=1.0,
+          score="sigmoid"):
     """Top-k routing of ``x`` [tokens, d] over the ``router_w.shape[0]``
     experts; ``router_w`` is [experts, d].
 
-    Scores are float32: the sigmoid of the router's product.
+    Scores are float32: the ``sigmoid`` of the router's product, or its
+    ``softmax`` over all the experts, as ``score`` says.
     ``expert_bias`` [experts] is added for the SELECTION only;
     the weights come from the unbiased scores, divided by their sum over
-    the k selected (+ 1e-6) where ``norm_topk``, times ``scale``.
+    the k selected where ``norm_topk`` (+ 1e-6 under ``sigmoid``, whose
+    scores may all be near zero), times ``scale``.
 
     Returns ``(sel, gate, counts)``: int32 [tokens, k] expert ids,
     float32 [tokens, k] weights (differentiable towards ``x`` and
@@ -48,13 +51,18 @@ def route(x, router_w, expert_bias=None, k=1, norm_topk=True, scale=1.0):
     """
     logits = lax.dot_general(x, router_w, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    scores = jax.nn.sigmoid(logits)
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"route: score {score!r}")
+    sigmoid = score == "sigmoid"
+    scores = jax.nn.sigmoid(logits) if sigmoid else \
+        jax.nn.softmax(logits, axis=-1)
     biased = scores if expert_bias is None else \
         scores + expert_bias.astype(jnp.float32)
     _, sel = lax.top_k(lax.stop_gradient(biased), k)
     gate = jnp.take_along_axis(scores, sel, axis=1)
     if norm_topk:
-        gate = gate / (jnp.sum(gate, axis=1, keepdims=True) + 1e-6)
+        gate = gate / (jnp.sum(gate, axis=1, keepdims=True) +
+                       (1e-6 if sigmoid else 0.0))
     gate = gate * scale
     n_experts = router_w.shape[0]
     counts = jnp.sum(jax.nn.one_hot(sel.reshape(-1), n_experts,
@@ -103,10 +111,10 @@ def _experts_held(x, sel, gate, w1, w3, w2, first):
 
 
 def moe_ffn(x, router_w, w1, w3, w2, expert_bias=None, k=1, first=0,
-            norm_topk=True, scale=1.0):
+            norm_topk=True, scale=1.0, score="sigmoid"):
     """Router and held experts in one call: ``(out, counts)``."""
     sel, gate, counts = route(x, router_w, expert_bias, k=k,
-                              norm_topk=norm_topk, scale=scale)
+                              norm_topk=norm_topk, scale=scale, score=score)
     return experts_held(x, sel, gate, w1, w3, w2, first=first), counts
 
 

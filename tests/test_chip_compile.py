@@ -118,6 +118,50 @@ def test_expert_layer_compiles_to_grouped_kernels_by_their_name(chip):
     assert len(products) == 9, kernels
 
 
+def test_flash_compiles_at_256_lanes_over_2_kv_heads(chip):
+    """Qwen3-Next's attention at the benchmark's batch: 32 query heads
+    (2 x 16) of 256 lanes over 4 K/V heads (2 x 2), 4,096 tokens: K and V
+    of one head are 4 MiB, inside ``VMEM_BUDGET_BYTES``, so the dispatch
+    takes the kernel; the backward is ``_chunked_attention_bwd`` at that
+    width."""
+    q = chip((32, 4096, 256), jnp.bfloat16)
+    kv = chip((4, 4096, 256), jnp.bfloat16)
+    assert 2 * 4096 * 256 * 2 <= pk.VMEM_BUDGET_BYTES
+    _is_kernel(pk._flash_call.lower(
+        q, kv, kv, causal=True, scale=256 ** -0.5, block_q=256, block_k=512,
+        interpret=False))
+
+    def loss(q, k, v):
+        out = pk._flash_diff(q, k, v, True, 256 ** -0.5, 256, 512, False)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in text and "while" in text
+
+
+def test_gated_delta_rule_compiles_at_the_published_widths(chip):
+    """Qwen3-Next's rule at the benchmark's batch: 2 x 4,096 tokens, 16 key
+    heads and 32 value heads of 128 lanes, forward and gradient; the
+    backward pass holds one group of 8 chunks' arrays at a time (0.56 GB
+    planned; every chunk's at once planned 3.05 GB and did not fit the
+    step), never a state per token (17 GB)."""
+    from mxnet_tpu.ops.nn import gated_delta_rule
+
+    qk = chip((2, 4096, 16, 128), jnp.bfloat16)
+    v = chip((2, 4096, 32, 128), jnp.bfloat16)
+    gb = chip((2, 4096, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_rule(q, k, v, g, beta).astype(jnp.float32).sum()
+
+    fwd = jax.jit(gated_delta_rule).lower(qk, qk, v, gb, gb).compile()
+    assert fwd.memory_analysis().temp_size_in_bytes < 2 ** 29
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, v, gb, gb).compile()
+    assert bwd.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 # h16 x d128 is the smoke's decoder; h4 x d16 is GenerativeDecoder's
 # default. paged_attention's rule is one line — on the chip every head
 # shape takes the kernel — so the smallest shipped shape compiles too

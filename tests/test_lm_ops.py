@@ -1,6 +1,8 @@
-"""The sequence-model ops (RMSNorm, rotary encoding, SwiGLU, short causal
-convolution, grouped-query attention) against ``jax.numpy`` written out,
-forward and gradient, through the registered ops."""
+"""The sequence-model ops (RMSNorm and its zero-centred weight, rotary
+encoding over all or the first ``rotary_dim`` lanes, SwiGLU, short causal
+convolution with and without its silu, grouped-query attention) against
+``jax.numpy`` written out, forward and gradient, through the registered
+ops."""
 import numpy as np
 import pytest
 
@@ -25,8 +27,13 @@ def rms_norm(x, g, eps=1e-5):
     return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def rope(x, theta=1e6):
+def rms_norm_zero_centered(x, g, eps=1e-6):
+    return rms_norm(x, 1 + g, eps)
+
+
+def rope(x, theta=1e6, lanes=None):
     _, t, _, d = x.shape
+    d = lanes or d
     out = []
     for i in range(d):
         j = i % (d // 2)
@@ -34,11 +41,20 @@ def rope(x, theta=1e6):
         pair = -x[..., i + d // 2] if i < d // 2 else x[..., i - d // 2]
         out.append(x[..., i] * jnp.cos(ang)[None, :, None]
                    + pair * jnp.sin(ang)[None, :, None])
-    return jnp.stack(out, -1)
+    return jnp.concatenate([jnp.stack(out, -1), x[..., d:]], -1)
+
+
+def rope_first_4_lanes(x):
+    return rope(x, 1e7, lanes=4)
 
 
 def swiglu(a, b):
     return a / (1 + jnp.exp(-a)) * b
+
+
+def causal_conv_silu(x, w):
+    y = causal_conv(x, w)
+    return y / (1 + jnp.exp(-y))
 
 
 def causal_conv(x, w):
@@ -67,8 +83,17 @@ def gq_attention(q, k, v):
 
 CASES = {
     "RMSNorm": (rms_norm, lambda: (_arr(2, 5, 16), 1 + 0.1 * _arr(16)), {}),
+    "RMSNorm zero_centered": (rms_norm_zero_centered,
+                              lambda: (_arr(2, 5, 16), 0.1 * _arr(16)),
+                              {"eps": 1e-6, "zero_centered": True}),
     "RotaryEmbedding": (rope, lambda: (_arr(2, 7, 3, 8),),
                         {"theta": 1e6}),
+    "RotaryEmbedding rotary_dim": (rope_first_4_lanes,
+                                   lambda: (_arr(2, 7, 3, 16),),
+                                   {"theta": 1e7, "rotary_dim": 4}),
+    "CausalConv1D silu": (causal_conv_silu,
+                          lambda: (_arr(2, 9, 6), _arr(6, 4)),
+                          {"activation": "silu"}),
     "SwiGLU": (swiglu, lambda: (_arr(3, 6, 10), _arr(3, 6, 10)), {}),
     "CausalConv1D": (causal_conv, lambda: (_arr(2, 9, 6), _arr(6, 3)), {}),
     "GQAttention": (gq_attention,
@@ -81,7 +106,8 @@ CASES = {
 def test_forward_against_jax_numpy_written_out(name):
     want_fn, make, attrs = CASES[name]
     args = make()
-    got = getattr(mx.nd, name)(*[mx.nd.array(a) for a in args], **attrs)
+    got = getattr(mx.nd, name.split()[0])(*[mx.nd.array(a) for a in args],
+                                          **attrs)
     want = want_fn(*[jnp.asarray(a) for a in args])
     np.testing.assert_allclose(got.asnumpy(), np.asarray(want), rtol=2e-5,
                                atol=2e-5)
@@ -97,7 +123,7 @@ def test_gradient_against_jax_numpy_written_out(name):
     for a in nds:
         a.attach_grad()
     with autograd.record():
-        out = getattr(mx.nd, name)(*nds, **attrs)
+        out = getattr(mx.nd, name.split()[0])(*nds, **attrs)
         head = mx.nd.array(_arr(*out.shape))
         loss = (out * head).sum()
     loss.backward()
@@ -115,7 +141,7 @@ def test_bfloat16_keeps_its_type_and_stays_close(name):
     16-bit call within 16-bit rounding of the float32 one."""
     want_fn, make, attrs = CASES[name]
     args = make()
-    op = registry.get(name)
+    op = registry.get(name.split()[0])
     got = op(*[jnp.asarray(a, jnp.bfloat16) for a in args], **attrs)
     assert got.dtype == jnp.bfloat16
     want = want_fn(*[jnp.asarray(a) for a in args])

@@ -26,15 +26,20 @@ def make(seed=3, dtype=jnp.float32):
             "w2": arr(E, F, D) / 4}
 
 
-def dense_layer(p, k, held=(0, E), norm=True, bias=True):
+def dense_layer(p, k, held=(0, E), norm=True, bias=True, score="sigmoid"):
     """The layer written out: scores over all the experts, top-k with the
     bias in the selection only, the held experts' part of the sum."""
     logits = p["x"] @ p["router"].T
-    s = jax.nn.sigmoid(logits)
+    if score == "softmax":
+        e = jnp.exp(logits - logits.max(1, keepdims=True))
+        s = e / e.sum(1, keepdims=True)
+    else:
+        s = jax.nn.sigmoid(logits)
     _, sel = jax.lax.top_k(s + (p["bias"] if bias else 0.0), k)
     w = jnp.take_along_axis(s, sel, 1)
     if norm:
-        w = w / (w.sum(1, keepdims=True) + 1e-6)
+        w = w / (w.sum(1, keepdims=True) + (1e-6 if score == "sigmoid"
+                                            else 0.0))
     out = jnp.zeros_like(p["x"])
     for e in range(held[0], held[0] + held[1]):
         we = jnp.where(sel == e, w, 0.0).sum(1)
@@ -83,6 +88,51 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
                                atol=2e-5)
     for _, counts in parts:
         np.testing.assert_array_equal(counts, parts[0][1])
+
+
+@pytest.mark.parametrize("k", [1, 3])
+@pytest.mark.parametrize("norm", [True, False])
+def test_softmax_scores_against_the_dense_loop(k, norm):
+    """``score="softmax"``: the softmax over ALL the experts, then top-k,
+    then (``norm``) the selected weights divided by their sum."""
+    p = make(12)
+    got, counts = layer(p, k, norm_topk=norm, score="softmax")
+    want, sel = dense_layer(p, k, norm=norm, score="softmax")
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(sel).ravel(), minlength=E))
+    _, gate, _ = moe.route(p["x"], p["router"], k=k, norm_topk=norm,
+                           score="softmax")
+    if norm:
+        np.testing.assert_allclose(gate.sum(1), 1.0, rtol=1e-6)
+    else:       # the unnormalised weights are a part of one softmax
+        assert float(gate.sum(1).max()) <= 1.0 + 1e-6
+
+
+def test_softmax_scores_shares_gradients_and_the_registered_op():
+    p = make(13)
+    whole, _ = dense_layer(p, 3, bias=False, score="softmax")
+    parts = [moe.moe_ffn(p["x"], p["router"], p["w1"][i:i + 2],
+                         p["w3"][i:i + 2], p["w2"][i:i + 2], k=3, first=i,
+                         score="softmax")[0] for i in range(0, E, 2)]
+    np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=2e-5)
+    names = ["x", "router", "w1"]
+    loss = lambda fn: lambda *a: (fn(dict(p, **dict(zip(names, a))), 3,
+                                     held=(2, 4), score="softmax")[0]
+                                  ** 2).sum()
+    got = jax.grad(loss(layer), (0, 1, 2))(*[p[n] for n in names])
+    want = jax.grad(loss(dense_layer), (0, 1, 2))(*[p[n] for n in names])
+    for n, a, w in zip(names, got, want):
+        np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5, err_msg=n)
+    sel, gate, counts = mx.nd.MoERoute(
+        mx.nd.array(np.asarray(p["x"])), mx.nd.array(np.asarray(p["router"])),
+        k=3, score="softmax")
+    _, want_gate, want_counts = moe.route(p["x"], p["router"], k=3,
+                                          score="softmax")
+    np.testing.assert_allclose(gate.asnumpy(), want_gate, rtol=1e-6)
+    np.testing.assert_array_equal(counts.asnumpy(), want_counts)
+    with pytest.raises(ValueError):
+        moe.route(p["x"], p["router"], k=3, score="tanh")
 
 
 def test_gradients_against_the_dense_loop():
