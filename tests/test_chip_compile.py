@@ -94,7 +94,8 @@ def test_expert_layer_compiles_to_grouped_kernels_by_their_name(chip):
     2,048 x 1,536, 8,192 tokens x top-4 = 32,768 visit rows): XLA:TPU
     makes ``lax.ragged_dot`` a grouped kernel of its own, named
     ``ragged-dot…`` — the name ``moe_grouped_roofline_pct`` reads — in the
-    forward and in both gradients."""
+    forward and in both gradients, on every rung of the ladder: each
+    rung's nine kernels work on rows of that rung's length."""
     from mxnet_tpu.parallel import moe
 
     x = chip((8192, 2048), jnp.bfloat16)
@@ -109,13 +110,16 @@ def test_expert_layer_compiles_to_grouped_kernels_by_their_name(chip):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         x, gate, w13, w13, w2, sel).compile().as_text()
-    kernels = [line.split(" = ")[0].split("%")[-1]
-               for line in text.splitlines()
-               if "tpu_custom_call" in line and " ragged-dot" in
-               " " + line.split(" = ")[0].split("%")[-1]]
-    products = [k for k in kernels if k.startswith("ragged-dot-none")]
-    # three forward (recomputed in the backward program), six backward
-    assert len(products) == 9, kernels
+    products = [line for line in text.splitlines()
+                if "tpu_custom_call" in line and
+                line.split(" = ")[0].split("%")[-1].startswith(
+                    "ragged-dot-none")]
+    rungs = moe.ladder(8192 * 4)
+    assert rungs == (8192, 16384, 32768)
+    # a rung: three forward (run again in the backward switch), six backward
+    assert len(products) == 9 * len(rungs)
+    for rows in rungs:
+        assert sum("[%d," % rows in line for line in products) == 9, rows
 
 
 def test_flash_compiles_at_256_lanes_over_2_kv_heads(chip):

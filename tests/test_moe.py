@@ -226,3 +226,177 @@ def test_expert_parallel_over_a_mesh_matches_local():
     want, _ = dense_layer(p, 2, bias=False)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     assert int(counts.sum()) == 2 * T
+
+
+# -- the ladder: the visits' buffer is a rung long ------------------------
+K2, HELD = 2, (2, 4)                       # 96 visit rows: rungs 24, 48, 96
+RUNGS = moe.ladder(T * K2)
+NAMES = ["x", "router", "w1", "w3", "w2"]
+
+
+def plant_live(p, k, held, live):
+    """``p`` with an ``expert_bias`` that lifts the held experts alike
+    until exactly ``live`` of the T x k visits go to them: the count rises
+    by single visits with the lift, so a bisection finds it."""
+    first, count = held
+    lift = jnp.zeros(E).at[first:first + count].set(1.0)
+
+    def visits(c):
+        _, sel = dense_layer(dict(p, bias=c * lift), k)
+        return int(((sel >= first) & (sel < first + count)).sum())
+
+    lo, hi = -2.0, 2.0
+    assert visits(lo) == 0 and visits(hi) == T * min(k, count)
+    for _ in range(60):
+        mid = (lo + hi) / 2
+        n = visits(mid)
+        if n == live:
+            return dict(p, bias=mid * lift)
+        lo, hi = (mid, hi) if n < live else (lo, mid)
+    raise AssertionError("no lift gives %d live visits" % live)
+
+
+def test_the_ladder_and_the_rule():
+    """The rule is public: the shortest rung that holds the live count,
+    and tokens x k for a buffer past a half full. A count per layer reads
+    as an array."""
+    assert RUNGS == (24, 48, 96)
+    assert moe.ladder(8192 * 10) == (20480, 40960, 81920)
+    assert moe.ladder(7) == (2, 4, 7) and moe.ladder(1) == (1,)
+    for rows in (96, 8192 * 10, 7):
+        steps = moe.ladder(rows)
+        assert steps[-1] == rows
+        for live in range(rows + 1) if rows < 100 else (
+                0, 8600, 20480, 20481, 40960, 40961, rows):
+            got = moe.rung_rows(live, rows)
+            assert got == min(c for c in steps if c >= live)
+    np.testing.assert_array_equal(
+        moe.rung_rows(np.array([0, 24, 25, 49, 96]), 96),
+        [24, 24, 48, 96, 96])
+
+
+@pytest.mark.parametrize(
+    "live", [c + d for c in RUNGS for d in (-1, 0, 1) if c + d <= T * K2])
+def test_layer_and_gradients_round_every_rung(live):
+    """A live count just under, exactly at and one over each rung's
+    length: the value and all five gradients against the dense loop."""
+    p = plant_live(make(21), K2, HELD, live)
+    _, gate, counts = moe.route(p["x"], p["router"], p["bias"], k=K2)
+    assert int(counts[HELD[0]:sum(HELD)].sum()) == live
+    got, _ = layer(p, K2, held=HELD)
+    want, _ = dense_layer(p, K2, held=HELD)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+    def loss(fn):
+        return lambda *a: (fn(dict(p, **dict(zip(NAMES, a))), K2,
+                              held=HELD)[0] ** 2).sum()
+
+    args = [p[n] for n in NAMES]
+    got = jax.grad(loss(layer), tuple(range(5)))(*args)
+    want = jax.grad(loss(dense_layer), tuple(range(5)))(*args)
+    for n, a, w in zip(NAMES, got, want):
+        np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5, err_msg=n)
+
+
+def test_the_shortest_rung_and_the_full_buffer_share_one_program():
+    fn = jax.jit(lambda p: layer(p, K2, held=HELD))
+    few, all_ = (plant_live(make(s), K2, HELD, n)
+                 for s, n in ((22, RUNGS[0] - 3), (23, T * K2)))
+    for p in (few, all_):
+        got, _ = fn(p)
+        want, _ = dense_layer(p, K2, held=HELD)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert fn._cache_size() == 1
+
+
+def _long_float_arrays(jaxpr, rows, inside_longest=False):
+    """Shapes of the floating arrays of ``rows`` rows by some width that
+    ``jaxpr`` makes anywhere but inside the last branch of a conditional
+    (the longest rung's)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if not inside_longest:
+            found += [v.aval.shape for v in eqn.outvars
+                      if len(v.aval.shape) == 2 and v.aval.shape[0] == rows
+                      and jnp.issubdtype(v.aval.dtype, jnp.floating)]
+        for name, value in eqn.params.items():
+            subs = value if isinstance(value, (tuple, list)) else [value]
+            for i, sub in enumerate(subs):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    last = eqn.primitive.name == "cond" and \
+                        name == "branches" and i == len(subs) - 1
+                    found += _long_float_arrays(sub, rows,
+                                                inside_longest or last)
+    return found
+
+
+def test_no_full_length_array_outside_the_longest_rung():
+    """Differentiated by JAX's own rule a ``switch`` makes every branch
+    return every other's residuals, zeros at their full shapes: the
+    layer's derivative is its own, and its gradient's program holds no
+    tokens x k rows by a width outside the longest rung's branch."""
+    p = make(24)
+    sel, gate, _ = moe.route(p["x"], p["router"], p["bias"], k=K2)
+    sl = slice(HELD[0], sum(HELD))
+    args = (p["x"], gate, p["w1"][sl], p["w3"][sl], p["w2"][sl])
+
+    def loss(x, gate, w1, w3, w2):
+        return (moe.experts_held(x, sel, gate, w1, w3, w2,
+                                 first=HELD[0]) ** 2).sum()
+
+    ours = jax.make_jaxpr(jax.grad(loss, tuple(range(5))))(*args)
+    assert _long_float_arrays(ours.jaxpr, T * K2) == []
+    # the reader does see them where JAX's rule is left to differentiate
+    def plain(x, gate, w1, w3, w2):
+        return (moe._on_rung(moe._rung, x, sel, gate, w1, w3, w2,
+                             HELD[0]) ** 2).sum()
+
+    theirs = jax.make_jaxpr(jax.grad(plain, tuple(range(5))))(*args)
+    assert _long_float_arrays(theirs.jaxpr, T * K2)
+
+
+def test_rung_scopes_under_the_model_s_scope():
+    """Through ``SparseExperts`` every rung's operations carry
+    ``rows.<length>`` inside ``lfm2.moe.experts``, so a capture says which
+    rung a layer took."""
+    import importlib
+    import re
+    lfm2 = importlib.import_module("mxnet_tpu.gluon.model_zoo.text.lfm2_moe")
+    block = lfm2.SparseExperts(
+        D, F, HELD, dict(experts=E, k=K2, norm_topk=True, scale=1.0,
+                         use_bias=True), "float32")
+    block.initialize()
+    block.hybridize()
+    x = mx.nd.array(np.asarray(make(25)["x"]).reshape(2, T // 2, D))
+    block(x)
+    (jitted, *_), = block._cached_jit.values()
+    pvals = tuple(p.data()._data for _, p in block._cached_plist)
+    text = jitted.lower(pvals, jax.random.PRNGKey(0),
+                        x._data).compile().as_text()
+    seen = set(re.findall(r'op_name="[^"]*lfm2\.moe\.experts/[^"]*?'
+                          r'(rows\.\d+)/', text))
+    assert seen == {"rows.%d" % c for c in RUNGS}
+
+
+def test_gradients_over_a_mesh_where_first_is_traced():
+    """Under ``moe_ffn_ep`` ``first`` is the device's index times its
+    experts, a traced value: an argument of the layer's own derivative
+    that takes no cotangent."""
+    mesh = par.create_mesh({"ep": 4}, devices=jax.devices()[:4])
+    p = make(26)
+
+    def fn(*args):
+        return moe.moe_ffn_ep(*args, axis_name="ep", k=K2)[0]
+
+    over = par.shard_map(
+        fn, mesh=mesh, in_specs=(P(), P(), P("ep"), P("ep"), P("ep")),
+        out_specs=P(), check_vma=False)
+    args = [p[n] for n in NAMES]
+    got = jax.grad(lambda *a: (over(*a) ** 2).sum(), tuple(range(5)))(*args)
+    want = jax.grad(
+        lambda *a: (dense_layer(dict(p, **dict(zip(NAMES, a))), K2,
+                                bias=False)[0] ** 2).sum(),
+        tuple(range(5)))(*args)
+    for n, a, w in zip(NAMES, got, want):
+        np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5, err_msg=n)
