@@ -2,8 +2,10 @@
 keys of the model's ``config.json``)."""
 from .lfm2_moe import LFM2MoE, lfm2_moe
 from .qwen3_next import Qwen3Next, qwen3_next
+from .smallthinker import SmallThinker, smallthinker
 
-_models = {"lfm2_moe": lfm2_moe, "qwen3_next": qwen3_next}
+_models = {"lfm2_moe": lfm2_moe, "qwen3_next": qwen3_next,
+           "smallthinker": smallthinker}
 
 
 def get_model(name, **kwargs):

@@ -147,12 +147,16 @@ class Router(HybridBlock):
 
 
 class SparseExperts(HybridBlock):
-    """The routed expert layer, this share's part of it."""
+    """The routed expert layer, this share's part of it:
+    ``layer(n)`` routes and multiplies on ``n``; ``layer(n, r)`` routes on
+    ``r`` (a router that reads another input than the experts') and
+    multiplies on ``n``. ``act`` gates each expert's product."""
 
-    def __init__(self, hidden, width, held, router, dtype, prefix=None,
-                 params=None):
+    def __init__(self, hidden, width, held, router, dtype, act="silu",
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._first, count = held
+        self._act = act
         with self.name_scope():
             self.router = Router(hidden, dtype=dtype, prefix="router_",
                                  **router)
@@ -164,13 +168,14 @@ class SparseExperts(HybridBlock):
             self.w2 = get("w2_weight", shape=(count, width, hidden),
                           dtype=dtype)
 
-    def hybrid_forward(self, F, n, w1, w3, w2):
+    def hybrid_forward(self, F, n, r=None, *, w1, w3, w2):
         flat = F.reshape(n, shape=(-3, 0))
         with jax.named_scope("lfm2.moe.route"):
-            sel, gate, counts = self.router(flat)
+            sel, gate, counts = self.router(
+                flat if r is None else F.reshape(r, shape=(-3, 0)))
         with jax.named_scope("lfm2.moe.experts"):
             out = F.MoEExperts(flat, sel, gate, w1, w3, w2,
-                               first=self._first)
+                               first=self._first, act=self._act)
         return F.reshape_like(out, n), counts
 
 

@@ -872,11 +872,17 @@ def rotary_embedding(data, theta=10000.0, offset=0, rotary_dim=0):
 
 @register("SwiGLU", aliases=("_contrib_SwiGLU",))
 @_recomputed
-def swiglu(gate, up):
-    """``silu(gate) * up``: the product of a gated MLP, taken in
-    float32."""
+def swiglu(gate, up, act="silu"):
+    """``act(gate) * up``: the product of a gated MLP, taken in float32;
+    ``act`` is ``silu`` (SwiGLU) or ``relu`` (ReGLU)."""
     g = gate.astype(jnp.float32)
-    return (g * jax.nn.sigmoid(g) * up.astype(jnp.float32)).astype(up.dtype)
+    if act == "silu":
+        g = g * jax.nn.sigmoid(g)
+    elif act == "relu":
+        g = jnp.maximum(g, 0.0)
+    else:
+        raise MXNetError(f"SwiGLU act {act!r} unsupported")
+    return (g * up.astype(jnp.float32)).astype(up.dtype)
 
 
 @register("CausalConv1D", aliases=("_contrib_CausalConv1D",))
@@ -900,17 +906,18 @@ def causal_conv1d(data, weight, activation=None):
 
 
 @register("GQAttention", aliases=("_contrib_GQAttention",))
-def gq_attention(query, key, value, causal=True, scale=None):
+def gq_attention(query, key, value, causal=True, scale=None, window=0):
     """Softmax attention with grouped-query heads: ``query`` [batch, seq,
     heads, dim], ``key`` / ``value`` [batch, seq, kv_heads, dim], each K/V
     head serving ``heads // kv_heads`` consecutive query heads; softmax in
-    float32. Long sequences take the flash kernel
+    float32. ``window`` > 0 (causal only; 0 = none): query t sees keys
+    t - window + 1 ... t. Long sequences take the flash kernel
     (``pallas_kernels.flash_attention`` owns the dispatch), which reads
     the shared K/V head through its index map."""
     from .pallas_kernels import flash_attention
     out = flash_attention(jnp.swapaxes(query, 1, 2), jnp.swapaxes(key, 1, 2),
                           jnp.swapaxes(value, 1, 2), causal=causal,
-                          scale=scale)
+                          scale=scale, window=window)
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -1030,8 +1037,10 @@ def moe_route(data, router_weight, expert_bias=None, k=1, norm_topk=True,
 
 
 @register("MoEExperts", aliases=("_contrib_MoEExperts",))
-def moe_experts(data, selection, gate, w1, w3, w2, first=0):
+def moe_experts(data, selection, gate, w1, w3, w2, first=0, act="silu"):
     """The held experts' part of a routed gated-MLP layer
-    (``parallel.moe.experts_held``)."""
+    (``parallel.moe.experts_held``); ``act`` gates each expert's product:
+    ``silu`` or ``relu``."""
     from ..parallel.moe import experts_held
-    return experts_held(data, selection, gate, w1, w3, w2, first=first)
+    return experts_held(data, selection, gate, w1, w3, w2, first=first,
+                        act=act)

@@ -21,9 +21,10 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-def _dense_reference(q, k, v, causal, scale):
+def _dense_reference(q, k, v, causal, scale, window=0):
     """jnp fallback, also the numerics oracle for the kernel tests.
-    q, k, v: (BH, T, D)."""
+    q, k, v: (BH, T, D). ``window`` > 0 (causal only): a query sees the
+    last ``window`` keys up to itself and nothing older."""
     s = jnp.einsum("btd,bsd->bts", q * scale, k)
     if causal:
         t_q, t_k = q.shape[1], k.shape[1]
@@ -35,15 +36,19 @@ def _dense_reference(q, k, v, causal, scale):
         # (decoder convention when t_q != t_k)
         q_pos = jnp.arange(t_q)[:, None] + (t_k - t_q)
         mask = jnp.arange(t_k)[None, :] <= q_pos
+        if window:
+            mask &= jnp.arange(t_k)[None, :] > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bts,bsd->btd", p, v)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
-                  causal, scale):
+                  causal, scale, window=0):
     """One (batch*head, q-block) program: stream K/V blocks through
-    VMEM folding each into an online-softmax accumulator (Dao 2022)."""
+    VMEM folding each into an online-softmax accumulator (Dao 2022).
+    Under ``window`` the loop starts at the block that holds the oldest
+    key the program's first query sees, and both edges are masked."""
     qi = pl.program_id(1)
     # the products take the operands in their own type (bfloat16 rides
     # the MXU in one pass) and accumulate in float32
@@ -63,7 +68,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+            seen = k_pos <= q_pos
+            if window:
+                seen &= k_pos > q_pos - window
+            s = jnp.where(seen, s, NEG_INF)
         m_cur = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m, m_cur)
         p = jnp.exp(s - m_new[:, None])
@@ -83,14 +91,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
                              // block_k, n_k)
     else:
         n_live = n_k
-    m, l, acc = jax.lax.fori_loop(0, n_live, body, (m0, l0, a0))
+    # a row whose window starts past the first block's end folds that
+    # block in as all-masked (weight exp(0) under the running maximum
+    # NEG_INF); its first visible key then rescales that by exp(-1e30)
+    # = 0, and every row sees its own position at the latest
+    first = jnp.maximum(qi * block_q - window + 1, 0) // block_k \
+        if window else 0
+    m, l, acc = jax.lax.fori_loop(first, n_live, body, (m0, l0, a0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale",
                                              "block_q", "block_k",
-                                             "interpret"))
-def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret):
+                                             "interpret", "window"))
+def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                window=0):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
@@ -100,7 +115,8 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret):
     group = bh // k.shape[0]
     grid = (bh, t_q // block_q)
     kernel = functools.partial(_flash_kernel, block_q=block_q,
-                               block_k=block_k, causal=causal, scale=scale)
+                               block_k=block_k, causal=causal, scale=scale,
+                               window=window)
     mem = {} if interpret else {"memory_space": pltpu.VMEM}
     return pl.pallas_call(
         kernel,
@@ -132,23 +148,34 @@ FLASH_MIN_SEQ = 4096
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_diff(q, k, v, causal, scale, block_q, block_k, interpret):
-    return _flash_call(q, k, v, causal, scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_diff(q, k, v, causal, scale, block_q, block_k, interpret,
+                window=0):
+    return _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                       window=window)
 
 
-def _flash_diff_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out = _flash_call(q, k, v, causal, scale, block_q, block_k, interpret)
+def _flash_diff_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window):
+    out = _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window=window)
     return out, (q, k, v, out)
 
 
-def _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q):
+def _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q,
+                           window=0):
     """FlashAttention-style backward without the (T, T) HBM matrix
     (Dao 2022 §3.1 backward): scan over q-blocks, recomputing each
     (block_q, T_k) score tile from q/k and using D = rowsum(dO ∘ O)
     for the softmax VJP. Peak memory is O(block_q · T_k) per step plus
     the dk/dv carries — the regime where the forward kernel dispatches
-    (T ≥ FLASH_MIN_SEQ) no longer OOMs in training."""
+    (T ≥ FLASH_MIN_SEQ) no longer OOMs in training.
+
+    Under ``window`` a q-block multiplies against a band of K and V of
+    static length (``window + block_q`` rounded up to the block: every
+    key its rows see), sliced where the block's last row ends and
+    clipped at the sequence's start, and adds its dk, dv into that band:
+    a window layer's backward costs its band, not T_k columns."""
     bh, t_q, d = q.shape
     t_k = k.shape[1]
     nb = t_q // block_q
@@ -159,25 +186,42 @@ def _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q):
     Ds = jnp.swapaxes(dD.reshape(bh, nb, block_q), 0, 1)
     kf = k.astype(f32)
     vf = v.astype(f32)
+    band = -(-(window + block_q) // block_q) * block_q if window else t_k
+    banded = band < t_k
 
     def body(carry, inp):
         dk, dv = carry
         qi, gi, Di, i = inp
         qi = qi.astype(f32)
         gi = gi.astype(f32)
-        s = jnp.einsum("bqd,bsd->bqs", qi * scale, kf)
+        if banded:
+            # the band ends with the block's last row (t_q == t_k)
+            lo = jnp.maximum((i + 1) * block_q - band, 0)
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, lo, band, 1)
+            kb, vb = cut(kf), cut(vf)
+        else:
+            lo, kb, vb = 0, kf, vf
+        s = jnp.einsum("bqd,bsd->bqs", qi * scale, kb)
         if causal:
             # forward kernel requires t_q == t_k when causal, so no
             # decoder offset here
             q_pos = i * block_q + jnp.arange(block_q)[:, None]
-            s = jnp.where(jnp.arange(t_k)[None, :] <= q_pos, s, NEG_INF)
+            k_pos = lo + jnp.arange(kb.shape[1])[None, :]
+            seen = k_pos <= q_pos
+            if window:
+                seen &= k_pos > q_pos - window
+            s = jnp.where(seen, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)                       # (b, bq, Tk)
-        dp = jnp.einsum("bqd,bsd->bqs", gi, vf)
+        dp = jnp.einsum("bqd,bsd->bqs", gi, vb)
         ds = p * (dp - Di[..., None])
-        dqi = jnp.einsum("bqs,bsd->bqd", ds, kf) * scale
-        dk = dk + jnp.einsum("bqs,bqd->bsd", ds, qi) * scale
-        dv = dv + jnp.einsum("bqs,bqd->bsd", p, gi)
-        return (dk, dv), dqi
+        dqi = jnp.einsum("bqs,bsd->bqd", ds, kb) * scale
+        dkb = jnp.einsum("bqs,bqd->bsd", ds, qi) * scale
+        dvb = jnp.einsum("bqs,bqd->bsd", p, gi)
+        if banded:
+            add = lambda a, b: jax.lax.dynamic_update_slice_in_dim(
+                a, cut(a) + b, lo, 1)
+            return (add(dk, dkb), add(dv, dvb)), dqi
+        return (dk + dkb, dv + dvb), dqi
 
     (dk, dv), dq = jax.lax.scan(
         body,
@@ -187,14 +231,15 @@ def _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _flash_diff_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_diff_bwd(causal, scale, block_q, block_k, interpret, window,
+                    res, g):
     q, k, v, out = res
     group = q.shape[0] // k.shape[0]
     if group > 1:
         # the chunked pass wants a K/V row per query head; the groups'
         # gradients are summed back onto the head they share
         dq, dk, dv = _flash_diff_bwd(
-            causal, scale, block_q, block_k, interpret,
+            causal, scale, block_q, block_k, interpret, window,
             (q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
              out), g)
         fold = lambda a, like: a.astype(jnp.float32).reshape(
@@ -203,10 +248,12 @@ def _flash_diff_bwd(causal, scale, block_q, block_k, interpret, res, g):
     if q.shape[1] % block_q:
         # shapes the forward kernel accepted always tile; safety net
         _, vjp = jax.vjp(
-            lambda a, b, c: _dense_reference(a, b, c, causal, scale),
+            lambda a, b, c: _dense_reference(a, b, c, causal, scale,
+                                             window),
             q, k, v)
         return vjp(g)
-    return _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q)
+    return _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q,
+                                  window)
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
@@ -611,7 +658,7 @@ def fused_adam(weight, grad, mean, var, lr=0.01, beta1=0.9,
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
-                    block_k=512, interpret=None, force=False):
+                    block_k=512, interpret=None, force=False, window=0):
     """Blockwise attention, O(T) memory. q, k, v: (B, H, T, D) or
     (BH, T, D); k and v may have fewer heads than q (grouped-query
     attention: H a multiple of their head count, query head h reading
@@ -619,7 +666,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
     (>= FLASH_MIN_SEQ, where it beats XLA's dense lowering by the
     measured margins above) and to the dense jnp path otherwise or when
     the sequence doesn't tile; `force=True` always takes the kernel
-    (tests)."""
+    (tests). ``window`` > 0 (causal only; 0 = none): query t sees keys
+    t - window + 1 ... t, itself among them; the kernel skips the blocks
+    before the window as it skips those past the diagonal, the backward
+    works on a band, and the dense path takes the same mask. A window
+    that covers the whole sequence is plain causal attention."""
     squeeze = False
     if q.ndim == 4:
         b, h, t, d = q.shape
@@ -630,6 +681,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     t_q, t_k = q.shape[1], k.shape[1]
+    window = int(window or 0)
+    if window < 0 or (window and not causal):
+        raise ValueError(f"flash_attention: window {window} needs causal "
+                         "attention and a length >= 0")
+    if window >= t_k:
+        window = 0          # every causal pair is inside it
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     tiles = not (t_q % block_q or t_k % block_k or
@@ -644,12 +701,13 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
         tiles = False
     if tiles and (force or t_q >= FLASH_MIN_SEQ):
         out = _flash_diff(q, k, v, bool(causal), float(scale),
-                          int(block_q), int(block_k), bool(interpret))
+                          int(block_q), int(block_k), bool(interpret),
+                          window)
     else:
         group = q.shape[0] // k.shape[0]
         if group > 1:
             k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
-        out = _dense_reference(q, k, v, causal, scale)
+        out = _dense_reference(q, k, v, causal, scale, window)
     if squeeze:
         b, h = squeeze
         out = out.reshape(b, h, t_q, -1)

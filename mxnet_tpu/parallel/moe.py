@@ -113,7 +113,7 @@ def rung_rows(live, rows):
     return np.asarray(steps)[_rung_index(np.asarray(live), steps)]
 
 
-def _on_rung(body, x, sel, gate, w1, w3, w2, first, *more):
+def _on_rung(body, x, sel, gate, w1, w3, w2, first, *more, act="silu"):
     """Plan (integers only, at the full length: the visits sorted by held
     expert, visits to absent experts last), then ``body`` on the rung
     that holds the live rows."""
@@ -125,20 +125,20 @@ def _on_rung(body, x, sel, gate, w1, w3, w2, first, *more):
     sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
     steps = ladder(key.shape[0])
     return lax.switch(_rung_index(jnp.sum(sizes), steps),
-                      _branches(body, steps),
+                      _branches(body, steps, act),
                       x, gate, w1, w3, w2, order, sizes, *more)
 
 
 @lru_cache(maxsize=None)
-def _branches(body, steps):
+def _branches(body, steps, act):
     # the same callables every call: JAX keeps a branch's traced body by
     # the callable and its shapes, so a model's second expert layer and
     # its second program find the first's (fresh closures cost a model of
     # four layers three seconds of set-up)
-    return tuple(partial(body, rows) for rows in steps)
+    return tuple(partial(body, rows, act) for rows in steps)
 
 
-def _rung(rows, x, gate, w1, w3, w2, order, sizes):
+def _rung(rows, act, x, gate, w1, w3, w2, order, sizes):
     """The layer on the first ``rows`` sorted visits (every live one is
     among them): gather, the three grouped products, the weighted rows
     added to their tokens. Nothing here is longer than ``rows``."""
@@ -151,44 +151,48 @@ def _rung(rows, x, gate, w1, w3, w2, order, sizes):
         live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
         xs = jnp.where(live, jnp.take(x, token, axis=0), 0)
         h = swiglu(lax.ragged_dot(xs, w1, sizes),
-                   lax.ragged_dot(xs, w3, sizes))
+                   lax.ragged_dot(xs, w3, sizes), act=act)
         y = jnp.where(live, lax.ragged_dot(h, w2, sizes), 0)
         y = y.astype(jnp.float32) * jnp.take(gate.reshape(-1), order)[:, None]
         out = jnp.zeros(x.shape, jnp.float32).at[token].add(y)
         return out.astype(x.dtype)
 
 
-def _rung_grads(rows, x, gate, w1, w3, w2, order, sizes, ct):
+def _rung_grads(rows, act, x, gate, w1, w3, w2, order, sizes, ct):
     _, pull = jax.vjp(
-        lambda *args: _rung(rows, *args, order, sizes), x, gate, w1, w3, w2)
+        lambda *args: _rung(rows, act, *args, order, sizes),
+        x, gate, w1, w3, w2)
     return pull(ct)
 
 
-@jax.custom_vjp
-def _experts_held(x, sel, gate, w1, w3, w2, first):
-    return _on_rung(_rung, x, sel, gate, w1, w3, w2, first)
+@partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _experts_held(x, sel, gate, w1, w3, w2, first, act):
+    return _on_rung(_rung, x, sel, gate, w1, w3, w2, first, act=act)
 
 
 def _experts_held_fwd(*args):
-    return _experts_held(*args), args
+    return _experts_held(*args), args[:-1]
 
 
-def _experts_held_bwd(args, ct):
-    dx, dgate, dw1, dw3, dw2 = _on_rung(_rung_grads, *args, ct)
+def _experts_held_bwd(act, args, ct):
+    dx, dgate, dw1, dw3, dw2 = _on_rung(_rung_grads, *args, ct, act=act)
     return dx, None, dgate, dw1, dw3, dw2, None
 
 
 _experts_held.defvjp(_experts_held_fwd, _experts_held_bwd)
 
 
-def experts_held(x, sel, gate, w1, w3, w2, first=0):
+def experts_held(x, sel, gate, w1, w3, w2, first=0, act="silu"):
     """This share's part of a gated-MLP expert layer.
 
     ``x`` [tokens, d]; ``sel`` / ``gate`` [tokens, k] from :func:`route`
     over all the experts; ``w1``, ``w3`` [held, d, f] and ``w2``
     [held, f, d] are the experts ``first`` … ``first + held - 1``.
-    Returns ``sum_e gate_e * w2_e(silu(w1_e x) * w3_e x)`` over the
-    selected experts that are held: [tokens, d]. No visit is dropped.
+    Returns ``sum_e gate_e * w2_e(act(w1_e x) * w3_e x)`` over the
+    selected experts that are held: [tokens, d]; ``act`` is ``silu`` or
+    ``relu``. No visit is dropped. ``sel`` and ``gate`` may come from
+    another input than ``x`` (a router that reads the layer's input
+    before attention): the layer only multiplies what it is told.
 
     The rows gathered, multiplied and added back are a rung of
     :func:`ladder` long, the rung :func:`rung_rows` names for this call's
@@ -203,16 +207,22 @@ def experts_held(x, sel, gate, w1, w3, w2, first=0):
     the longest one's buffers after all. Here only the arguments, the
     cotangent and the five gradients cross either ``lax.switch``.
     """
+    if act not in ("silu", "relu"):
+        raise ValueError(f"experts_held: act {act!r}")
     return _experts_held(x, sel, gate, w1, w3, w2,
-                         jnp.asarray(first, jnp.int32))
+                         jnp.asarray(first, jnp.int32), act)
 
 
 def moe_ffn(x, router_w, w1, w3, w2, expert_bias=None, k=1, first=0,
-            norm_topk=True, scale=1.0, score="sigmoid"):
-    """Router and held experts in one call: ``(out, counts)``."""
-    sel, gate, counts = route(x, router_w, expert_bias, k=k,
-                              norm_topk=norm_topk, scale=scale, score=score)
-    return experts_held(x, sel, gate, w1, w3, w2, first=first), counts
+            norm_topk=True, scale=1.0, score="sigmoid", act="silu",
+            route_on=None):
+    """Router and held experts in one call: ``(out, counts)``. The router
+    reads ``route_on`` [tokens, d] where given, else ``x``."""
+    sel, gate, counts = route(x if route_on is None else route_on, router_w,
+                              expert_bias, k=k, norm_topk=norm_topk,
+                              scale=scale, score=score)
+    return experts_held(x, sel, gate, w1, w3, w2, first=first,
+                        act=act), counts
 
 
 def moe_ffn_ep(x, router_w, w1, w3, w2, axis_name="ep", **route_args):

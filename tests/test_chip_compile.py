@@ -144,6 +144,99 @@ def test_flash_compiles_at_256_lanes_over_2_kv_heads(chip):
     assert "tpu_custom_call" in text and "while" in text
 
 
+def test_flash_compiles_with_a_window_at_128_lanes_over_groups_of_7(chip):
+    """SmallThinker-21BA3B's attention at the benchmark's batch: 28 query
+    heads of 128 lanes over 4 K/V heads (groups of 7, no power of two),
+    one sequence of 8,192 tokens, a window of 4,096: K and V of a program
+    are 4 MiB, inside ``VMEM_BUDGET_BYTES``; the K loop starts at a block
+    computed from the program's index. The backward is the banded
+    ``_chunked_attention_bwd``: its score tile is 4,352 columns wide, not
+    8,192."""
+    q = chip((28, 8192, 128), jnp.bfloat16)
+    kv = chip((4, 8192, 128), jnp.bfloat16)
+    assert 2 * 8192 * 128 * 2 <= pk.VMEM_BUDGET_BYTES
+    for window in (4096, 0):
+        _is_kernel(pk._flash_call.lower(
+            q, kv, kv, causal=True, scale=128 ** -0.5, block_q=256,
+            block_k=512, interpret=False, window=window))
+
+    def loss(q, k, v):
+        out = pk._flash_diff(q, k, v, True, 128 ** -0.5, 256, 512, False,
+                             4096)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in text and "while" in text
+    assert "f32[28,256,4352]" in text and "f32[28,256,8192]" not in text
+
+
+def test_smallthinker_step_compiles_and_fits_at_the_published_widths(
+        chip, monkeypatch):
+    """``benchmark/configs/smallthinker-21b-a3b.json`` through gluon's own
+    two programs of a recorded step (``_build_recorded``: the forward that
+    writes the residuals, and the pullback), one sequence of 8,192 tokens,
+    compiled for the described chip from shapes alone. The plan: 16 bytes
+    a parameter of state (bfloat16 weight and gradient, float32 master,
+    Adam's two) beside the larger of what the forward holds (its outputs
+    and temporaries) and what the backward holds (the residuals, the
+    logits' cotangent, its temporaries and the new gradients): 13.2 GB of
+    the chip's 16.9 (chip-free compile, PR 34; the chip's own peak, with
+    the loss block, Adam's program and the next launch waiting, is in
+    PERF.md)."""
+    import json
+
+    import mxnet_tpu as mx  # noqa: F401
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import block as blk
+    from mxnet_tpu.ndarray import NDArray
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        cfg = json.load(f)
+    net = gluon.model_zoo.get_model("smallthinker", config=cfg,
+                                    held=cfg["held"], dtype=cfg["dtype"])
+    net.hybridize()
+    plist = sorted(net.collect_params().items())
+    _, in_spec = blk._flatten([NDArray(jnp.zeros((1, 1), jnp.int32))])
+    jfn, _, _ = net._build_cached(plist, in_spec, True)
+    diff = tuple(p.grad_req != "null" for _, p in plist) + (False,)
+    call, fwd, bwd = net._build_recorded(jfn, diff, True)
+    pvals = tuple(chip(p.shape, p.dtype) for _, p in plist)
+    key, ids = chip((2,), jnp.uint32), chip((1, 8192), jnp.int32)
+    # the flash kernel's dispatch asks for the backend: steer it here,
+    # not through an option of the program
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    forward = fwd.lower(pvals, key, ids).compile()
+    kernels = [line for line in forward.as_text().splitlines()
+               if "tpu_custom_call" in line and
+               line.split(" = ")[0].split("%")[-1].startswith("_flash_call")]
+    assert len(kernels) == 4                    # one a layer
+    outs, _, computed = jax.eval_shape(fwd, pvals, key, ids)
+    on = lambda a: chip(a.shape, a.dtype)
+    size = lambda arrays: sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                              for a in arrays)
+    # the pullback's tree, put together as ``call`` does
+    cells = dict(zip(call.__code__.co_freevars,
+                     (c.cell_contents for c in call.__closure__)))
+    passed, made = (*pvals, ids, *map(on, outs)), iter(map(on, computed))
+    pullback = cells["vjp_tree"].unflatten(
+        next(made) if i is None else passed[i] for i in cells["sources"])
+    backward = bwd.lower(pullback, tuple(map(on, outs))).compile()
+    mf, mb = forward.memory_analysis(), backward.memory_analysis()
+    parameters = sum(int(np.prod(p.shape)) for _, p in plist
+                     if p.grad_req != "null")
+    assert parameters == cfg["parameters"]
+    beside = max(mf.output_size_in_bytes + mf.temp_size_in_bytes,
+                 size(computed) + size(outs) + mb.temp_size_in_bytes +
+                 mb.output_size_in_bytes)
+    plan = 16 * parameters + beside
+    assert 16 * parameters == 8948654080
+    assert beside < 5.5e9, beside          # 4.2 GB planned (PR 34)
+    assert plan < 15.5e9 < 16.9e9, plan    # ISSUE 34's line for this cut
+
+
 def test_gated_delta_rule_compiles_at_the_published_widths(chip):
     """Qwen3-Next's rule at the benchmark's batch: 2 x 4,096 tokens, 16 key
     heads and 32 value heads of 128 lanes, forward and gradient; the

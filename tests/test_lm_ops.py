@@ -206,3 +206,123 @@ def test_flash_kernel_grouped_heads_forward_and_gradient(heads, kv, dtype,
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(w, np.float32),
                                    rtol=5 * tol, atol=5 * tol)
+
+
+# -- window attention: kernel, dense path and the banded backward --------------
+
+def _masked_oracle(q, k, v, window):
+    """The masked dense attention written out: [b, heads, t, d] over
+    [b, kv, t, d]; query i sees keys i - window + 1 ... i."""
+    heads, kv, t, d = q.shape[1], k.shape[1], q.shape[2], q.shape[3]
+    k, v = (jnp.repeat(a, heads // kv, axis=1) for a in (k, v))
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k) * d ** -0.5
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    mask = (j <= i) & (j > i - window)
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+    return jnp.einsum("bhts,bhsd->bhtd", p, v)
+
+
+def _window_inputs(heads, kv, t, d, dtype=jnp.float32, b=1):
+    return (jnp.asarray(_arr(b, heads, t, d), dtype),
+            jnp.asarray(_arr(b, kv, t, d), dtype),
+            jnp.asarray(_arr(b, kv, t, d), dtype))
+
+
+# a window of one block, one that ends inside a block, one longer than
+# two, and a single key; 14 query heads over 2 K/V heads are groups of 7
+@pytest.mark.parametrize("window", [1, 32, 45, 100])
+@pytest.mark.parametrize("heads,kv", [(14, 2), (4, 4)])
+def test_window_kernel_forward_and_banded_backward(heads, kv, window):
+    t, d = 256, 16
+    q, k, v = _window_inputs(heads, kv, t, d)
+
+    def flash(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, window=window,
+                                  block_q=32, block_k=64, force=True,
+                                  interpret=True)
+
+    np.testing.assert_allclose(flash(q, k, v),
+                               _masked_oracle(q, k, v, window),
+                               rtol=2e-5, atol=2e-5)
+    head = jnp.asarray(_arr(1, heads, t, d))
+    got = jax.grad(lambda *a: (flash(*a) * head).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (_masked_oracle(*a, window) * head).sum(),
+                    (0, 1, 2))(q, k, v)
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(a, w, rtol=1e-4, atol=1e-4)
+
+
+def test_banded_backward_takes_a_band_and_not_every_column():
+    """With a window the backward scan's score tile is ``window +
+    block_q`` columns rounded up to the block, not ``t_k``."""
+    t, d, window, block_q = 512, 16, 70, 32
+    q, k, v = (a[0] for a in _window_inputs(2, 2, t, d))
+
+    def loss(q, k, v):
+        return pk._flash_diff(q, k, v, True, 0.25, block_q, 64, True,
+                              window).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
+    band = -(-(window + block_q) // block_q) * block_q
+    assert band == 128
+    assert "f32[2,%d,%d]" % (block_q, band) in text
+    assert "f32[2,%d,%d]" % (block_q, t) not in text
+    plain = str(jax.make_jaxpr(jax.grad(
+        lambda *a: pk._flash_diff(*a, True, 0.25, block_q, 64, True).sum(),
+        (0, 1, 2)))(q, k, v))
+    assert "f32[2,%d,%d]" % (block_q, t) in plain
+
+
+@pytest.mark.parametrize("window", [3, 40])
+def test_window_on_the_dense_path_and_through_the_registered_op(window):
+    b, t, heads, kv, d = 2, 48, 14, 2, 8
+    q, k, v = _arr(b, t, heads, d), _arr(b, t, kv, d), _arr(b, t, kv, d)
+    args = [mx.nd.array(a) for a in (q, k, v)]
+    for a in args:
+        a.attach_grad()
+    with autograd.record():
+        out = mx.nd.GQAttention(*args, causal=True, window=window)
+        loss = (out * out).sum()
+    loss.backward()
+    swap = lambda a: jnp.swapaxes(jnp.asarray(a), 1, 2)
+    oracle = lambda q, k, v: jnp.swapaxes(
+        _masked_oracle(swap(q), swap(k), swap(v), window), 1, 2)
+    np.testing.assert_allclose(out.asnumpy(), oracle(q, k, v), rtol=2e-5,
+                               atol=2e-5)
+    want = jax.grad(lambda *a: (oracle(*a) ** 2).sum(), (0, 1, 2))(
+        *(jnp.asarray(a) for a in (q, k, v)))
+    for a, w in zip(args, want):
+        np.testing.assert_allclose(a.grad.asnumpy(), w, rtol=1e-4,
+                                   atol=1e-4)
+
+
+@pytest.mark.parametrize("window", [128, 129, 4096])
+@pytest.mark.parametrize("force", [True, False])
+def test_a_window_that_covers_the_sequence_is_plain_causal_to_the_bit(
+        window, force):
+    q, k, v = _window_inputs(14, 2, 128, 16, jnp.bfloat16)
+    kw = dict(causal=True, block_q=32, block_k=64, force=force,
+              interpret=True)
+    loss = lambda fn: lambda *a: fn(*a).astype(jnp.float32).sum()
+    with_w = lambda *a: pk.flash_attention(*a, window=window, **kw)
+    plain = lambda *a: pk.flash_attention(*a, **kw)
+    np.testing.assert_array_equal(np.asarray(with_w(q, k, v), np.float32),
+                                  np.asarray(plain(q, k, v), np.float32))
+    for a, w in zip(jax.grad(loss(with_w), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(plain), (0, 1, 2))(q, k, v)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+def test_window_needs_causal_attention():
+    q, k, v = _window_inputs(2, 2, 64, 8)
+    with pytest.raises(ValueError):
+        pk.flash_attention(q, k, v, causal=False, window=8)
+
+
+def test_reglu_is_relu_of_the_gate_times_up():
+    g, u = _arr(5, 12), _arr(5, 12)
+    got = mx.nd.SwiGLU(mx.nd.array(g), mx.nd.array(u), act="relu").asnumpy()
+    np.testing.assert_allclose(got, np.maximum(g, 0) * u, rtol=1e-6)
+    with pytest.raises(mx.MXNetError):
+        mx.nd.SwiGLU(mx.nd.array(g), mx.nd.array(u), act="gelu")

@@ -400,3 +400,124 @@ def test_gradients_over_a_mesh_where_first_is_traced():
         tuple(range(5)))(*args)
     for n, a, w in zip(NAMES, got, want):
         np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5, err_msg=n)
+
+
+# -- the experts' gate and a router that reads another input ---------------------
+
+def relu_layer(p, k, route_on=None, held=(0, E)):
+    """The layer written out with ReLU in the gate, softmax scores, the
+    router reading ``route_on`` where given."""
+    r = p["x"] if route_on is None else route_on
+    logits = r @ p["router"].T
+    _, sel = jax.lax.top_k(logits, k)
+    w = jax.nn.softmax(jnp.take_along_axis(logits, sel, 1), -1)
+    out = jnp.zeros_like(p["x"])
+    for e in range(held[0], held[0] + held[1]):
+        we = jnp.where(sel == e, w, 0.0).sum(1)
+        y = (jnp.maximum(p["x"] @ p["w1"][e], 0) * (p["x"] @ p["w3"][e])) \
+            @ p["w2"][e]
+        out = out + we[:, None] * y
+    return out, sel
+
+
+@pytest.mark.parametrize("held", [(0, E), (2, 2), (6, 2)])
+def test_relu_gate_value_and_gradients_against_the_dense_loop(held):
+    p = make(31)
+    sl = slice(held[0], sum(held))
+    names = ("x", "router", "w1", "w3", "w2")
+
+    def ours(x, router, w1, w3, w2):
+        return moe.moe_ffn(x, router, w1[sl], w3[sl], w2[sl], k=K2,
+                           first=held[0], score="softmax", act="relu")[0]
+
+    def theirs(*a):
+        return relu_layer(dict(zip(names, a)), K2, held=held)[0]
+
+    args = [p[n] for n in names]
+    np.testing.assert_allclose(ours(*args), theirs(*args), rtol=2e-5,
+                               atol=2e-5)
+    got = jax.grad(lambda *a: (ours(*a) ** 2).sum(), tuple(range(5)))(*args)
+    want = jax.grad(lambda *a: (theirs(*a) ** 2).sum(),
+                    tuple(range(5)))(*args)
+    for n, a, w in zip(names, got, want):
+        np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5, err_msg=n)
+    # the gate matters: silu on the same arguments is another number
+    silu = moe.moe_ffn(p["x"], p["router"], p["w1"][sl], p["w3"][sl],
+                       p["w2"][sl], k=K2, first=held[0], score="softmax")[0]
+    assert float(jnp.abs(silu - ours(*args)).max()) > 1e-3
+    with pytest.raises(ValueError):
+        moe.experts_held(p["x"], None, None, p["w1"], p["w3"], p["w2"],
+                         act="gelu")
+
+
+def test_a_router_that_reads_another_input_than_the_experts():
+    """``route_on``: the selection, the weights and the router's gradient
+    come from it; the products and their gradients from ``x``."""
+    p = make(32)
+    r = jnp.asarray(np.random.default_rng(33).standard_normal((T, D)),
+                    jnp.float32)
+    names = ("x", "router", "w1", "w3", "w2")
+
+    def ours(r, x, router, w1, w3, w2):
+        return moe.moe_ffn(x, router, w1, w3, w2, k=K2, score="softmax",
+                           act="relu", route_on=r)
+
+    def theirs(r, *a):
+        return relu_layer(dict(zip(names, a)), K2, route_on=r)
+
+    args = [r] + [p[n] for n in names]
+    (got, counts), (want, sel) = ours(*args), theirs(*args)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(sel).ravel(), minlength=E))
+    _, sel_x = relu_layer(p, K2)
+    assert (np.asarray(sel) != np.asarray(sel_x)).any()
+    g_got = jax.grad(lambda *a: (ours(*a)[0] ** 2).sum(),
+                     tuple(range(6)))(*args)
+    g_want = jax.grad(lambda *a: (theirs(*a)[0] ** 2).sum(),
+                      tuple(range(6)))(*args)
+    for n, a, w in zip(("route_on",) + names, g_got, g_want):
+        np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5, err_msg=n)
+    assert float(jnp.abs(g_got[0]).max()) > 0     # the router's input
+
+
+def test_sparse_experts_block_routes_on_its_second_input():
+    """``SparseExperts(n, r)`` through gluon: the hybridized block and
+    autograd, ReLU in the gate."""
+    import importlib
+    lfm2 = importlib.import_module("mxnet_tpu.gluon.model_zoo.text.lfm2_moe")
+    p = make(34)
+    block = lfm2.SparseExperts(
+        D, F, (0, E), dict(experts=E, k=K2, norm_topk=True, scale=1.0,
+                           use_bias=False, score="softmax"), "float32",
+        act="relu")
+    block.initialize()
+    for name, param in zip(("w1", "w3", "w2", "router"),
+                           block.collect_params().values()):
+        param.set_data(mx.nd.array(np.asarray(p[name])))
+    block.hybridize()
+    r = np.random.default_rng(35).standard_normal((2, T // 2, D)) \
+        .astype(np.float32)
+    n, rr = mx.nd.array(np.asarray(p["x"]).reshape(2, T // 2, D)), \
+        mx.nd.array(r)
+    n.attach_grad()
+    rr.attach_grad()
+    with autograd.record():
+        out, counts = block(n, rr)
+        loss = (out * out).sum()
+    loss.backward()
+    want, sel = relu_layer(p, K2, route_on=jnp.asarray(r).reshape(T, D))
+    np.testing.assert_allclose(out.asnumpy().reshape(T, D), want, rtol=2e-5,
+                               atol=2e-5)
+    assert int(counts.asnumpy().sum()) == T * K2
+    g = jax.grad(lambda x, q: (relu_layer(dict(p, x=x), K2, route_on=q)[0]
+                               ** 2).sum(), (0, 1))(
+        p["x"], jnp.asarray(r).reshape(T, D))
+    np.testing.assert_allclose(n.grad.asnumpy().reshape(T, D), g[0],
+                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(rr.grad.asnumpy().reshape(T, D), g[1],
+                               rtol=2e-4, atol=2e-5)
+    # one input: the block routes on what it multiplies, as before
+    alone, _ = block(n)
+    np.testing.assert_allclose(alone.asnumpy().reshape(T, D),
+                               relu_layer(p, K2)[0], rtol=2e-5, atol=2e-5)
