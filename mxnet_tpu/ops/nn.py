@@ -913,7 +913,9 @@ def gq_attention(query, key, value, causal=True, scale=None, window=0):
     float32. ``window`` > 0 (causal only; 0 = none): query t sees keys
     t - window + 1 ... t. Long sequences take the flash kernel
     (``pallas_kernels.flash_attention`` owns the dispatch), which reads
-    the shared K/V head through its index map."""
+    the shared K/V head through its index map; its gradient is a kernel
+    too, which adds a group's ``dk``, ``dv`` onto the head they share
+    in VMEM."""
     from .pallas_kernels import flash_attention
     out = flash_attention(jnp.swapaxes(query, 1, 2), jnp.swapaxes(key, 1, 2),
                           jnp.swapaxes(value, 1, 2), causal=causal,

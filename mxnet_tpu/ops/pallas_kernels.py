@@ -43,62 +43,129 @@ def _dense_reference(q, k, v, causal, scale, window=0):
     return jnp.einsum("bts,bsd->btd", p, v)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
+def _seen(q0, k0, shape, window):
+    """The causal (and window) mask of one [queries, keys] score tile
+    whose first query is ``q0`` and first key ``k0``."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    seen = k_pos <= q_pos
+    if window:
+        seen &= k_pos > q_pos - window
+    return seen
+
+
+def _k_blocks(qi, block_q, block_k, n_k, causal, window):
+    """The K blocks the rows of query block ``qi`` see (the forward's loop
+    and the backward's), as four bounds ``a <= b <= c <= d``: blocks
+    ``[a, d)`` hold a visible key, and those of ``[b, c)`` hold no hidden
+    one (they need no mask)."""
+    if not causal:
+        return 0, 0, n_k, n_k
+    lo, hi = qi * block_q, (qi + 1) * block_q
+    # blocks strictly above the diagonal contribute nothing, nor do
+    # those that end before the window of the block's first row
+    a = jnp.maximum(lo - window + 1, 0) // block_k if window else 0
+    d = jnp.minimum((hi + block_k - 1) // block_k, n_k)
+    b = (jnp.maximum(hi - window, 0) + block_k - 1) // block_k \
+        if window else 0
+    b = jnp.minimum(jnp.maximum(b, a), d)
+    c = jnp.minimum(jnp.maximum((lo + 1) // block_k, b), d)
+    return a, b, c, d
+
+
+def _masked_then_plain(bounds, step, carry):
+    """Run ``step(masked)`` over the blocks of ``bounds``: with the mask
+    on the edges, without it in between."""
+    a, b, c, d = bounds
+    for lo, hi, masked in ((a, b, True), (b, c, False), (c, d, True)):
+        if isinstance(lo, int) and isinstance(hi, int) and lo == hi:
+            continue                    # not causal: no edge to mask
+        carry = jax.lax.fori_loop(lo, hi, step(masked), carry)
+    return carry
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _row_of(col):
+    """An (n, 1) column as a (1, n) row without a relayout (Mosaic's own,
+    from sublanes to lanes, costs the forward kernel 13 % at 256 rows a
+    program; this, 2 - 5 %: PERF.md PR 35): 128 rows at a time on the
+    diagonal of a square tile, summed over the sublanes. Exact: every
+    sum adds zeros to one value."""
+    n = col.shape[0]
+    w = min(n, 128)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0) ==
+           jax.lax.broadcasted_iota(jnp.int32, (w, w), 1))
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(eye, col[c:c + w], 0.0), axis=0, keepdims=True)
+         for c in range(0, n, w)], axis=1)
+
+
+def _dot(a, b, dims):
+    # the products take the operands in their own type (bfloat16 rides
+    # the MXU in one pass) and accumulate in float32
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
                   causal, scale, window=0):
     """One (batch*head, q-block) program: stream K/V blocks through
     VMEM folding each into an online-softmax accumulator (Dao 2022).
     Under ``window`` the loop starts at the block that holds the oldest
-    key the program's first query sees, and both edges are masked."""
+    key the program's first query sees, and both edges are masked.
+    Beside the output it writes each row's log-sum-exp, the one
+    statistic the backward kernel rebuilds the weights from."""
     qi = pl.program_id(1)
-    # the products take the operands in their own type (bfloat16 rides
-    # the MXU in one pass) and accumulate in float32
     q = q_ref[0]                                       # (BQ, D)
-    t_k = k_ref.shape[1]
-    n_k = t_k // block_k
+    n_k = k_ref.shape[1] // block_k
 
     def body(j, carry):
         m, l, acc = carry
         k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]   # (BK, D)
         v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # (BQ, BK)
+        s = _dot(q, k_blk, _NT) * scale                    # (BQ, BK)
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            seen = k_pos <= q_pos
-            if window:
-                seen &= k_pos > q_pos - window
-            s = jnp.where(seen, s, NEG_INF)
+            s = jnp.where(_seen(qi * block_q, j * block_k, s.shape,
+                                window), s, NEG_INF)
         m_cur = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m, m_cur)
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
         l_new = alpha * l + p.sum(axis=-1)
-        acc_new = alpha[:, None] * acc + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_new = alpha[:, None] * acc + _dot(p.astype(v_blk.dtype), v_blk,
+                                              _NN)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     a0 = jnp.zeros((block_q, q_ref.shape[2]), jnp.float32)
-    if causal:
-        # blocks strictly above the diagonal contribute nothing
-        n_live = jnp.minimum(((qi + 1) * block_q + block_k - 1)
-                             // block_k, n_k)
-    else:
-        n_live = n_k
     # a row whose window starts past the first block's end folds that
     # block in as all-masked (weight exp(0) under the running maximum
     # NEG_INF); its first visible key then rescales that by exp(-1e30)
     # = 0, and every row sees its own position at the latest
-    first = jnp.maximum(qi * block_q - window + 1, 0) // block_k \
-        if window else 0
+    first, _, _, n_live = _k_blocks(qi, block_q, block_k, n_k, causal,
+                                    window)
     m, l, acc = jax.lax.fori_loop(first, n_live, body, (m0, l0, a0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    l = jnp.maximum(l, 1e-30)
+    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
+    lse_ref[0] = _row_of((m + jnp.log(l))[:, None])
+
+
+def _kernel_specs(interpret):
+    """BlockSpec with the kernels' memory space (none in interpret mode)
+    and the output declaration that inherits an operand's varying mesh
+    axes (under shard_map an output must say how it varies)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    mem = {} if interpret else {"memory_space": pltpu.VMEM}
+    spec = lambda shape, index: pl.BlockSpec(shape, index, **mem)
+    like = lambda shape, dtype, a: jax.ShapeDtypeStruct(
+        shape, dtype, vma=jax.typeof(a).vma)
+    return spec, like
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale",
@@ -106,36 +173,139 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
                                              "interpret", "window"))
 def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
                 window=0):
-    from jax.experimental.pallas import tpu as pltpu
-
+    """-> (out [bh, t_q, d], lse [bh, 1, t_q] float32)."""
     bh, t_q, d = q.shape
     t_k = k.shape[1]
     # grouped-query heads: consecutive ``group`` query heads read one
     # K/V head, picked by the index map; nothing is repeated in HBM
     group = bh // k.shape[0]
-    grid = (bh, t_q // block_q)
     kernel = functools.partial(_flash_kernel, block_q=block_q,
                                block_k=block_k, causal=causal, scale=scale,
                                window=window)
-    mem = {} if interpret else {"memory_space": pltpu.VMEM}
+    spec, like = _kernel_specs(interpret)
     return pl.pallas_call(
         kernel,
-        # under shard_map the output must declare how it varies across
-        # mesh axes (vma) — inherit q's
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype,
-                                       vma=jax.typeof(q).vma),
-        grid=grid,
+        out_shape=(like(q.shape, q.dtype, q),
+                   like((bh, 1, t_q), jnp.float32, q)),
+        grid=(bh, t_q // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0), **mem),
-            pl.BlockSpec((1, t_k, d), lambda b, i: (b // group, 0, 0),
-                         **mem),
-            pl.BlockSpec((1, t_k, d), lambda b, i: (b // group, 0, 0),
-                         **mem),
+            spec((1, block_q, d), lambda b, i: (b, i, 0)),
+            spec((1, t_k, d), lambda b, i: (b // group, 0, 0)),
+            spec((1, t_k, d), lambda b, i: (b // group, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
-                               **mem),
+        out_specs=(spec((1, block_q, d), lambda b, i: (b, i, 0)),
+                   spec((1, 1, block_q), lambda b, i: (b, 0, i))),
         interpret=interpret,
     )(q, k, v)
+
+
+def _flash_bwd_kernel(q_ref, do_ref, o_ref, lse_ref, k_ref, v_ref, dq_ref,
+                      dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
+                      causal, scale, window):
+    """One (K/V head, query head of its group, query block) program, the
+    forward's own loop over the K blocks its rows see (Dao 2022,
+    algorithm 4, in one pass): the score tile again from q and k, the
+    weights from the saved log-sum-exp (no second softmax), then the
+    five products. ``dq`` accumulates here and is written once; ``dk``
+    and ``dv`` accumulate in float32 over the whole key length in VMEM,
+    across every program of the K/V head (its ``group`` query heads
+    among them: nothing is repeated or folded in HBM), and are written
+    once, by the last."""
+    g, qi = pl.program_id(1), pl.program_id(2)
+    q, do = q_ref[0], do_ref[0]                        # (BQ, D)
+    lse = lse_ref[0, 0][:, None]                       # (BQ, 1)
+    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                    axis=-1, keepdims=True)
+
+    @pl.when((g == 0) & (qi == 0))
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step(masked):
+        def body(j, dq):
+            cols = pl.ds(j * block_k, block_k)
+            k_blk, v_blk = k_ref[0, cols, :], v_ref[0, cols, :]
+            s = _dot(q, k_blk, _NT) * scale            # (BQ, BK)
+            if masked:
+                s = jnp.where(_seen(qi * block_q, j * block_k, s.shape,
+                                    window), s, NEG_INF)
+            p = jnp.exp(s - lse)
+            ds = (p * (_dot(do, v_blk, _NT) - delta)).astype(q.dtype)
+            dv_acc[cols, :] += _dot(p.astype(do.dtype), do, _TN)
+            dk_acc[cols, :] += _dot(ds, q, _TN)
+            return dq + _dot(ds, k_blk, _NN)
+        return body
+
+    dq = _masked_then_plain(
+        _k_blocks(qi, block_q, block_k, k_ref.shape[1] // block_k, causal,
+                  window),
+        step, jnp.zeros(q.shape, jnp.float32))
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+
+    @pl.when((g == pl.num_programs(1) - 1) & (qi == pl.num_programs(2) - 1))
+    def _flush():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_vmem_bytes(t_k, d, block_q, block_k, itemsize):
+    """What one backward program keeps in VMEM: K and V of its head and
+    the ``dk``, ``dv`` blocks staged twice (the pipeline's two buffers),
+    the two float32 accumulators, the per-block operands twice, and the
+    float32 tiles of a step (scores, weights, their gradients)."""
+    lanes = max(d, 128)                 # a row pads to the lane width
+    whole = t_k * lanes * (4 * 2 * itemsize + 2 * 4)
+    blocks = block_q * lanes * (4 * 2 * itemsize + 2 * 4)
+    return whole + blocks + 6 * block_q * block_k * 4
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "scale",
+                                             "block_q", "block_k",
+                                             "interpret", "window"))
+def _flash_bwd_call(q, k, v, out, lse, do, causal, scale, block_q, block_k,
+                    interpret, window=0):
+    """-> (dq, dk, dv) from the forward's residuals and ``do``, in one
+    kernel (:func:`_flash_bwd_kernel`)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t_q, d = q.shape
+    bh_k, t_k, _ = k.shape
+    group = bh // bh_k
+    spec, like = _kernel_specs(interpret)
+    rows = lambda h, g, i: (h * group + g, i, 0)
+    head = lambda h, g, i: (h, 0, 0)
+    # Mosaic's default scope holds 16 MiB; the accumulators alone are
+    # 8 MiB at 8,192 x 128. Ask for what the shapes need and its half
+    # again for the compiler's own temporaries.
+    need = _bwd_vmem_bytes(t_k, d, block_q, block_k, q.dtype.itemsize)
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=need + need // 2)}
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, block_q=block_q,
+                          block_k=block_k, causal=causal, scale=scale,
+                          window=window),
+        out_shape=(like(q.shape, q.dtype, q), like(k.shape, k.dtype, k),
+                   like(v.shape, v.dtype, v)),
+        grid=(bh_k, group, t_q // block_q),
+        in_specs=[
+            spec((1, block_q, d), rows),
+            spec((1, block_q, d), rows),
+            spec((1, block_q, d), rows),
+            spec((1, 1, block_q), lambda h, g, i: (h * group + g, 0, i)),
+            spec((1, t_k, d), head),
+            spec((1, t_k, d), head),
+        ],
+        out_specs=(spec((1, block_q, d), rows), spec((1, t_k, d), head),
+                   spec((1, t_k, d), head)),
+        scratch_shapes=[pltpu.VMEM((t_k, d), jnp.float32),
+                        pltpu.VMEM((t_k, d), jnp.float32)],
+        interpret=interpret,
+        # the instruction's name in a capture, whatever transform wraps
+        # the call; the forward's events keep ``_flash_call``
+        name="_flash_bwd_call",
+        **params,
+    )(q, do, out, lse, k, v)
 
 
 # measured on one TPU chip (B=2 H=8 D=128 bf16, causal): dense wins to
@@ -152,108 +322,26 @@ VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 def _flash_diff(q, k, v, causal, scale, block_q, block_k, interpret,
                 window=0):
     return _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
-                       window=window)
+                       window=window)[0]
 
 
 def _flash_diff_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                     window):
-    out = _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
-                      window=window)
-    return out, (q, k, v, out)
-
-
-def _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q,
-                           window=0):
-    """FlashAttention-style backward without the (T, T) HBM matrix
-    (Dao 2022 §3.1 backward): scan over q-blocks, recomputing each
-    (block_q, T_k) score tile from q/k and using D = rowsum(dO ∘ O)
-    for the softmax VJP. Peak memory is O(block_q · T_k) per step plus
-    the dk/dv carries — the regime where the forward kernel dispatches
-    (T ≥ FLASH_MIN_SEQ) no longer OOMs in training.
-
-    Under ``window`` a q-block multiplies against a band of K and V of
-    static length (``window + block_q`` rounded up to the block: every
-    key its rows see), sliced where the block's last row ends and
-    clipped at the sequence's start, and adds its dk, dv into that band:
-    a window layer's backward costs its band, not T_k columns."""
-    bh, t_q, d = q.shape
-    t_k = k.shape[1]
-    nb = t_q // block_q
-    f32 = jnp.float32
-    dD = jnp.sum(g.astype(f32) * out.astype(f32), axis=-1)   # (BH, T_q)
-    qs = jnp.swapaxes(q.reshape(bh, nb, block_q, d), 0, 1)
-    gs = jnp.swapaxes(g.reshape(bh, nb, block_q, d), 0, 1)
-    Ds = jnp.swapaxes(dD.reshape(bh, nb, block_q), 0, 1)
-    kf = k.astype(f32)
-    vf = v.astype(f32)
-    band = -(-(window + block_q) // block_q) * block_q if window else t_k
-    banded = band < t_k
-
-    def body(carry, inp):
-        dk, dv = carry
-        qi, gi, Di, i = inp
-        qi = qi.astype(f32)
-        gi = gi.astype(f32)
-        if banded:
-            # the band ends with the block's last row (t_q == t_k)
-            lo = jnp.maximum((i + 1) * block_q - band, 0)
-            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, lo, band, 1)
-            kb, vb = cut(kf), cut(vf)
-        else:
-            lo, kb, vb = 0, kf, vf
-        s = jnp.einsum("bqd,bsd->bqs", qi * scale, kb)
-        if causal:
-            # forward kernel requires t_q == t_k when causal, so no
-            # decoder offset here
-            q_pos = i * block_q + jnp.arange(block_q)[:, None]
-            k_pos = lo + jnp.arange(kb.shape[1])[None, :]
-            seen = k_pos <= q_pos
-            if window:
-                seen &= k_pos > q_pos - window
-            s = jnp.where(seen, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)                       # (b, bq, Tk)
-        dp = jnp.einsum("bqd,bsd->bqs", gi, vb)
-        ds = p * (dp - Di[..., None])
-        dqi = jnp.einsum("bqs,bsd->bqd", ds, kb) * scale
-        dkb = jnp.einsum("bqs,bqd->bsd", ds, qi) * scale
-        dvb = jnp.einsum("bqs,bqd->bsd", p, gi)
-        if banded:
-            add = lambda a, b: jax.lax.dynamic_update_slice_in_dim(
-                a, cut(a) + b, lo, 1)
-            return (add(dk, dkb), add(dv, dvb)), dqi
-        return (dk + dkb, dv + dvb), dqi
-
-    (dk, dv), dq = jax.lax.scan(
-        body,
-        (jnp.zeros((bh, t_k, d), f32), jnp.zeros((bh, t_k, d), f32)),
-        (qs, gs, Ds, jnp.arange(nb)))
-    dq = jnp.swapaxes(dq, 0, 1).reshape(bh, t_q, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    out, lse = _flash_call(q, k, v, causal, scale, block_q, block_k,
+                           interpret, window=window)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_diff_bwd(causal, scale, block_q, block_k, interpret, window,
                     res, g):
-    q, k, v, out = res
-    group = q.shape[0] // k.shape[0]
-    if group > 1:
-        # the chunked pass wants a K/V row per query head; the groups'
-        # gradients are summed back onto the head they share
-        dq, dk, dv = _flash_diff_bwd(
-            causal, scale, block_q, block_k, interpret, window,
-            (q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
-             out), g)
-        fold = lambda a, like: a.astype(jnp.float32).reshape(
-            like.shape[0], group, *like.shape[1:]).sum(1).astype(like.dtype)
-        return dq, fold(dk, k), fold(dv, v)
-    if q.shape[1] % block_q:
-        # shapes the forward kernel accepted always tile; safety net
-        _, vjp = jax.vjp(
-            lambda a, b, c: _dense_reference(a, b, c, causal, scale,
-                                             window),
-            q, k, v)
-        return vjp(g)
-    return _chunked_attention_bwd(q, k, v, out, g, causal, scale, block_q,
-                                  window)
+    # the backward's query block is twice the forward's where that tiles
+    # (a program's read-modify-write of the dk, dv accumulators and its
+    # K, V block loads are shared by twice the rows: 512 x 512 tiles at
+    # the defaults; measured, PERF.md PR 35)
+    if res[0].shape[1] % (2 * block_q) == 0:
+        block_q *= 2
+    return _flash_bwd_call(*res, g, causal, scale, block_q, block_k,
+                           interpret, window=window)
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
@@ -668,9 +756,16 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
     the sequence doesn't tile; `force=True` always takes the kernel
     (tests). ``window`` > 0 (causal only; 0 = none): query t sees keys
     t - window + 1 ... t, itself among them; the kernel skips the blocks
-    before the window as it skips those past the diagonal, the backward
-    works on a band, and the dense path takes the same mask. A window
-    that covers the whole sequence is plain causal attention."""
+    before the window as it skips those past the diagonal, and the dense
+    path takes the same mask. A window that covers the whole sequence is
+    plain causal attention.
+
+    Differentiated, the kernel path runs a second kernel
+    (``_flash_bwd_call`` in a capture) over the same blocks: the forward
+    saves each row's log-sum-exp beside its output, and one pass rebuilds
+    a tile's weights from it and accumulates ``dq``, ``dk`` and ``dv`` in
+    VMEM (float32; the products take the operands' type), the grouped
+    K/V heads' gradients summed there and written once."""
     squeeze = False
     if q.ndim == 4:
         b, h, t, d = q.shape

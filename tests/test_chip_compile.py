@@ -54,8 +54,30 @@ def chip(topo):
     cc.reset_cache()
 
 
+def _kernel_names(text):
+    return [line.split(" = ")[0].split("%")[-1].split(".")[0]
+            for line in text.splitlines() if "tpu_custom_call" in line]
+
+
 def _is_kernel(lowered):
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    assert _kernel_names(lowered.compile().as_text())
+
+
+def _backward_is_a_kernel(q, kv, scale, window=0):
+    """The gradient of ``_flash_diff`` at the forward's blocks: the forward
+    kernel and the backward kernel, each a ``tpu_custom_call``, the
+    backward's under a name of its own (``_flash_call`` is the name the
+    benchmark counts forward FLOPs by), and no XLA loop."""
+    def loss(q, k, v):
+        out = pk._flash_diff(q, k, v, True, scale, 256, 512, False, window)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    names = sorted(_kernel_names(text))
+    assert len(names) == 2 and names[0] == "_flash_bwd_call"
+    assert "_flash_call" in names[1]
+    assert " while(" not in text and "dynamic-update-slice(" not in text
 
 
 @pytest.mark.parametrize("bh,t,d", [(16, 4096, 128), (8, 8192, 128)])
@@ -64,6 +86,24 @@ def test_flash_compiles(chip, bh, t, d):
     _is_kernel(pk._flash_call.lower(
         q, q, q, causal=True, scale=d ** -0.5, block_q=256, block_k=512,
         interpret=False))
+
+
+def test_flash_backward_compiles_at_the_longest_keys_the_dispatch_admits(
+        chip):
+    """24,576 keys of 128 lanes in bfloat16 fill ``VMEM_BUDGET_BYTES``, the
+    dispatch's line for the kernels; the backward kernel keeps K, V and
+    float32 ``dk``, ``dv`` of that length (some 76 MB) and asks Mosaic for
+    the scope that takes. (The forward kernel itself, at Mosaic's default
+    scope of 16 MiB, compiles to 14,336 keys of 128 lanes and is refused
+    from 16,384: ROADMAP B9.)"""
+    q = chip((2, 24576, 128), jnp.bfloat16)
+    kv = chip((1, 24576, 128), jnp.bfloat16)
+    assert 2 * 24576 * 128 * 2 == pk.VMEM_BUDGET_BYTES
+    text = pk._flash_bwd_call.lower(
+        q, kv, kv, q, chip((2, 1, 24576), jnp.float32), q, causal=True,
+        scale=128 ** -0.5, block_q=512, block_k=512,
+        interpret=False).compile().as_text()
+    assert _kernel_names(text) == ["_flash_bwd_call"]
 
 
 def test_flash_compiles_grouped_heads_at_64_lanes(chip):
@@ -84,9 +124,8 @@ def test_flash_compiles_grouped_heads_at_64_lanes(chip):
                                   block_q=256, block_k=512, interpret=False)
 
     text = jax.jit(mx_attn).lower(q, kv, kv).compile().as_text()
-    kernels = [line.split(" = ")[0].split("%")[-1]
-               for line in text.splitlines() if "tpu_custom_call" in line]
-    assert len(kernels) == 1 and kernels[0].startswith("_flash_call")
+    assert _kernel_names(text) == ["_flash_call"]
+    _backward_is_a_kernel(q, kv, 0.125)
 
 
 def test_expert_layer_compiles_to_grouped_kernels_by_their_name(chip):
@@ -126,22 +165,15 @@ def test_flash_compiles_at_256_lanes_over_2_kv_heads(chip):
     """Qwen3-Next's attention at the benchmark's batch: 32 query heads
     (2 x 16) of 256 lanes over 4 K/V heads (2 x 2), 4,096 tokens: K and V
     of one head are 4 MiB, inside ``VMEM_BUDGET_BYTES``, so the dispatch
-    takes the kernel; the backward is ``_chunked_attention_bwd`` at that
-    width."""
+    takes the kernel; the backward is a kernel too at that width, over
+    groups of 8."""
     q = chip((32, 4096, 256), jnp.bfloat16)
     kv = chip((4, 4096, 256), jnp.bfloat16)
     assert 2 * 4096 * 256 * 2 <= pk.VMEM_BUDGET_BYTES
     _is_kernel(pk._flash_call.lower(
         q, kv, kv, causal=True, scale=256 ** -0.5, block_q=256, block_k=512,
         interpret=False))
-
-    def loss(q, k, v):
-        out = pk._flash_diff(q, k, v, True, 256 ** -0.5, 256, 512, False)
-        return out.astype(jnp.float32).sum()
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile().as_text()
-    assert "tpu_custom_call" in text and "while" in text
+    _backward_is_a_kernel(q, kv, 256 ** -0.5)
 
 
 def test_flash_compiles_with_a_window_at_128_lanes_over_groups_of_7(chip):
@@ -149,9 +181,10 @@ def test_flash_compiles_with_a_window_at_128_lanes_over_groups_of_7(chip):
     heads of 128 lanes over 4 K/V heads (groups of 7, no power of two),
     one sequence of 8,192 tokens, a window of 4,096: K and V of a program
     are 4 MiB, inside ``VMEM_BUDGET_BYTES``; the K loop starts at a block
-    computed from the program's index. The backward is the banded
-    ``_chunked_attention_bwd``: its score tile is 4,352 columns wide, not
-    8,192."""
+    computed from the program's index. The backward's kernel takes the
+    same window (and none, as the full layer does); beside K and V it
+    keeps ``dk``, ``dv`` of the head in float32 (8 MiB) and asks Mosaic
+    for the scope that takes."""
     q = chip((28, 8192, 128), jnp.bfloat16)
     kv = chip((4, 8192, 128), jnp.bfloat16)
     assert 2 * 8192 * 128 * 2 <= pk.VMEM_BUDGET_BYTES
@@ -159,16 +192,7 @@ def test_flash_compiles_with_a_window_at_128_lanes_over_groups_of_7(chip):
         _is_kernel(pk._flash_call.lower(
             q, kv, kv, causal=True, scale=128 ** -0.5, block_q=256,
             block_k=512, interpret=False, window=window))
-
-    def loss(q, k, v):
-        out = pk._flash_diff(q, k, v, True, 128 ** -0.5, 256, 512, False,
-                             4096)
-        return out.astype(jnp.float32).sum()
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile().as_text()
-    assert "tpu_custom_call" in text and "while" in text
-    assert "f32[28,256,4352]" in text and "f32[28,256,8192]" not in text
+        _backward_is_a_kernel(q, kv, 128 ** -0.5, window)
 
 
 def test_smallthinker_step_compiles_and_fits_at_the_published_widths(

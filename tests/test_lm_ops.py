@@ -208,7 +208,7 @@ def test_flash_kernel_grouped_heads_forward_and_gradient(heads, kv, dtype,
                                    rtol=5 * tol, atol=5 * tol)
 
 
-# -- window attention: kernel, dense path and the banded backward --------------
+# -- window attention: kernel, dense path and the backward kernel --------------
 
 def _masked_oracle(q, k, v, window):
     """The masked dense attention written out: [b, heads, t, d] over
@@ -228,9 +228,10 @@ def _window_inputs(heads, kv, t, d, dtype=jnp.float32, b=1):
             jnp.asarray(_arr(b, kv, t, d), dtype))
 
 
-# a window of one block, one that ends inside a block, one longer than
-# two, and a single key; 14 query heads over 2 K/V heads are groups of 7
-@pytest.mark.parametrize("window", [1, 32, 45, 100])
+# a single key, a window of one block, one that ends inside a block, one
+# longer than two, and two that cover the sequence; 14 query heads over 2
+# K/V heads are groups of 7
+@pytest.mark.parametrize("window", [1, 32, 45, 100, 256, 300])
 @pytest.mark.parametrize("heads,kv", [(14, 2), (4, 4)])
 def test_window_kernel_forward_and_banded_backward(heads, kv, window):
     t, d = 256, 16
@@ -253,24 +254,33 @@ def test_window_kernel_forward_and_banded_backward(heads, kv, window):
 
 
 def test_banded_backward_takes_a_band_and_not_every_column():
-    """With a window the backward scan's score tile is ``window +
-    block_q`` columns rounded up to the block, not ``t_k``."""
-    t, d, window, block_q = 512, 16, 70, 32
+    """With a window the backward kernel visits the blocks of the band and
+    no others: a gradient poisoned outside the band (NaN in every query row
+    that no kept pair joins to the K block under test) leaves that block's
+    ``dk``, ``dv`` as they were, and a query block's ``dq`` never reads the
+    K blocks before its window or past its diagonal."""
+    t, d, window, block_q, block_k = 512, 16, 70, 32, 64
     q, k, v = (a[0] for a in _window_inputs(2, 2, t, d))
-
-    def loss(q, k, v):
-        return pk._flash_diff(q, k, v, True, 0.25, block_q, 64, True,
-                              window).sum()
-
-    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
-    band = -(-(window + block_q) // block_q) * block_q
-    assert band == 128
-    assert "f32[2,%d,%d]" % (block_q, band) in text
-    assert "f32[2,%d,%d]" % (block_q, t) not in text
-    plain = str(jax.make_jaxpr(jax.grad(
-        lambda *a: pk._flash_diff(*a, True, 0.25, block_q, 64, True).sum(),
-        (0, 1, 2)))(q, k, v))
-    assert "f32[2,%d,%d]" % (block_q, t) in plain
+    kw = dict(causal=True, scale=0.25, block_q=block_q, block_k=block_k,
+              interpret=True, window=window)
+    out, lse = pk._flash_call(q, k, v, **kw)
+    do = jnp.asarray(_arr(2, t, d))
+    want = pk._flash_bwd_call(q, k, v, out, lse, do, **kw)
+    # K block 2 holds keys 128 ... 191: queries 128 ... 260 see one of them,
+    # inside query blocks 4 ... 8; every other block's rows are poisoned
+    rows = jnp.arange(t) // block_q
+    far = (rows < 4) | (rows > 8)
+    _, dk, dv = pk._flash_bwd_call(
+        q, k, v, out, lse, jnp.where(far[None, :, None], jnp.nan, do), **kw)
+    np.testing.assert_array_equal(dk[:, 128:192], want[1][:, 128:192])
+    np.testing.assert_array_equal(dv[:, 128:192], want[2][:, 128:192])
+    assert not np.isfinite(np.asarray(dk[:, :64])).any()
+    # query block 8 (rows 256 ... 287) sees keys 187 ... 287: K blocks 2 ... 4
+    cols = jnp.arange(t) // block_k
+    dq, _, _ = pk._flash_bwd_call(
+        q, jnp.where(((cols < 2) | (cols > 4))[None, :, None], jnp.nan, k),
+        v, out, lse, do, **kw)
+    np.testing.assert_array_equal(dq[:, 256:288], want[0][:, 256:288])
 
 
 @pytest.mark.parametrize("window", [3, 40])

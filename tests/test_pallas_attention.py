@@ -7,6 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.pallas_kernels import (FLASH_MIN_SEQ, _dense_reference,
                                           flash_attention)
 
@@ -69,8 +70,8 @@ def test_contrib_op_registered():
 
 
 def test_flash_gradients():
-    """The kernel path is differentiable (custom VJP recomputes through
-    the dense formulation), matching dense gradients."""
+    """The kernel path is differentiable (custom VJP: the backward
+    kernel), matching dense gradients."""
     rng = np.random.default_rng(4)
     q, k, v = (jnp.asarray(rng.standard_normal((2, 128, 16)), jnp.float32)
                for _ in range(3))
@@ -90,7 +91,7 @@ def test_flash_gradients():
 
 
 def test_flash_chunked_backward_rectangular():
-    """Non-causal t_q != t_k through the chunked backward (the (T,T)
+    """Non-causal t_q != t_k through the backward kernel (the (T,T)
     matrix is never materialized; ADVICE r3)."""
     rng = np.random.default_rng(5)
     q = jnp.asarray(rng.standard_normal((2, 128, 16)), jnp.float32)
@@ -109,3 +110,115 @@ def test_flash_chunked_backward_rectangular():
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
+
+
+# -- the backward kernel against the oracle - ----------------------------------
+
+def _qkv(rng, heads, kv, t_q, t_k, d, dtype):
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    return draw(heads, t_q, d), draw(kv, t_k, d), draw(kv, t_k, d)
+
+
+def _oracle(q, k, v, causal, scale, window=0):
+    """``_dense_reference`` in float32 over K/V repeated for the group."""
+    group = q.shape[0] // k.shape[0]
+    wide = lambda a: jnp.repeat(a.astype(jnp.float32), group, axis=0)
+    return _dense_reference(q.astype(jnp.float32), wide(k), wide(v), causal,
+                            scale, window)
+
+
+# groups of 1, 4, 7 and 8 at the three cells' lanes; not causal: t_q != t_k
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 4e-2)])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("group,d", [(1, 64), (4, 64), (7, 128), (8, 256)])
+def test_backward_kernel_matches_the_dense_gradient(group, d, causal, dtype,
+                                                   tol):
+    rng = np.random.default_rng(group * d)
+    t_q, t_k = (64, 64) if causal else (32, 96)
+    q, k, v = _qkv(rng, 2 * group, 2, t_q, t_k, d, dtype)
+    head = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    scale = d ** -0.5
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=16,
+                               block_k=32, force=True, interpret=True)
+
+    got = jax.grad(lambda *a: (flash(*a).astype(jnp.float32) * head).sum(),
+                   (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (_oracle(*a, causal, scale) * head).sum(),
+                    (0, 1, 2))(q, k, v)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and a.dtype == dtype
+        w = np.asarray(w, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), w, rtol=tol,
+                                   atol=tol * np.abs(w).max())
+
+
+@pytest.mark.parametrize("causal,window", [(False, 0), (True, 0), (True, 1),
+                                           (True, 16), (True, 45)])
+def test_forward_saves_the_log_sum_exp_of_the_masked_scores(causal, window):
+    rng = np.random.default_rng(7)
+    t_q, t_k, d = 64, 64 if causal else 96, 16
+    q, k, v = _qkv(rng, 4, 2, t_q, t_k, d, jnp.float32)
+    out, lse = pk._flash_call(q, k, v, causal, 0.25, 16, 32, True,
+                              window=window)
+    assert lse.shape == (4, 1, t_q) and lse.dtype == jnp.float32
+    s = jnp.einsum("btd,bsd->bts", q * 0.25, jnp.repeat(k, 2, axis=0))
+    if causal:
+        i, j = jnp.arange(t_q)[:, None], jnp.arange(t_k)[None, :]
+        seen = (j <= i) & ((j > i - window) if window else True)
+        s = jnp.where(seen, s, -jnp.inf)
+    np.testing.assert_allclose(lse[:, 0], jax.nn.logsumexp(s, axis=-1),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(out, _oracle(q, k, v, causal, 0.25, window),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 32), (32, 16)])
+@pytest.mark.parametrize("window", [0, 1, 16, 32, 45, 100, 191])
+def test_block_ranges_are_the_blocks_the_mask_keeps(block_q, block_k,
+                                                    window):
+    """``_k_blocks`` (the forward's loop and the backward's) against the
+    mask written out: ``[a, d)`` are the blocks with a visible pair,
+    ``[b, c)`` those with no hidden one."""
+    t = 192
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = (j <= i) & ((j > i - window) if window else True)
+    n_q, n_k = t // block_q, t // block_k
+    tiles = seen.reshape(n_q, block_q, n_k, block_k)
+    some, every = tiles.any((1, 3)), tiles.all((1, 3))     # [n_q, n_k]
+
+    def check(bounds, some, every):
+        a, b, c, d = (int(x) for x in bounds)
+        assert 0 <= a <= b <= c <= d <= len(some)
+        assert list(np.flatnonzero(some)) == list(range(a, d))
+        assert every[b:c].all()
+        # the masked edges hold every block that needs its mask; a block
+        # with no hidden pair may sit there only next to one that has
+        assert not every[a:b][:-1].any() and not every[c:d][1:].any()
+
+    for qi in range(n_q):
+        check(pk._k_blocks(jnp.int32(qi), block_q, block_k, n_k, True,
+                           window), some[qi], every[qi])
+    assert pk._k_blocks(0, block_q, block_k, n_k, False, 0) == (0, 0, n_k,
+                                                                n_k)
+
+
+def test_backward_is_one_kernel_and_no_scan():
+    """The gradient's program: the forward kernel and the backward kernel
+    under its own name; no scan, no float32 score tile in HBM, no K/V
+    repeated for the group."""
+    rng = np.random.default_rng(8)
+    q, k, v = _qkv(rng, 14, 2, 128, 128, 16, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return pk._flash_diff(q, k, v, True, 0.25, 32, 64, True,
+                              45).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v))
+    assert text.count("pallas_call[") == 2
+    assert "name=_flash_bwd_call" in text
+    for gone in ("scan[", "dynamic_update_slice", "f32[14,32,128]", "f32[14,128,128]",
+                 "bf16[14,128,16] = broadcast", "repeat"):
+        assert gone not in text, gone
