@@ -10,7 +10,18 @@ save/restore, and the kvstore wire protocol (a worker push/pull span's
 — and the server opens child spans for recv/update).
 
 Closed spans land in bounded per-thread ring buffers; nothing is ever
-written unless asked.  Consumers:
+written unless asked.  A ring record is a dict with the keys ``name``,
+``cat``, ``trace``, ``span``, ``parent`` (the enclosing span's id, None
+for a root), ``start_ns`` and ``dur_ns`` (``CLOCK_MONOTONIC``),
+``cpu_ns`` (the CPU time the span's own thread burnt inside it,
+``CLOCK_THREAD_CPUTIME_ID``; None for a span finished elsewhere and
+handed to :func:`record_span`), ``tid``, ``thread`` and ``attrs``.
+``dur_ns - cpu_ns`` is how long the thread stood blocked inside the
+span: on the device, on buffers, on another thread. The record keeps
+the clock's reading as it is: where the kernel's scheduler clock ticks
+(10 ms on some virtual machines) one span reads a whole number of
+ticks, 0 or more than its length, and only a sum over many spans says
+how long they worked (``clock.thread_cpu_ns``). Consumers:
 
 - ``tracing.export.write_trace(path)`` — one trace file per process,
   stitched across ranks by ``tools/trace_merge.py``;
@@ -19,7 +30,10 @@ written unless asked.  Consumers:
   on SIGTERM, unhandled crash, or a watchdog timeout
   (``MXTPU_HANG_TIMEOUT_SEC``);
 - ``telemetry`` — span durations of framework seams feed the
-  ``mx_span_seconds`` histogram family.
+  ``mx_span_seconds`` histogram family;
+- the benchmark — ``benchmark/lib/ring.py`` reads the ring as it stands
+  after a window (:func:`spans_snapshot`): the training loop's spans
+  over the untraced steps, busy and blocked, per step.
 
 Knobs: ``MXTPU_TRACE_SAMPLE`` (0..1 trace-level sampling, default 1 —
 rings are cheap; 0 disables recording entirely), ``MXTPU_TRACE_RING``
@@ -174,12 +188,13 @@ class Span:
     the context on the wire. While it is open the same interval stands
     under the same name as a TraceMe on the host plane of a
     ``jax.profiler`` capture, beside ``PjitFunction(...)``: the ring
-    keeps it on ``CLOCK_MONOTONIC`` with its parent link, the capture on
-    the profiler's clock with the device's operations."""
+    keeps it on ``CLOCK_MONOTONIC`` with its parent link and its
+    thread's CPU time, the capture on the profiler's clock, as one
+    length, with the device's operations."""
 
     __slots__ = ("name", "cat", "attrs", "trace_id", "span_id",
-                 "parent_id", "start_ns", "_token", "_ring_ref",
-                 "_mirror")
+                 "parent_id", "start_ns", "_cpu_start_ns", "_token",
+                 "_ring_ref", "_mirror")
 
     def __init__(self, name, cat, attrs, trace_id, parent_id):
         self.name = name
@@ -189,6 +204,7 @@ class Span:
         self.span_id = _new_id()
         self.parent_id = parent_id
         self.start_ns = 0
+        self._cpu_start_ns = 0
         self._token = None
         self._ring_ref = None
         self._mirror = None
@@ -198,6 +214,7 @@ class Span:
 
     def __enter__(self):
         self.start_ns = clock.now_ns()
+        self._cpu_start_ns = clock.thread_cpu_ns()
         self._token = _ctx.set(self)
         r = self._ring_ref = _ring()
         r.open.append(self)
@@ -210,6 +227,7 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         if self._mirror is not None:
             self._mirror.__exit__(exc_type, exc, tb)
+        cpu_ns = clock.thread_cpu_ns() - self._cpu_start_ns
         end_ns = clock.now_ns()
         _ctx.reset(self._token)
         r = self._ring_ref
@@ -226,6 +244,7 @@ class Span:
                "trace": self.trace_id, "span": self.span_id,
                "parent": self.parent_id,
                "start_ns": self.start_ns, "dur_ns": end_ns - self.start_ns,
+               "cpu_ns": cpu_ns,
                "tid": r.ident, "thread": r.thread_name,
                "attrs": self.attrs}
         r.closed.append(rec)
@@ -387,6 +406,7 @@ def record_span(name, trace_id, parent_id, start_ns, end_ns, cat=None,
                      "parent": int(parent_id) or None,
                      "start_ns": int(start_ns),
                      "dur_ns": int(end_ns) - int(start_ns),
+                     "cpu_ns": None,
                      "tid": r.ident, "thread": r.thread_name,
                      "attrs": dict(attrs or {})})
     if len(r.closed) > _RING_CAP:

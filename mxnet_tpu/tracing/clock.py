@@ -29,6 +29,24 @@ def now_ns():
     return time.monotonic_ns()
 
 
+def thread_cpu_ns():
+    """CPU time the calling thread has burnt, in nanoseconds
+    (CLOCK_THREAD_CPUTIME_ID). It stands still while the thread is
+    blocked — on the device, on a buffer, on a lock, in ``sleep`` — so
+    over an interval, wall less this is how long the thread stood
+    blocked. Differences only: the zero point is the thread's own.
+
+    The kernel charges a thread from its scheduler clock. Where that
+    clock has no fine-grained source (a virtual machine without a
+    stable TSC falls back to the timer tick, 10 ms at HZ=100) a
+    difference is the number of ticks that fell while the thread ran,
+    times the tick: right in the mean over many intervals, 0 or a whole
+    tick for one short interval. So nothing here or in a span clamps a
+    reading to the interval's length; whoever adds readings up may
+    bound the sum."""
+    return time.thread_time_ns()
+
+
 def rel_us(ns):
     """Absolute monotonic ns -> microseconds since the process epoch
     (the chrome-trace ``ts`` unit)."""

@@ -11,7 +11,8 @@ File format (versioned, plain JSON)::
      "meta": {"pid": ..., "role": "worker", "rank": 0,
               "epoch_ns": <process epoch for profiler-relative ts>},
      "spans": [{"name", "cat", "trace", "span", "parent",
-                "start_ns", "dur_ns", "tid", "thread", "attrs"}, ...]}
+                "start_ns", "dur_ns", "cpu_ns", "tid", "thread",
+                "attrs"}, ...]}
 
 Wire-propagation format this pairs with (comm.cc wire v2): every
 kvstore request header carries ``u64 trace_id | u64 span_id`` after the

@@ -12,6 +12,7 @@ import math
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -342,6 +343,119 @@ def test_sample_zero_records_nothing_and_builds_no_annotation(monkeypatch):
     recorded = len(tracing.spans_snapshot())
     assert recorded >= len(TRAIN_PARENT)
     assert _Counted.made >= recorded       # every span has its twin
+
+
+# -- (g) a span's own thread: worked or blocked ------------------------------
+def _spin(seconds):
+    end = time.monotonic() + seconds
+    while time.monotonic() < end:
+        pass
+
+
+def _tick_ns():
+    """The thread clock's step: the least increment a spinning thread
+    sees. Under a microsecond where the scheduler clock is fine, 10 ms
+    where it ticks (``tracing/clock.py``)."""
+    seen, last, end = [], time.thread_time_ns(), time.monotonic() + 0.2
+    while len(seen) < 5 and time.monotonic() < end:
+        now = time.thread_time_ns()
+        if now != last:
+            seen.append(now - last)
+            last = now
+    return min(seen)
+
+
+def _cpu_share(body, seconds, tick_ns):
+    tracing.reset()
+    with tracing.span("probe"):
+        body(seconds)
+    rec, = tracing.spans_snapshot()
+    assert 0 <= rec["cpu_ns"] <= rec["dur_ns"] + tick_ns
+    return rec["cpu_ns"] / rec["dur_ns"]
+
+
+@pytest.mark.parametrize("body,low,high", [(time.sleep, 0.0, 0.1),
+                                           (_spin, 0.9, 1.1)],
+                         ids=["sleep", "spin"])
+def test_span_cpu_time_tells_work_from_waiting(body, low, high):
+    """A thread that waits burns no CPU time, one that spins burns its
+    whole length. Another process can take the core from a spin, so it
+    has a few short tries and one has to come through whole."""
+    tick = _tick_ns()
+    seconds = max(0.02, 30 * tick / 1e9)
+    shares = []
+    for _ in range(20):
+        shares.append(_cpu_share(body, seconds, tick))
+        if low <= shares[-1] <= high:
+            break
+    assert low <= shares[-1] <= high, shares
+
+
+def test_a_record_keeps_the_clocks_reading_where_it_ticks(monkeypatch):
+    """One 10 ms tick falls inside a span a millisecond long: the record
+    says 10 ms, more than its length. Bounding is for whoever sums."""
+    ticks = iter([0, 10_000_000])
+    monkeypatch.setattr(tracing.clock, "thread_cpu_ns", lambda: next(ticks))
+    tracing.reset()
+    with tracing.span("short"):
+        time.sleep(0.001)
+    rec, = tracing.spans_snapshot()
+    assert rec["cpu_ns"] == 10_000_000 > rec["dur_ns"]
+
+
+def test_a_span_finished_elsewhere_has_no_cpu_time():
+    tracing.reset()
+    tracing.record_span("remote", 7, 0, 100, 350)
+    rec, = tracing.spans_snapshot()
+    assert rec["dur_ns"] == 250 and rec["cpu_ns"] is None
+
+
+def test_cpu_time_passes_through_the_trace_file_and_the_flight_view(
+        tmp_path):
+    tracing.reset()
+    with tracing.span("here"):
+        pass
+    tracing.record_span("remote", 7, 0, 100, 350)
+    path = str(tmp_path / "trace.json")
+    tracing.export.write_trace(path)
+    loaded = {s["name"]: s for s in tracing.export.load_trace(path)["spans"]}
+    assert loaded["here"]["cpu_ns"] >= 0
+    assert loaded["remote"]["cpu_ns"] is None
+    assert len(tracing.export.chrome_events(loaded.values())) == 2
+    recent = [s["name"] for t in tracing.flight.snapshot()["threads"]
+              for s in t["recent"]]
+    assert {"here", "remote"} <= set(recent)
+
+
+@pytest.mark.parametrize("clock_fn", ["now_ns", "thread_cpu_ns"])
+def test_sample_zero_reads_no_clock(monkeypatch, clock_fn):
+    reads = []
+    real = getattr(tracing.clock, clock_fn)
+    monkeypatch.setattr(tracing.clock, clock_fn,
+                        lambda: reads.append(1) or real())
+    with tracing.span("sampled"):
+        pass
+    assert reads                    # the probe sees a span's reads
+    del reads[:]
+    tracing.set_sample(0)
+    try:
+        with tracing.span("unsampled") as sp:
+            assert sp is tracing.NOOP
+        assert tracing.record_span("remote", 7, 0, 100, 350) == 0
+    finally:
+        tracing.set_sample(1)
+    assert reads == []
+
+
+def test_step_spans_in_the_ring_carry_cpu_time(captured):
+    tick = _tick_ns()
+    by_id = {s["span"]: s for s in captured["ring"]}
+    for rec in captured["ring"]:
+        assert 0 <= rec["cpu_ns"] <= rec["dur_ns"] + tick, rec["name"]
+        assert rec["cpu_ns"] > 0 or tick > 1_000_000, rec["name"]
+        parent = by_id.get(rec["parent"])
+        if parent is not None:      # one thread, one clock: a child's
+            assert rec["cpu_ns"] <= parent["cpu_ns"]    # time is inside
 
 
 # -- (f) names that do not change from process to process -------------------
