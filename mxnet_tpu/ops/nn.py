@@ -923,7 +923,10 @@ def gq_attention(query, key, value, causal=True, scale=None, window=0):
     return jnp.swapaxes(out, 1, 2)
 
 
-RULE_GROUP = 8       # chunks of the gated delta rule kept per checkpoint
+# chunks of the rule's XLA path kept per checkpoint. It and ``grouped`` below
+# serve that path alone: the kernels keep a state a chunk and need no group.
+# No benchmark cell runs the XLA path, so the constant is not re-tuned.
+RULE_GROUP = 8
 
 
 @register("GatedDeltaRule", aliases=("_contrib_GatedDeltaRule",))
@@ -952,11 +955,42 @@ def gated_delta_rule(query, key, value, g, beta, chunk=64, eps=1e-6):
     products take their operands in ``value``'s type. A sequence that is
     no multiple of C is padded with tokens that leave the state alone.
 
-    Differentiated, the scan over groups of ``RULE_GROUP`` chunks keeps
+    On a TPU, heads of whole 128-lane tiles take two Pallas kernels
+    (``pallas_kernels.delta_rule``: ``_gdn_fwd_call`` and ``_gdn_bwd_call``
+    in a capture): a program serves a key head's value heads, the state
+    stays in VMEM from chunk to chunk, the substitution is made on the VPU
+    (16 x 16 blocks) and of products at full float32 precision, and a
+    derivative of its own keeps the arguments, the state before each chunk
+    and each chunk's inverse, from which the backward kernel rebuilds a
+    chunk's arrays.
+    Anything else takes the same mathematics in plain XLA, a ``lax.scan``
+    over groups of ``RULE_GROUP`` chunks: differentiated, that scan keeps
     each group's incoming state beside the arguments, and a group's own
     arrays are computed again in the backward pass (``jax.checkpoint``):
     one state per group and one group's chunk-local arrays are held,
     never a state per token."""
+    return _delta_rule(query, key, value, g, beta, chunk, eps)
+
+
+def _delta_rule(query, key, value, g, beta, chunk, eps, interpret=None,
+                force=False):
+    """The rule's one dispatch, from the backend and the shapes alone;
+    ``force`` takes the kernels wherever the shapes admit them (the parity
+    tests run them in interpret mode)."""
+    from . import pallas_kernels as pk
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    tiles = pk.delta_rule_tiles(query.shape[3], value.shape[3],
+                                min(int(chunk), query.shape[1]))
+    if tiles and (force or not interpret):
+        return pk.delta_rule(query, key, value, g, beta, chunk=chunk, eps=eps,
+                             interpret=bool(interpret))
+    return _delta_rule_scan(query, key, value, g, beta, chunk, eps)
+
+
+def _delta_rule_scan(query, key, value, g, beta, chunk, eps):
+    """:func:`gated_delta_rule` in plain XLA: the path of every shape the
+    kernels do not take, and the oracle of their tests."""
     b, t, hk, dk = query.shape
     hv, dv = value.shape[2:]
     f32, lo = jnp.float32, value.dtype

@@ -745,6 +745,436 @@ def fused_adam(weight, grad, mean, var, lr=0.01, beta1=0.9,
                                _adam_reference, interpret, force)
 
 
+# ---------------------------------------------------------------------------
+# the gated delta rule (ops/nn.py ``gated_delta_rule`` owns the dispatch and
+# the chunked XLA path, which is the oracle of these kernels' tests)
+# ---------------------------------------------------------------------------
+
+# the same products with a leading batch axis: a program's chunks
+_BNT = (((2,), (2,)), ((0,), (0,)))
+_BNN = (((2,), (1,)), ((0,), (0,)))
+_BTN = (((1,), (1,)), ((0,), (0,)))
+
+
+def _mm(a, b, dims):
+    """A product accumulated in float32: 16-bit operands in one pass of the
+    MXU, float32 operands at full float32 precision."""
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+        else None)
+
+
+def _chunk_iota(c):
+    return (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
+
+
+def _col_of(row):
+    """[n, 1, c] rows as [n, c, 1] columns: each row on the diagonal of a
+    square tile, summed over the lanes (exact, as :func:`_row_of`)."""
+    ii, jj = _chunk_iota(row.shape[-1])
+    return jnp.sum(jnp.where(ii == jj, row, 0.0), axis=-1, keepdims=True)
+
+
+_SOLVE_BLOCK = 16
+
+
+def _diagonal_inverses(a):
+    """The inverses of ``I +`` the 16 x 16 diagonal blocks of ``a``
+    [n, c, c], block-diagonal [n, c, c], by forward substitution proper
+    on the VPU: every block's rows on the same 16 sublanes, each block at
+    its own lanes ([n, 16, c]: a chunk's blocks fill two registers), and
+    with ``T = I`` column after column ``T <- T - a[:, j] T[j, :]``."""
+    n, c = a.shape[0], a.shape[-1]
+    w = _SOLVE_BLOCK
+    lane = jax.lax.broadcasted_iota(jnp.int32, (w, c), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (w, c), 0)
+    own = jnp.zeros((n, w, c), jnp.float32)
+    for r in range(c // w):
+        own = jnp.where(lane // w == r, a[:, r * w:(r + 1) * w, :], own)
+    # column j of every block along its block's lanes, for every j at once:
+    # a product with zeros and ones, exact over the three bfloat16 pieces
+    # of a float32
+    src = jax.lax.broadcasted_iota(jnp.int32, (c, (w - 1) * c), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (c, (w - 1) * c), 1)
+    pick = jnp.where((src // w == (dst % c) // w) & (src % w == dst // c),
+                     1.0, 0.0).astype(jnp.bfloat16)
+    rest, cols = own.reshape(n * w, c), 0.0
+    for _ in range(3):
+        piece = rest.astype(jnp.bfloat16)
+        rest = rest - piece.astype(jnp.float32)
+        cols = cols + _dot(piece, pick, _NN)
+    cols = cols.reshape(n, w, (w - 1) * c)
+    t = jnp.where(lane % w == row, 1.0, 0.0) + jnp.zeros_like(own)
+    for j in range(w - 1):
+        t = t - cols[:, :, j * c:(j + 1) * c] * t[:, j:j + 1, :]
+    return jnp.concatenate(
+        [jnp.where(lane // w == r, t, 0.0) for r in range(c // w)], axis=1)
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` of strictly lower triangular float32 ``a`` [n, c, c],
+    c = 16 * 2^m (Mosaic has no triangular solve): the 16 x 16 diagonal
+    blocks by forward substitution, then pairs of blocks merged by products
+    at full float32 precision, ``[[T1, 0], [-T2 a21 T1, T2]]``, until one
+    block is left."""
+    c = a.shape[-1]
+    t = _diagonal_inverses(a)
+    size = _SOLVE_BLOCK
+    while size < c:
+        # of a pair of blocks of ``size`` rows the second alone changes, at
+        # the first's lanes. The second blocks of every pair at once, half
+        # a chunk's rows: a's (their pair's lanes alone) times T, laid back
+        # on their rows, then their own rows of T times that
+        blocks = lambda x: [x[:, r:r + size] for r in range(0, x.shape[1],
+                                                            size)]
+        second = lambda x: jnp.concatenate(blocks(x)[1::2], axis=1)
+        half = (c // 2, c)
+        pair = (jax.lax.broadcasted_iota(jnp.int32, half, 1) // size ==
+                2 * (jax.lax.broadcasted_iota(jnp.int32, half, 0) // size))
+        x = _mm(jnp.where(pair, second(a), 0.0), t, _BNN)
+        zero = jnp.zeros_like(x[:, :size])
+        on_rows = jnp.concatenate(
+            [y for block in blocks(x) for y in (zero, block)], axis=1)
+        new = second(t) - _mm(second(t), on_rows, _BNN)
+        t = jnp.concatenate(
+            [y for both in zip(blocks(t)[::2], blocks(new)) for y in both],
+            axis=1)
+        size *= 2
+    return t
+
+
+def _gdn_keys(q_ref, k_ref, per, eps, lo):
+    """A program's ``per`` chunks of its key head, [per, c, dk]: q and k
+    at unit length in float32 (q scaled by ``dk^-1/2`` besides), what the
+    gradient of that needs, and the two products every value head of the
+    key head shares."""
+    def unit(ref):
+        x = ref[0].astype(jnp.float32)
+        x = x.reshape(per, x.shape[0] // per, x.shape[1])
+        r = jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+        return x * r, r
+
+    scale = q_ref.shape[2] ** -0.5
+    (q_unit, q_by), (k, k_by) = unit(q_ref), unit(k_ref)
+    q = q_unit * scale
+    ql, kl = q.astype(lo), k.astype(lo)
+    return dict(q=q, k=k, q_unit=q_unit, q_by=q_by * scale, k_by=k_by, ql=ql,
+                kl=kl, gram=_mm(kl, kl, _BNT), qk=_mm(ql, kl, _BNT))
+
+
+def _gdn_local(keys, v, g_row, b_row, t=None):
+    """What is local to each of a value head's n chunks of the rule
+    (``ops/nn.py`` ``gated_delta_rule``), all n at once so that one chunk's
+    products fill the MXU while another's are on their way: ``keys`` from
+    :func:`_gdn_keys`, ``v`` [n, c, dv], ``g_row`` (the decay's running sum
+    inside the chunk) and ``b_row`` [n, 1, c] float32. Every exponent is of
+    a difference that is <= 0. The products take their operands in ``v``'s
+    type. ``t``: the inverses the forward saved, where the caller has
+    them."""
+    f32, lo = jnp.float32, v.dtype
+    q, k = keys["q"], keys["k"]
+    c = q.shape[1]
+    ii, jj = _chunk_iota(c)
+    g_col, b_col = _col_of(g_row), _col_of(b_row)
+    decay = jnp.exp(jnp.where(ii >= jj, g_col - g_row, -jnp.inf))
+    a = jnp.where(ii > jj, b_col * keys["gram"] * decay, 0.0)
+    if t is None:
+        t = _unit_lower_inverse(a)
+    e_g = jnp.exp(g_col)
+    g_last = jnp.sum(jnp.where(jj[:1] == c - 1, g_row, 0.0), axis=-1,
+                     keepdims=True)                     # [n, 1, 1]
+    e_out = jnp.exp(g_last - g_col)
+    rhs = jnp.concatenate([k * (b_col * e_g), v.astype(f32) * b_col], axis=2)
+    return dict(
+        b_col=b_col, decay=decay, a=a, t=t, e_g=e_g, e_out=e_out,
+        wu=_mm(t, rhs, _BNN).astype(lo),
+        p=(keys["qk"] * decay).astype(lo), q_in=(q * e_g).astype(lo),
+        k_out=(k * e_out).astype(lo), keep=jnp.exp(g_last),
+        # Mosaic broadcasts along sublanes or along lanes, not both
+        keep_row=jnp.exp(jnp.broadcast_to(g_last, g_last.shape[:2] +
+                                          (v.shape[2],))))
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, per,
+                    eps):
+    """One (batch, key head, ``per`` chunks) program for the value heads
+    the key head serves: q and k are read and normalised once for them, the
+    chunks of a row come one after another, and each value head's state
+    [dk, dv] stays in VMEM between them, zeroed at the row's first. Where
+    the gradient will follow, each chunk's incoming state and its inverse
+    ``(I + A)^-1`` are written out beside ``o`` (the inverse is the larger
+    part of a chunk's local work and a quarter of a state's bytes)."""
+    s_ref = rest[-1]
+    saved, t_ref = rest[:2] if len(rest) == 3 else (None, None)
+    heads, dk, dv = s_ref.shape
+    c = q_ref.shape[1] // per
+    lo = v_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    keys = _gdn_keys(q_ref, k_ref, per, eps, lo)
+    for h in range(heads):
+        lanes = slice(h * dv, (h + 1) * dv)
+        x = _gdn_local(keys, v_ref[0, :, lanes].reshape(per, c, dv),
+                       g_ref[0, h], b_ref[0, h])
+        if t_ref is not None:
+            t_ref[0, h] = x["t"]
+        s = s_ref[h]
+        for j in range(per):
+            if saved is not None:
+                saved[0, h, j] = s
+            sl = s.astype(lo)
+            wu = x["wu"][j]
+            v_new = (wu[:, dk:].astype(jnp.float32) -
+                     _mm(wu[:, :dk], sl, _NN)).astype(lo)
+            o_ref[0, j * c:(j + 1) * c, lanes] = (
+                _mm(x["q_in"][j], sl, _NN) +
+                _mm(x["p"][j], v_new, _NN)).astype(o_ref.dtype)
+            s = x["keep_row"][j] * s + _mm(x["k_out"][j], v_new, _TN)
+        s_ref[h] = s
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, t_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, per,
+                    eps):
+    """The forward's programs in reverse: the chunks' local arrays again
+    from their inputs and the saved inverses, then chunk before chunk with
+    the state that came into it and the gradient of the state [dk, dv] in
+    VMEM, zero after a row's last. The chain through the inverse needs no
+    second one: with ``T = (I + A)^-1`` and ``[W | U] = T R``, ``dR = T^T
+    [dW | dU]`` and ``dA = -tril(dR [W | U]^T)``. A key head's ``dq`` and
+    ``dk`` are summed here over the value heads it serves and taken back
+    through the unit lengths; the decay's and ``beta``'s gradients leave as
+    rows."""
+    f32 = jnp.float32
+    heads, dk, dv = ds_ref.shape
+    c = q_ref.shape[1] // per
+    lo = v_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    keys = _gdn_keys(q_ref, k_ref, per, eps, lo)
+    q, k = keys["q"], keys["k"]
+    rowsum = lambda y: jnp.sum(y, axis=-1, keepdims=True)
+    colsum = lambda y: jnp.sum(y, axis=-2, keepdims=True)
+    ii, jj = _chunk_iota(c)
+    d_q = d_k = 0.0
+    for h in range(heads):
+        lanes = slice(h * dv, (h + 1) * dv)
+        v = v_ref[0, :, lanes].reshape(per, c, dv)
+        x = _gdn_local(keys, v, g_ref[0, h], b_ref[0, h], t_ref[0, h])
+        # chunk before chunk: what the state's gradient passes through
+        ds = ds_ref[h]
+        d_qin, d_p, d_kout, d_wu, d_keep = ([None] * per for _ in range(5))
+        for j in reversed(range(per)):
+            do, s = do_ref[0, j * c:(j + 1) * c, lanes], s_ref[0, h, j]
+            w, u = x["wu"][j][:, :dk], x["wu"][j][:, dk:]
+            sl, dsl = s.astype(lo), ds.astype(lo)
+            v_new = (u.astype(f32) - _mm(w, sl, _NN)).astype(lo)
+            # o = q_in s + p v_new;  s' = keep s + k_out^T v_new
+            d_qin[j] = _mm(do, sl, _NT)
+            d_p[j] = _mm(do, v_new, _NT)
+            d_kout[j] = _mm(v_new, dsl, _NT)
+            d_vnew = _mm(x["p"][j], do, _TN) + _mm(x["k_out"][j], dsl, _NN)
+            d_keep[j] = jnp.sum(rowsum(ds * s), axis=0, keepdims=True)
+            # v_new = u - w s
+            d_vl = d_vnew.astype(lo)
+            d_wu[j] = jnp.concatenate([-_mm(d_vl, sl, _NT), d_vnew], axis=1)
+            ds = (x["keep_row"][j] * ds + _mm(x["q_in"][j], do, _TN) -
+                  _mm(w, d_vl, _TN))
+        ds_ref[h] = ds
+        d_qin, d_p, d_kout, d_wu, d_keep = (
+            jnp.stack(y) for y in (d_qin, d_p, d_kout, d_wu, d_keep))
+        # the rest is local to a chunk again, all of them at once:
+        # [w | u] = T rhs, T = (I + a)^-1
+        d_rhs = _mm(x["t"], d_wu, _BTN)
+        d_a = jnp.where(ii > jj, -_mm(d_rhs.astype(lo), x["wu"], _BNT), 0.0)
+        d_rw, d_ru = d_rhs[:, :, :dk], d_rhs[:, :, dk:]
+        b_col, e_g, e_out = x["b_col"], x["e_g"], x["e_out"]
+        from_w = rowsum(d_rw * k) * e_g
+        d_b = from_w + rowsum(d_ru * v.astype(f32))
+        d_g = from_w * b_col
+        # a = beta gram decay, p = qk decay: an entry's share of the
+        # decay's gradient goes to its row's gamma and from its column's
+        d_b = d_b + rowsum(d_a * keys["gram"] * x["decay"])
+        by_decay = d_a * x["a"] + d_p * (keys["qk"] * x["decay"])
+        d_g = d_g + rowsum(by_decay)
+        d_g_row = -colsum(by_decay)
+        d_gram = (d_a * b_col * x["decay"]).astype(lo)
+        d_qk = (d_p * x["decay"]).astype(lo)
+        d_q = d_q + _mm(d_qk, keys["kl"], _BNN) + d_qin * e_g
+        d_k = (d_k + d_rw * (b_col * e_g) + _mm(d_qk, keys["ql"], _BTN) +
+               _mm(d_gram, keys["kl"], _BNN) + _mm(d_gram, keys["kl"], _BTN) +
+               d_kout * e_out)
+        d_g = d_g + rowsum(d_qin * q) * e_g
+        from_out = rowsum(d_kout * k) * e_out
+        d_g = d_g - from_out
+        d_last = (jnp.sum(from_out, axis=1, keepdims=True) +
+                  d_keep * x["keep"])
+        d_g_row = d_g_row + jnp.where(jj[:1] == c - 1, d_last, 0.0)
+        d_v = d_ru * b_col
+        for j in range(per):
+            dv_ref[0, j * c:(j + 1) * c, lanes] = d_v[j].astype(dv_ref.dtype)
+            dg_ref[0, h, j] = _row_of(d_g[j]) + d_g_row[j]
+            db_ref[0, h, j] = _row_of(d_b[j])
+    # through the unit lengths: x^ = u scale with u = x by / scale, |u| = 1
+    for ref, u, by, d in ((dq_ref, keys["q_unit"], keys["q_by"], d_q),
+                          (dk_ref, k, keys["k_by"], d_k)):
+        d = (d - u * rowsum(u * d)) * by
+        ref[0] = d.reshape(per * c, dk).astype(ref.dtype)
+
+
+def _gdn_specs(hk, hv, dk, dv, n, chunk, per, interpret, back=False):
+    """The grid and the block specifications the two rule kernels share:
+    (batch, key head, blocks of ``per`` chunks) with the key head's value
+    heads side by side in a block, the chunks in reverse for the
+    gradient."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    group = hv // hk
+    last = n // per - 1
+    at = (lambda i: last - i) if back else (lambda i: i)
+    spec, _ = _kernel_specs(interpret)
+    rows = per * chunk
+    keys = spec((1, rows, dk), lambda b, h, i: (b, at(i), h))
+    values = spec((1, rows, group * dv), lambda b, h, i: (b, at(i), h))
+    by_chunk = lambda *tail: spec((1, group, per) + tail,
+                                  lambda b, h, i: (b, h, at(i), 0, 0))
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))}
+    return (keys, values, by_chunk(1, chunk), by_chunk(dk, dv),
+            by_chunk(chunk, chunk), params)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "chunk", "per", "eps",
+                                             "save", "interpret"))
+def _gdn_fwd_call(q, k, v, gamma, beta, heads, chunk, per, eps, save,
+                  interpret):
+    """``q``, ``k`` [b, t, hk * dk], ``v`` [b, t, hv * dv], ``gamma``,
+    ``beta`` [b, hv, t / chunk, 1, chunk] float32 -> ``o`` like ``v`` and,
+    with ``save``, the state before each chunk [b, hv, t / chunk, dk, dv]
+    and each chunk's inverse [b, hv, t / chunk, chunk, chunk], float32."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    hk, hv = heads
+    b, t = q.shape[:2]
+    dk, dv, n = q.shape[2] // hk, v.shape[2] // hv, t // chunk
+    keys, values, scalars, states, inverses, params = _gdn_specs(
+        hk, hv, dk, dv, n, chunk, per, interpret)
+    _, like = _kernel_specs(interpret)
+    out_shape, out_specs = [like(v.shape, v.dtype, v)], [values]
+    if save:
+        out_shape += [like((b, hv, n, dk, dv), jnp.float32, v),
+                      like((b, hv, n, chunk, chunk), jnp.float32, v)]
+        out_specs += [states, inverses]
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, per=per, eps=eps),
+        out_shape=out_shape, grid=(b, hk, n // per),
+        in_specs=[keys, keys, values, scalars, scalars],
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((hv // hk, dk, dv), jnp.float32)],
+        interpret=interpret, name="_gdn_fwd_call", **params,
+    )(q, k, v, gamma, beta)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "chunk", "per", "eps",
+                                             "interpret"))
+def _gdn_bwd_call(q, k, v, gamma, beta, states, inverses, do, heads, chunk,
+                  per, eps, interpret):
+    """-> ``dq``, ``dk`` like ``q``, ``dv`` like ``v``, ``dgamma``,
+    ``dbeta`` like ``gamma``, from the forward's arguments, its saved
+    states and inverses, and ``do``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    hk, hv = heads
+    b, t = q.shape[:2]
+    dk, dv, n = q.shape[2] // hk, v.shape[2] // hv, t // chunk
+    keys, values, scalars, states_at, inverses_at, params = _gdn_specs(
+        hk, hv, dk, dv, n, chunk, per, interpret, back=True)
+    _, like = _kernel_specs(interpret)
+    return pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, per=per, eps=eps),
+        out_shape=(like(q.shape, q.dtype, v), like(k.shape, k.dtype, v),
+                   like(v.shape, v.dtype, v),
+                   like(gamma.shape, jnp.float32, v),
+                   like(gamma.shape, jnp.float32, v)),
+        grid=(b, hk, n // per),
+        in_specs=[keys, keys, values, scalars, scalars, states_at,
+                  inverses_at, values],
+        out_specs=(keys, keys, values, scalars, scalars),
+        scratch_shapes=[pltpu.VMEM((hv // hk, dk, dv), jnp.float32)],
+        interpret=interpret, name="_gdn_bwd_call", **params,
+    )(q, k, v, gamma, beta, states, inverses, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _gdn_diff(q, k, v, gamma, beta, heads, chunk, per, eps, interpret):
+    return _gdn_fwd_call(q, k, v, gamma, beta, heads, chunk, per, eps, False,
+                         interpret)[0]
+
+
+def _gdn_diff_fwd(q, k, v, gamma, beta, heads, chunk, per, eps, interpret):
+    o, states, inverses = _gdn_fwd_call(q, k, v, gamma, beta, heads, chunk,
+                                        per, eps, True, interpret)
+    return o, (q, k, v, gamma, beta, states, inverses)
+
+
+def _gdn_diff_bwd(heads, chunk, per, eps, interpret, res, do):
+    return _gdn_bwd_call(*res, do, heads, chunk, per, eps, interpret)
+
+
+_gdn_diff.defvjp(_gdn_diff_fwd, _gdn_diff_bwd)
+
+
+def delta_rule_tiles(dk, dv, chunk):
+    """The shapes the rule's kernels take: heads of whole 128-lane tiles
+    and a chunk of 16 x 16 blocks that merge in pairs (a function of
+    shapes alone; the length is padded to whole chunks either way)."""
+    return (dk % 128 == 0 and dv % 128 == 0 and chunk % _SOLVE_BLOCK == 0 and
+            chunk & (chunk - 1) == 0)
+
+
+def delta_rule(query, key, value, g, beta, chunk=64, eps=1e-6,
+               interpret=False):
+    """The gated delta rule of ``ops/nn.py`` ``gated_delta_rule`` (its
+    arguments, its result) through the kernels ``_gdn_fwd_call`` and, for
+    the gradient, ``_gdn_bwd_call``; only the decay's running sum inside a
+    chunk and the padding to whole chunks are plain JAX round them."""
+    b, t, hk, dk = query.shape
+    hv, dv = value.shape[2:]
+    c = min(int(chunk), t)
+    n = -(-t // c)
+    # sixteen chunks' local arrays to a program where the heads allow: at
+    # Qwen3-Next's shape eight chunks a program ran the forward kernel in
+    # 4.1 ms where four took 4.7 (some 2 us a program beside its work)
+    per = next(p for p in (8, 4, 2, 1)
+               if n % p == 0 and (p * (hv // hk) <= 16 or p == 1))
+    pad = n * c - t
+
+    def flat(x):
+        """[b, t, heads, d] -> [b, whole chunks, heads * d]; a padded
+        token has q = k = v = 0, beta = 0 and g = 0."""
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+            b, n * c, -1)
+
+    def by_chunk(x):
+        """[b, t, hv] -> [b, hv, chunks, 1, c] float32: a chunk's
+        scalars are a row of lanes"""
+        x = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad), (0, 0)))
+        return jnp.swapaxes(x, 1, 2).reshape(b, hv, n, 1, c)
+
+    o = _gdn_diff(flat(query), flat(key), flat(value),
+                  jnp.cumsum(by_chunk(g), axis=-1), by_chunk(beta), (hk, hv),
+                  c, per, float(eps), bool(interpret))
+    return o.reshape(b, n * c, hv, dv)[:, :t]
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
                     block_k=512, interpret=None, force=False, window=0):
     """Blockwise attention, O(T) memory. q, k, v: (B, H, T, D) or

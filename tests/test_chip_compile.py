@@ -263,23 +263,37 @@ def test_smallthinker_step_compiles_and_fits_at_the_published_widths(
 
 def test_gated_delta_rule_compiles_at_the_published_widths(chip):
     """Qwen3-Next's rule at the benchmark's batch: 2 x 4,096 tokens, 16 key
-    heads and 32 value heads of 128 lanes, forward and gradient; the
-    backward pass holds one group of 8 chunks' arrays at a time (0.56 GB
-    planned; every chunk's at once planned 3.05 GB and did not fit the
-    step), never a state per token (17 GB)."""
-    from mxnet_tpu.ops.nn import gated_delta_rule
-
+    heads and 32 value heads of 128 lanes in bfloat16, forward and
+    gradient, through the kernels' own differentiable entry (the public
+    dispatch asks for the backend, which is the CPU here; its shape rule
+    is asserted beside). The two kernels come out as ``tpu_custom_call``s
+    under the names the device trace shows, and neither the scan's loop nor
+    ``triangular_solve`` is left: a shape that fell back to the XLA path
+    would fail here, where interpret mode stays green. The gradient holds
+    the state before each chunk and each chunk's inverse (268 + 67 MB)
+    where the scan held a group's arrays (0.56 GB planned), never a state
+    per token (17 GB)."""
     qk = chip((2, 4096, 16, 128), jnp.bfloat16)
     v = chip((2, 4096, 32, 128), jnp.bfloat16)
     gb = chip((2, 4096, 32), jnp.float32)
+    assert pk.delta_rule_tiles(128, 128, 64)
+    assert not pk.delta_rule_tiles(16, 128, 64)
 
-    def loss(q, k, v, g, beta):
-        return gated_delta_rule(q, k, v, g, beta).astype(jnp.float32).sum()
+    def rule(q, k, v, g, beta):
+        return pk.delta_rule(q, k, v, g, beta, interpret=False)
 
-    fwd = jax.jit(gated_delta_rule).lower(qk, qk, v, gb, gb).compile()
-    assert fwd.memory_analysis().temp_size_in_bytes < 2 ** 29
+    def loss(*args):
+        return rule(*args).astype(jnp.float32).sum()
+
+    fwd = jax.jit(rule).lower(qk, qk, v, gb, gb).compile()
     bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         qk, qk, v, gb, gb).compile()
+    assert _kernel_names(fwd.as_text()) == ["_gdn_fwd_call"]
+    assert sorted(_kernel_names(bwd.as_text())) == ["_gdn_bwd_call",
+                                                    "_gdn_fwd_call"]
+    for text in (fwd.as_text(), bwd.as_text()):
+        assert " while(" not in text and "triangular-solve" not in text
+    assert fwd.memory_analysis().temp_size_in_bytes < 2 ** 29
     assert bwd.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
