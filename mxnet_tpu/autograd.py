@@ -300,7 +300,24 @@ def _build_replay(nodes, grad_leaves, head_entries, held):
     return f
 
 
-def _compute_gradients(heads, head_grads, create_graph=False):
+def _release_written(grad_leaves):
+    """Let go of the gradient that a ``write`` leaf's backward replaces,
+    before that backward runs: the device can then give its memory to the
+    new one (at 16 bytes of state a parameter, the 2 of a bfloat16
+    gradient held twice is what a step would not fit in). The NDArray
+    itself stays and takes the new value; until then it holds nothing."""
+    released = []
+    for e in grad_leaves:
+        nd = e.nd_ref()
+        if nd._grad_req == "write" and nd.grad is not None:
+            old = nd.grad._data
+            released.append((nd.grad, old.shape, old.dtype))
+            nd.grad._data = None
+    return released
+
+
+def _compute_gradients(heads, head_grads, create_graph=False,
+                       release=False):
     from .ndarray.ndarray import NDArray
 
     head_entries = []
@@ -320,6 +337,7 @@ def _compute_gradients(heads, head_grads, create_graph=False):
     nodes, grad_leaves = _collect(head_entries)
     if not grad_leaves:
         raise MXNetError("no variables with grad attached found in the graph")
+    released = _release_written(grad_leaves) if release else ()
 
     held = {}
     if not create_graph:
@@ -346,7 +364,12 @@ def _compute_gradients(heads, head_grads, create_graph=False):
         with _tracing.span("autograd.pullback"):
             return vjp_fn(tuple(hg))
 
-    grads = gradfn(*leaf_vals)
+    try:
+        grads = gradfn(*leaf_vals)
+    except BaseException:
+        for g, shape, dtype in released:      # what was let go reads zero
+            g._data = jnp.zeros(shape, dtype)
+        raise
     grad_nds = [NDArray(g) for g in grads]
 
     if create_graph:
@@ -377,7 +400,8 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     from .ndarray.ndarray import NDArray
 
     with _tracing.span("autograd.backward"):
-        grad_leaves, grads = _compute_gradients(heads, head_grads)
+        grad_leaves, grads = _compute_gradients(heads, head_grads,
+                                                release=True)
         for e, g in zip(grad_leaves, grads):
             nd = e.nd_ref()
             if nd._grad_req == "add" and nd.grad is not None:

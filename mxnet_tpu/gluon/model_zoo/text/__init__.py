@@ -1,10 +1,11 @@
 """Language models built from a published configuration (a dict with the
 keys of the model's ``config.json``)."""
+from .laguna import Laguna, laguna
 from .lfm2_moe import LFM2MoE, lfm2_moe
 from .qwen3_next import Qwen3Next, qwen3_next
 from .smallthinker import SmallThinker, smallthinker
 
-_models = {"lfm2_moe": lfm2_moe, "qwen3_next": qwen3_next,
+_models = {"laguna": laguna, "lfm2_moe": lfm2_moe, "qwen3_next": qwen3_next,
            "smallthinker": smallthinker}
 
 

@@ -12,6 +12,7 @@ here (and is *verified* by the subgraph tests rather than hand-scheduled).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -850,21 +851,52 @@ def rms_norm(data, gamma, eps=1e-5, zero_centered=False):
     return (x * inv * (1.0 + w if zero_centered else w)).astype(data.dtype)
 
 
+def yarn_bounds(theta, dim, original, beta_fast, beta_slow):
+    """YaRN's ramp (Peng et al., arXiv:2309.00071) over the ``dim / 2``
+    frequencies of ``dim`` rotated lanes: ``(low, high)``, the first
+    frequency index below ``beta_fast`` turns over ``original`` positions
+    (rounded down) and the first below ``beta_slow`` (rounded up), with
+    ``c(r) = dim ln(original / (2 pi r)) / (2 ln theta)``."""
+    edge = lambda r: dim * math.log(original / (r * 2 * math.pi)) / \
+        (2 * math.log(theta))
+    return (max(math.floor(edge(beta_fast)), 0),
+            min(math.ceil(edge(beta_slow)), dim - 1))
+
+
 @register("RotaryEmbedding", aliases=("_contrib_RotaryEmbedding",))
 @_recomputed
-def rotary_embedding(data, theta=10000.0, offset=0, rotary_dim=0):
+def rotary_embedding(data, theta=10000.0, offset=0, rotary_dim=0,
+                     yarn_factor=0.0, yarn_original=0, beta_fast=32.0,
+                     beta_slow=1.0, attention_factor=1.0):
     """Rotary position encoding of ``data`` [batch, seq, heads, dim], the
     rotate-half form over the first ``rotary_dim`` lanes (all ``dim`` where
     0), the other lanes untouched: with ``d = rotary_dim``, ``a_t,i =
-    (offset + t) * theta^(-2i/d)``, ``out = x * cos(a) + rotate_half(x) *
-    sin(a)`` and ``rotate_half(x) = concat(-x[d/2:d], x[:d/2])``."""
+    (offset + t) * f_i``, ``f_i = theta^(-2i/d)``, ``out = x * cos(a) +
+    rotate_half(x) * sin(a)`` and ``rotate_half(x) = concat(-x[d/2:d],
+    x[:d/2])``.
+
+    ``yarn_factor`` > 0: YaRN's frequencies, ``f_i (1 - e_i) / yarn_factor
+    + f_i e_i`` with ``e_i = 1 - clip((i - low) / (high - low), 0, 1)``
+    and ``(low, high) = yarn_bounds(theta, d, yarn_original, beta_fast,
+    beta_slow)``. ``attention_factor`` multiplies cos and sin, so the
+    rotated lanes alone."""
     t, d = data.shape[1], rotary_dim or data.shape[3]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if yarn_factor:
+        low, high = yarn_bounds(theta, d, yarn_original, beta_fast,
+                                beta_slow)
+        ramp = (np.arange(d // 2, dtype=np.float32) - low) / \
+            max(high - low, 1e-3)
+        kept = jnp.asarray(1.0 - np.clip(ramp, 0.0, 1.0), jnp.float32)
+        inv_freq = inv_freq * (1.0 - kept) / yarn_factor + inv_freq * kept
     ang = (offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv_freq
     ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
     x = data[..., :d].astype(jnp.float32)
     half = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
-    out = (x * jnp.cos(ang) + half * jnp.sin(ang)).astype(data.dtype)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
+    out = (x * cos + half * sin).astype(data.dtype)
     if d == data.shape[3]:
         return out
     return jnp.concatenate([out, data[..., d:]], axis=-1)

@@ -195,19 +195,29 @@ def test_flash_compiles_with_a_window_at_128_lanes_over_groups_of_7(chip):
         _backward_is_a_kernel(q, kv, 128 ** -0.5, window)
 
 
-def test_smallthinker_step_compiles_and_fits_at_the_published_widths(
-        chip, monkeypatch):
-    """``benchmark/configs/smallthinker-21b-a3b.json`` through gluon's own
+def test_flash_compiles_with_a_512_key_window_over_groups_of_9_and_6(chip):
+    """Laguna-S-2.1's attention at the benchmark's batch: one sequence of
+    4,096 tokens, 128 lanes over 8 K/V heads, 72 query heads (groups of 9)
+    in the window layers with a window of 512 keys, one K block; 48
+    (groups of 6) in the full layers. Forward and backward kernels."""
+    kv = chip((8, 4096, 128), jnp.bfloat16)
+    for heads, window in ((72, 512), (48, 0)):
+        q = chip((heads, 4096, 128), jnp.bfloat16)
+        _is_kernel(pk._flash_call.lower(
+            q, kv, kv, causal=True, scale=128 ** -0.5, block_q=256,
+            block_k=512, interpret=False, window=window))
+        _backward_is_a_kernel(q, kv, 128 ** -0.5, window)
+
+
+def _step_plan(chip, monkeypatch, config, seq, kernels):
+    """``config`` (a file under ``benchmark/configs``) through gluon's own
     two programs of a recorded step (``_build_recorded``: the forward that
-    writes the residuals, and the pullback), one sequence of 8,192 tokens,
-    compiled for the described chip from shapes alone. The plan: 16 bytes
-    a parameter of state (bfloat16 weight and gradient, float32 master,
-    Adam's two) beside the larger of what the forward holds (its outputs
-    and temporaries) and what the backward holds (the residuals, the
-    logits' cotangent, its temporaries and the new gradients): 13.2 GB of
-    the chip's 16.9 (chip-free compile, PR 34; the chip's own peak, with
-    the loss block, Adam's program and the next launch waiting, is in
-    PERF.md)."""
+    writes the residuals, and the pullback), one sequence of ``seq``
+    tokens, compiled for the described chip from shapes alone; the forward
+    holds ``kernels`` flash kernels, one a layer. -> (parameters, what the
+    forward holds beside the state: its outputs and temporaries; what the
+    backward holds: the residuals, the logits' cotangent and its
+    temporaries; the new gradients it writes)."""
     import json
 
     import mxnet_tpu as mx  # noqa: F401
@@ -216,10 +226,9 @@ def test_smallthinker_step_compiles_and_fits_at_the_published_widths(
     from mxnet_tpu.ndarray import NDArray
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "smallthinker-21b-a3b.json")) as f:
+    with open(os.path.join(root, "benchmark", "configs", config)) as f:
         cfg = json.load(f)
-    net = gluon.model_zoo.get_model("smallthinker", config=cfg,
+    net = gluon.model_zoo.get_model(cfg["model"], config=cfg,
                                     held=cfg["held"], dtype=cfg["dtype"])
     net.hybridize()
     plist = sorted(net.collect_params().items())
@@ -228,15 +237,15 @@ def test_smallthinker_step_compiles_and_fits_at_the_published_widths(
     diff = tuple(p.grad_req != "null" for _, p in plist) + (False,)
     call, fwd, bwd = net._build_recorded(jfn, diff, True)
     pvals = tuple(chip(p.shape, p.dtype) for _, p in plist)
-    key, ids = chip((2,), jnp.uint32), chip((1, 8192), jnp.int32)
+    key, ids = chip((2,), jnp.uint32), chip((1, seq), jnp.int32)
     # the flash kernel's dispatch asks for the backend: steer it here,
     # not through an option of the program
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     forward = fwd.lower(pvals, key, ids).compile()
-    kernels = [line for line in forward.as_text().splitlines()
-               if "tpu_custom_call" in line and
-               line.split(" = ")[0].split("%")[-1].startswith("_flash_call")]
-    assert len(kernels) == 4                    # one a layer
+    found = [line for line in forward.as_text().splitlines()
+             if "tpu_custom_call" in line and
+             line.split(" = ")[0].split("%")[-1].startswith("_flash_call")]
+    assert len(found) == kernels
     outs, _, computed = jax.eval_shape(fwd, pvals, key, ids)
     on = lambda a: chip(a.shape, a.dtype)
     size = lambda arrays: sum(int(np.prod(a.shape)) * a.dtype.itemsize
@@ -252,9 +261,41 @@ def test_smallthinker_step_compiles_and_fits_at_the_published_widths(
     parameters = sum(int(np.prod(p.shape)) for _, p in plist
                      if p.grad_req != "null")
     assert parameters == cfg["parameters"]
-    beside = max(mf.output_size_in_bytes + mf.temp_size_in_bytes,
-                 size(computed) + size(outs) + mb.temp_size_in_bytes +
-                 mb.output_size_in_bytes)
+    return (parameters, mf.output_size_in_bytes + mf.temp_size_in_bytes,
+            size(computed) + size(outs) + mb.temp_size_in_bytes,
+            mb.output_size_in_bytes)
+
+
+def test_laguna_step_compiles_and_fits_at_the_published_widths(
+        chip, monkeypatch):
+    """``benchmark/configs/laguna-s-2.1.json``, one sequence of 4,096
+    tokens: 16 bytes a parameter of state (bfloat16 weight and gradient,
+    float32 master, Adam's two) beside the larger program's share, under
+    15.9 GB of the chip's 16.9. The backward's new gradients take the place
+    of the ones ``autograd.backward`` lets go before it runs, so they add
+    nothing to the state (held twice they were 16.7 GB: chip-free
+    compile)."""
+    parameters, forward, backward, grads = _step_plan(
+        chip, monkeypatch, "laguna-s-2.1.json", 4096, 5)
+    assert 16 * parameters == 12976275456
+    # bfloat16, every leaf (and the output tuple's few bytes)
+    assert 0 <= grads - 2 * parameters < 2 ** 20
+    plan = 16 * parameters + max(forward, backward)
+    assert plan < 15.9e9 < 16.9e9, (forward, backward)
+
+
+def test_smallthinker_step_compiles_and_fits_at_the_published_widths(
+        chip, monkeypatch):
+    """``benchmark/configs/smallthinker-21b-a3b.json``, one sequence of
+    8,192 tokens. The plan: 16 bytes a parameter of state (bfloat16 weight
+    and gradient, float32 master, Adam's two) beside the larger of what
+    the forward holds and what the backward holds with its new gradients:
+    13.2 GB of the chip's 16.9 (chip-free compile, PR 34; the chip's own
+    peak, with the loss block, Adam's program and the next launch
+    waiting, is in PERF.md)."""
+    parameters, forward, backward, grads = _step_plan(
+        chip, monkeypatch, "smallthinker-21b-a3b.json", 8192, 4)
+    beside = max(forward, backward + grads)
     plan = 16 * parameters + beside
     assert 16 * parameters == 8948654080
     assert beside < 5.5e9, beside          # 4.2 GB planned (PR 34)

@@ -155,6 +155,33 @@ def test_backward_kernel_matches_the_dense_gradient(group, d, causal, dtype,
                                    atol=tol * np.abs(w).max())
 
 
+@pytest.mark.parametrize("group", [9, 6])
+def test_a_window_one_k_block_wide_over_groups_of_9_and_6(group):
+    """Laguna-S-2.1's shape scaled down: 128 lanes, a window of one K
+    block, an eighth of the row (512 keys of 4,096 rows at the chip's
+    blocks), 72 or 48 query heads over 8 K/V heads. Rows from the window's
+    second block on start on a block that they see none of, which the
+    forward folds in as all-masked; the backward takes query blocks twice
+    the forward's. Forward and gradient against the dense oracle."""
+    rng = np.random.default_rng(group)
+    q, k, v = _qkv(rng, group, 1, 256, 256, 128, jnp.float32)
+    head = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=16, block_k=32,
+                               force=True, interpret=True, window=32)
+
+    oracle = lambda q, k, v: _oracle(q, k, v, True, 128 ** -0.5, 32)
+    np.testing.assert_allclose(flash(q, k, v), oracle(q, k, v), rtol=2e-5,
+                               atol=2e-5)
+    got = jax.grad(lambda *a: (flash(*a) * head).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (oracle(*a) * head).sum(), (0, 1, 2))(q, k, v)
+    for a, w in zip(got, want):
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(a), w, rtol=1e-4,
+                                   atol=1e-4 * np.abs(w).max())
+
+
 @pytest.mark.parametrize("causal,window", [(False, 0), (True, 0), (True, 1),
                                            (True, 16), (True, 45)])
 def test_forward_saves_the_log_sum_exp_of_the_masked_scores(causal, window):
