@@ -11,15 +11,20 @@ updates are collected during the trace and returned as extra outputs
 """
 from __future__ import annotations
 
+import itertools
+import math
 import re
 import threading
 from collections import OrderedDict
 
 import jax
+import jax.numpy as jnp
+from jax.experimental.layout import Layout
 
 from .. import autograd
 from .. import ndarray as nd_mod
 from .. import random as _random
+from .. import telemetry as _telemetry
 from .. import tracing as _tracing
 from ..base import MXNetError
 from ..ndarray import NDArray
@@ -405,7 +410,8 @@ class HybridBlock(Block):
             # one program gives the outputs and writes the residuals; the
             # pullback it returns waits on the tape for backward
             if diff not in recorded:
-                recorded[diff] = self._build_recorded(jfn, diff, training)
+                recorded[diff] = self._build_recorded(
+                    jfn, diff, training, (pvals, key, *in_datas))
             flat_out_data, aux_data, pullback = recorded[diff][0](
                 pvals, key, in_datas)
         else:
@@ -513,21 +519,41 @@ class HybridBlock(Block):
             "train" if training else "eval", part)
         return pure_fn
 
-    def _build_recorded(self, jfn, diff, training):
-        """``(call, fwd, bwd)`` for a recorded call that differentiates
-        the arguments ``diff`` marks (parameters, then inputs). ``fwd``
-        and ``bwd`` are its two programs: the forward that also writes
-        the residuals, and the pullback over them.
-        ``call(param_vals, key, in_datas)`` runs ``fwd`` and gives the
-        outputs, the aux updates and ``pullback(cts)``, which runs
-        ``bwd`` on the cotangents of the inexact outputs.
+    def _build_recorded(self, jfn, diff, training, args):
+        """``(call, (fwd, bwd), (forward, backward))`` for a recorded call
+        that differentiates the arguments ``diff`` marks (parameters, then
+        inputs), built for arguments like ``args`` (``(param_vals, key,
+        *in_datas)``: arrays or shapes). ``fwd`` and ``bwd`` are its two
+        programs: the forward that also writes the residuals, and the
+        pullback over them; ``forward`` and ``backward`` the same compiled
+        for ``args``. ``call(param_vals, key, in_datas)`` runs ``fwd`` and
+        gives the outputs, the aux updates and ``pullback(cts)``, which
+        runs ``bwd`` on the cotangents of the inexact outputs.
 
         ``fwd`` returns only those leaves of ``jax.vjp``'s pullback that
         it computed. A leaf that is one of its arguments or outputs (a
         weight kept for the input's gradient, an output its own
         derivative needs) would be copied to be returned a second time:
         the trace notes where each leaf comes from, and ``call`` puts
-        the tree together from the arrays it already holds."""
+        the tree together from the arrays it already holds.
+
+        A program returns its results in the default layout for their
+        shapes, so a residual made in another one (a convolution's
+        activation, made with its channels minor) is copied into it. The
+        copies that feed the compiled forward's result say which, and in
+        what layout each was made. Nothing but the pullback reads a
+        residual, so such a one may cross transposed instead: as the array
+        whose default layout holds the bytes as they were made, which the
+        transpose writes without a copy; the pullback transposes it back.
+        The pair with the transposed residuals is kept unless its forward,
+        compiled for ``args``, plans more bytes (outputs and temporaries)
+        than the plain one; only the kept pair's backward is compiled
+        (comparing the two backwards too would cost every process one more
+        compile), and the call compiles nothing more. A non-default layout
+        itself never crosses: jax reads an uncommitted array in one as if
+        it were in the default layout, and labels the outputs of a program
+        read back from the persistent compilation cache in the default
+        one."""
         pure_fn = jfn.__wrapped__
         vjp_tree = sources = None
 
@@ -554,8 +580,63 @@ class HybridBlock(Block):
         def backward(vjp_fn, cts):
             return vjp_fn(cts)
 
-        fwd = jax.jit(self._named(forward, training, "_fwd"))
-        bwd = jax.jit(self._named(backward, training, "_bwd"))
+        plain = jax.jit(self._named(forward, training, "_fwd"))
+        plain_back = jax.jit(self._named(backward, training, "_bwd"))
+        outs, aux, computed = jax.eval_shape(plain, *args)
+        shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        passed = (*args[0], *args[2:], *map(shape, outs))
+        cts = tuple(shape(o) for o in outs if autograd._inexact(o))
+
+        def transposed_pair(orders):
+            def transposed(param_vals, key, *in_datas):
+                outs, aux, computed = plain(param_vals, key, *in_datas)
+                return outs, aux, [c if o is None else jnp.transpose(c, o)
+                                   for c, o in zip(computed, orders)]
+
+            def transposed_back(vjp_fn, cts):
+                leaves, it = jax.tree_util.tree_leaves(vjp_fn), iter(orders)
+                for k, i in enumerate(sources):
+                    o = next(it) if i is None else None
+                    if o is not None:
+                        leaves[k] = jnp.transpose(leaves[k], _inverse(o))
+                return plain_back(vjp_tree.unflatten(leaves), cts)
+
+            return (jax.jit(self._named(transposed, training, "_fwd")),
+                    jax.jit(self._named(transposed_back, training, "_bwd")))
+
+        def compiled(fwd):
+            """``fwd`` compiled for ``args``, and the bytes it plans:
+            outputs and temporaries."""
+            c = fwd.lower(*args).compile()
+            m = c.memory_analysis()
+            return c, m.output_size_in_bytes + m.temp_size_in_bytes
+
+        devices = jax.tree_util.tree_leaves(args)[0].sharding.device_set
+        device = next(iter(devices))
+        fwd, bwd = plain, plain_back
+        forward_c, planned = compiled(plain)
+        orders = [None] * len(computed)
+        if len(devices) == 1:           # a sharded program's text is a shard's
+            orders = _axis_orders(forward_c.as_text(),
+                                  len(outs) + len(aux), computed, device)
+        if any(orders):
+            moved_fwd, moved_bwd = transposed_pair(orders)
+            moved_c, moved_planned = compiled(moved_fwd)
+            if moved_planned <= planned:
+                fwd, bwd, forward_c = moved_fwd, moved_bwd, moved_c
+            else:
+                orders = [None] * len(computed)
+        rs = iter(map(shape, jax.eval_shape(fwd, *args)[2]))
+        pullback = vjp_tree.unflatten(
+            next(rs) if i is None else passed[i] for i in sources)
+        # ``autograd.backward`` calls the pullback inside jax's
+        # transposition, which traces under the empty abstract mesh: traced
+        # in that context here, it is found there compiled
+        with jax.sharding.use_abstract_mesh(_NO_MESH):
+            pair = forward_c, bwd.lower(pullback, cts).compile()
+        moved = [c for c, o in zip(computed, orders) if o is not None]
+        _set_relaid(plain.__name__, len(moved), sum(
+            math.prod(c.shape) * c.dtype.itemsize for c in moved))
 
         def call(param_vals, key, in_datas):
             outs, aux, computed = fwd(param_vals, key, *in_datas)
@@ -564,7 +645,7 @@ class HybridBlock(Block):
                 next(computed) if i is None else passed[i] for i in sources)
             return outs, aux, lambda cts: bwd(vjp_fn, cts)
 
-        return call, fwd, bwd
+        return call, (fwd, bwd), pair
 
     def _build_cached(self, plist, in_spec, training):
         """Trace the whole subtree once into a jitted pure function."""
@@ -637,6 +718,79 @@ class HybridBlock(Block):
 
 _in_trace = threading.local()
 _param_override = threading.local()
+
+
+_NO_MESH = jax.sharding.AbstractMesh((), ())
+
+_relaid_metrics = _telemetry.metrics.lazy_metrics(lambda reg: (
+    reg.gauge("mx_residuals_relaid",
+              "residuals a recorded call's forward returns transposed, "
+              "made in another layout than the default for their shape",
+              labelnames=("program",)),
+    reg.gauge("mx_residual_bytes_relaid",
+              "bytes of those residuals", labelnames=("program",))))
+
+_HLO_LAYOUT = re.compile(r"\{([\d,]*)(?::T((?:\(\d+(?:,\d+)*\))+))?")
+
+
+def _axis_orders(hlo, first, residuals, device):
+    """For each of the ``residuals`` (shapes), the results from ``first``
+    on of the program compiled for ``device`` (``hlo``, its text): ``None``
+    where the program returns it as it made it, else the order of axes in
+    which it crosses without a copy (``jnp.transpose(r, order)``'s default
+    layout holds the bytes as they were made), or ``None`` where no order
+    does."""
+    orders = [None] * len(residuals)
+    lines = hlo[hlo.index("\nENTRY "):].splitlines()[1:]
+    made, root = {}, ""
+    for line in lines[:next(i for i, l in enumerate(lines)
+                            if l.startswith("}"))]:
+        name, _, rest = line.strip().partition(" = ")
+        if name.startswith("ROOT "):
+            root = rest
+        made[name.split()[-1]] = rest
+    if " tuple(" not in root:
+        return orders
+    results = re.sub(r"/\*.*?\*/", "", root.split(" tuple(", 1)[1]).split(
+        ")")[0].split(", ")
+    for k, (r, name) in enumerate(zip(residuals, results[first:])):
+        op = made.get(name, "").partition(" ")[2]
+        if not op.startswith("copy(") or jax.dtypes.issubdtype(
+                r.dtype, jax.dtypes.extended):
+            continue
+        source = made.get(op[5:].split(")")[0].split(",")[0], "")
+        layout = _HLO_LAYOUT.search(source.partition(" ")[0])
+        if layout is None or not layout.group(1):
+            continue
+        order = tuple(int(a) for a in layout.group(1).split(","))[::-1]
+        tiles = tuple(tuple(int(n) for n in t.split(","))
+                      for t in re.findall(r"\(([\d,]+)\)",
+                                          layout.group(2) or ""))
+        if len(order) != len(r.shape):
+            continue
+        for q in (order, *itertools.permutations(range(len(order)))):
+            if q == tuple(range(len(q))):
+                continue
+            default = Layout.from_pjrt_layout(device.client.get_default_layout(
+                r.dtype, tuple(r.shape[a] for a in q), device))
+            if (tuple(q[a] for a in default.major_to_minor) == order
+                    and tuple(default.tiling) == tiles):
+                orders[k] = q
+                break
+    return orders
+
+
+def _inverse(order):
+    return tuple(sorted(range(len(order)), key=order.__getitem__))
+
+
+def _set_relaid(program, count, nbytes):
+    """The forward ``program``'s gauges, once a compiled pair: how many of
+    its residuals cross transposed, and their bytes."""
+    if _telemetry.enabled():
+        gauges = _relaid_metrics()
+        gauges[0].labels(program=program).set(count)
+        gauges[1].labels(program=program).set(nbytes)
 
 
 def _in_trace_flag():

@@ -14,6 +14,7 @@ only once a test has started: the topology is described inside a
 fixture, never at import, and every compile happens in this process.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -209,14 +210,30 @@ def test_flash_compiles_with_a_512_key_window_over_groups_of_9_and_6(chip):
         _backward_is_a_kernel(q, kv, 128 ** -0.5, window)
 
 
+def _recorded_pair(chip, net, plist, in_spec, ins):
+    """``net``'s two programs of a recorded training call
+    (``_build_recorded``: the forward that writes the residuals, and the
+    pullback over them, as the call keeps them), compiled for the described
+    chip from shapes alone; the inputs ``ins``
+    are not differentiated. -> (compiled forward, compiled backward, the
+    forward's outputs, the residuals it computed)."""
+    jfn, _, _ = net._build_cached(plist, in_spec, True)
+    diff = tuple(p.grad_req != "null" for _, p in plist) + (False,) * len(ins)
+    args = (tuple(chip(p.shape, p.dtype) for _, p in plist),
+            chip((2,), jnp.uint32), *ins)
+    _, (fwd, _), (forward, backward) = net._build_recorded(
+        jfn, diff, True, args)
+    outs, _, computed = jax.eval_shape(fwd, *args)
+    return forward, backward, outs, computed
+
+
 def _step_plan(chip, monkeypatch, config, seq, kernels):
     """``config`` (a file under ``benchmark/configs``) through gluon's own
-    two programs of a recorded step (``_build_recorded``: the forward that
-    writes the residuals, and the pullback), one sequence of ``seq``
-    tokens, compiled for the described chip from shapes alone; the forward
-    holds ``kernels`` flash kernels, one a layer. -> (parameters, what the
-    forward holds beside the state: its outputs and temporaries; what the
-    backward holds: the residuals, the logits' cotangent and its
+    two programs of a recorded step (``_recorded_pair``), one sequence of
+    ``seq`` tokens, compiled for the described chip from shapes alone; the
+    forward holds ``kernels`` flash kernels, one a layer. -> (parameters,
+    what the forward holds beside the state: its outputs and temporaries;
+    what the backward holds: the residuals, the logits' cotangent and its
     temporaries; the new gradients it writes)."""
     import json
 
@@ -233,30 +250,17 @@ def _step_plan(chip, monkeypatch, config, seq, kernels):
     net.hybridize()
     plist = sorted(net.collect_params().items())
     _, in_spec = blk._flatten([NDArray(jnp.zeros((1, 1), jnp.int32))])
-    jfn, _, _ = net._build_cached(plist, in_spec, True)
-    diff = tuple(p.grad_req != "null" for _, p in plist) + (False,)
-    call, fwd, bwd = net._build_recorded(jfn, diff, True)
-    pvals = tuple(chip(p.shape, p.dtype) for _, p in plist)
-    key, ids = chip((2,), jnp.uint32), chip((1, seq), jnp.int32)
     # the flash kernel's dispatch asks for the backend: steer it here,
     # not through an option of the program
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    forward = fwd.lower(pvals, key, ids).compile()
+    forward, backward, outs, computed = _recorded_pair(
+        chip, net, plist, in_spec, (chip((1, seq), jnp.int32),))
     found = [line for line in forward.as_text().splitlines()
              if "tpu_custom_call" in line and
              line.split(" = ")[0].split("%")[-1].startswith("_flash_call")]
     assert len(found) == kernels
-    outs, _, computed = jax.eval_shape(fwd, pvals, key, ids)
-    on = lambda a: chip(a.shape, a.dtype)
     size = lambda arrays: sum(int(np.prod(a.shape)) * a.dtype.itemsize
                               for a in arrays)
-    # the pullback's tree, put together as ``call`` does
-    cells = dict(zip(call.__code__.co_freevars,
-                     (c.cell_contents for c in call.__closure__)))
-    passed, made = (*pvals, ids, *map(on, outs)), iter(map(on, computed))
-    pullback = cells["vjp_tree"].unflatten(
-        next(made) if i is None else passed[i] for i in cells["sources"])
-    backward = bwd.lower(pullback, tuple(map(on, outs))).compile()
     mf, mb = forward.memory_analysis(), backward.memory_analysis()
     parameters = sum(int(np.prod(p.shape)) for _, p in plist
                      if p.grad_req != "null")
@@ -336,6 +340,84 @@ def test_gated_delta_rule_compiles_at_the_published_widths(chip):
         assert " while(" not in text and "triangular-solve" not in text
     assert fwd.memory_analysis().temp_size_in_bytes < 2 ** 29
     assert bwd.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _entry(text):
+    """The instructions of the entry computation of compiled HLO ``text``:
+    ``{name: the rest of its line}``, and the ROOT's."""
+    lines = text[text.index("\nENTRY "):].splitlines()[1:]
+    lines = lines[:next(i for i, l in enumerate(lines) if l.startswith("}"))]
+    made, root = {}, ""
+    for line in lines:
+        name, _, rest = line.strip().partition(" = ")
+        if name.startswith("ROOT "):
+            root = rest
+        made[name.split()[-1].lstrip("%")] = rest
+    return made, root
+
+
+def _returned_copies(text):
+    """Bytes of each ``copy`` whose result the entry's ROOT tuple of
+    compiled HLO ``text`` returns."""
+    made, root = _entry(text)
+    operands = re.sub(r"/\*.*?\*/", "", root.split("tuple(", 1)[-1])
+    copies = []
+    for name in operands.split(")")[0].split(","):
+        rest = made.get(name.strip().lstrip("%"), "")
+        if " copy(" in rest:
+            dtype, dims = rest.split("{")[0].rstrip("]").split("[")
+            copies.append(_BYTES[dtype] * int(np.prod(
+                [int(d) for d in dims.split(",") if d])))
+    return copies
+
+
+def _estimated_cycles(text):
+    """The compiler's estimate of the entry computation's cycles."""
+    made, _ = _entry(text)
+    return sum(int(c) for rest in made.values()
+               for c in re.findall(r'"estimated_cycles":"(\d+)"', rest))
+
+
+def test_resnet50_residuals_cross_in_the_layouts_the_forward_makes(chip):
+    """ResNet-50 v1 at the benchmark's batch of 64, through gluon's two
+    programs of a recorded step (``_recorded_pair``). With every residual
+    returned in its default layout, 149 copies (4.64 GiB) relay them
+    before the forward's result, 29.9 M of its 110.4 M estimated cycles,
+    and its temporaries plan 1.85 GiB. The pair kept returns 39 of them
+    (3.88 GiB) transposed, in the default layout of the axis order they
+    were made in: 0.77 GiB of copies are left, the four 64 x 64 x 112 x
+    112 activations, whose 64 channels the convolution pads to 128 as no
+    default layout does; the forward's estimate falls to 76.6 M cycles and
+    its temporaries to 1.18 GiB, its outputs stay 10.71 GiB."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import block as blk
+    from mxnet_tpu.ndarray import NDArray
+
+    net = gluon.model_zoo.vision.get_model("resnet50_v1", classes=1000)
+    net.initialize(mx.init.Xavier())
+    blk.infer_shapes(net, (64, 3, 224, 224))
+    net.hybridize()
+    plist = sorted(net.collect_params().items())
+    _, in_spec = blk._flatten([NDArray(jnp.zeros((1, 1), jnp.float32))])
+    forward, _, _, computed = _recorded_pair(
+        chip, net, plist, in_spec, (chip((64, 3, 224, 224), jnp.float32),))
+    assert len(computed) == 523
+    gauges = mx.telemetry.snapshot()["metrics"]
+    relaid = [{s["labels"]["program"]: s["value"]
+               for s in gauges[name]["series"]}["mx_resnetv1_train_fwd"]
+              for name in ("mx_residuals_relaid", "mx_residual_bytes_relaid")]
+    assert relaid == [39, 4161798144], relaid
+    text = forward.as_text()
+    returned = _returned_copies(text)
+    assert sum(returned) < 2 ** 30, (len(returned), sum(returned))
+    assert _estimated_cycles(text) < 90e6
+    mf = forward.memory_analysis()
+    assert mf.temp_size_in_bytes < 1.5 * 2 ** 30
+    assert mf.output_size_in_bytes + mf.temp_size_in_bytes < 12 * 2 ** 30
 
 
 # h16 x d128 is the smoke's decoder; h4 x d16 is GenerativeDecoder's

@@ -545,6 +545,13 @@ _RECORDED = {
                      lambda net, x: (net(x) ** 2).sum(), False),
     "integer_output": (_WithIndex,
                        lambda net, x: (net(x)[0] ** 2).sum(), True),
+    "conv_batchnorm": (_seq(lambda: nn.Conv2D(4, 3, padding=1, in_channels=2),
+                            lambda: nn.BatchNorm(in_channels=4),
+                            lambda: nn.Activation("relu"),
+                            lambda: nn.Dense(3, in_units=60)),
+                       lambda net, x: (net(x.reshape((1, 2, 3, 5)))
+                                       ** 2).sum(),
+                       True),
 }
 
 
@@ -595,7 +602,7 @@ def test_recorded_hybrid_call_equals_the_replay(case, x_wants_grad):
     for jfn, _, _, recorded in net._cached_jit.values():
         assert jfn._cache_size() == 0
         assert all(fwd._cache_size() == 1 and bwd._cache_size() == 1
-                   for _, fwd, bwd in recorded.values())
+                   for _, (fwd, bwd), _ in recorded.values())
 
 
 def test_recorded_call_differentiates_only_what_is_on_the_tape():
@@ -659,3 +666,138 @@ def test_dropout_mask_is_the_same_forward_and_backward():
     assert 0.2 < kept.mean() < 0.8
     np.testing.assert_array_equal(x.grad.asnumpy() != 0, kept)
     np.testing.assert_allclose(x.grad.asnumpy(), y.asnumpy())
+
+
+def _conv_net(grad_req="write"):
+    np.random.seed(5)
+    mx.random.seed(5)
+    build, heads_of, _ = _RECORDED["conv_batchnorm"]
+    net = build()
+    net.initialize()
+    net.hybridize()
+    for p in net.collect_params().values():
+        if p.grad_req != "null":
+            p.grad_req = grad_req
+    return net, heads_of
+
+
+def _grads(net):
+    return [p.grad().asnumpy() for p in net.collect_params().values()
+            if p.grad_req != "null"]
+
+
+def test_recorded_conv_block_backward_twice_with_retain_graph():
+    """A second ``backward`` runs the kept pullback again, over the
+    residuals as the forward returned them (transposed, those the
+    convolution made in another layout), and the gradients it adds are
+    the replay's."""
+    net, heads_of = _conv_net("add")
+    with autograd.record():
+        loss = heads_of(net, nd.array(_X))
+    loss.backward(retain_graph=True)
+    once = _grads(net)
+    assert all(np.abs(g).sum() > 0 for g in once)
+    loss.backward()
+    for g, g1 in zip(_grads(net), once):
+        np.testing.assert_allclose(g, 2 * g1, rtol=1e-6, atol=1e-7)
+    want, _, _ = _recorded_step(*_RECORDED["conv_batchnorm"], True, False)
+    for g1, w in zip(once, want[1:]):
+        np.testing.assert_allclose(g1, w, rtol=1e-6, atol=1e-6)
+
+
+def test_recorded_conv_block_with_create_graph_takes_the_replay():
+    """``create_graph=True`` cannot use the kept pullback (it is closed
+    over concrete residuals): the node's plain program is replayed, and
+    gives the gradients the pullback gives."""
+    net, heads_of = _conv_net()
+    x = nd.array(_X)
+    x.attach_grad()
+    with autograd.record():
+        loss = heads_of(net, x)
+    kept, = [n.pullback for n in autograd._st().tape
+             if n.op.name.startswith("cachedop_")]
+    assert kept is not None
+    (_, _, _, recorded), = net._cached_jit.values()
+    (_, (_, bwd), _), = recorded.values()
+    gx, = autograd.grad([loss], [x], create_graph=True, retain_graph=True)
+    # the replay traced the plain program; the pullback never ran
+    assert bwd._cache_size() == 0
+    loss.backward()
+    assert bwd._cache_size() == 1
+    np.testing.assert_allclose(gx.asnumpy(), x.grad.asnumpy(),
+                               rtol=1e-6, atol=1e-6)
+
+
+def _relaid(program):
+    series = mx.telemetry.snapshot()["metrics"]["mx_residuals_relaid"]
+    return {s["labels"]["program"]: s["value"]
+            for s in series["series"]}[program]
+
+
+@pytest.mark.parametrize("case", ["batchnorm", "conv_batchnorm"])
+def test_relaid_residuals_counter_reads_what_crosses_transposed(case):
+    """The gauge a recorded call's compiled pair sets counts the residuals
+    its forward returns transposed, because its operations made them in
+    another layout than the default for their shape. On the CPU the dense
+    block's forward makes every residual in the default layout, so it
+    reads 0; the convolution's makes some otherwise. Whatever crosses,
+    crosses in the default layout for its shape."""
+    import jax
+    from jax.experimental.layout import Layout
+
+    np.random.seed(5)
+    mx.random.seed(5)
+    build, heads_of, _ = _RECORDED[case]
+    net = build()
+    net.initialize()
+    net.hybridize()
+    with autograd.record():
+        heads_of(net, nd.array(_X)).backward()
+    (_, _, _, recorded), = net._cached_jit.values()
+    (_, (fwd, _), _), = recorded.values()
+    x = nd.array(_X)
+    if case == "conv_batchnorm":
+        x = x.reshape((1, 2, 3, 5))
+    _, _, computed = fwd(tuple(p.data()._data for _, p in net._cached_plist),
+                         mx.random.next_key(), x._data)
+    dev = jax.devices()[0]
+    assert all(c.format.layout == Layout.from_pjrt_layout(
+        dev.client.get_default_layout(c.dtype, c.shape, dev))
+        for c in computed)
+    if case == "batchnorm":
+        assert _relaid(fwd.__name__) == 0
+    else:
+        assert _relaid(fwd.__name__) > 0
+
+
+def test_recorded_conv_forward_plans_no_more_bytes_than_the_plain_one(
+        monkeypatch):
+    """The forward a recorded call keeps, with some residuals crossing
+    transposed, plans no more bytes (outputs and temporaries) than the
+    plain forward that returns every residual in its default layout; the
+    plain pair, kept where nothing crosses transposed, computes the same
+    gradients."""
+    from mxnet_tpu.gluon import block as blk
+
+    net, heads_of = _conv_net()
+    with autograd.record():
+        heads_of(net, nd.array(_X)).backward()
+    (jfn, _, _, recorded), = net._cached_jit.values()
+    (diff, (_, (fwd, _), (kept, _))), = recorded.items()
+    assert _relaid(fwd.__name__) > 0
+    want = _grads(net)
+    args = (tuple(p.data()._data for _, p in net._cached_plist),
+            mx.random.next_key(), nd.array(_X).reshape((1, 2, 3, 5))._data)
+    monkeypatch.setattr(blk, "_axis_orders",
+                        lambda hlo, first, residuals, device:
+                        [None] * len(residuals))
+    _, _, (plain, _) = net._build_recorded(jfn, diff, True, args)
+    assert _relaid(fwd.__name__) == 0
+    kf, pf = (c.memory_analysis() for c in (kept, plain))
+    assert (kf.output_size_in_bytes + kf.temp_size_in_bytes
+            <= pf.output_size_in_bytes + pf.temp_size_in_bytes)
+    net._cached_jit.clear()
+    with autograd.record():
+        heads_of(net, nd.array(_X)).backward()
+    for g, w in zip(_grads(net), want):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
