@@ -134,11 +134,13 @@ class GatedAttention(HybridBlock):
 class SharedSparseExperts(HybridBlock):
     """``routed + shared``: ``lfm2_moe.SparseExperts`` over this share's
     experts (the router's ``scale`` multiplies their weights) beside the
-    ungated shared expert, which is whole on every chip."""
+    ungated shared expert, which is whole on every chip; its operations
+    carry the named scope ``scope``."""
 
     def __init__(self, hidden, width, shared_width, held, router, dtype,
-                 prefix=None, params=None):
+                 scope="laguna.shared_expert", prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        self._shared_scope = scope
         with self.name_scope():
             self.routed = SparseExperts(hidden, width, held, router, dtype,
                                         prefix="routed_")
@@ -148,14 +150,18 @@ class SharedSparseExperts(HybridBlock):
 
     def hybrid_forward(self, F, n):
         out, counts = self.routed(n)
-        with jax.named_scope("laguna.shared_expert"):
+        with jax.named_scope(self._shared_scope):
             shared = recompute(self.shared, n)
         return out + shared, counts
 
 
 class DecoderLayer(HybridBlock):
-    def __init__(self, attn, ff, sparse, hidden, eps, dtype, prefix=None,
-                 params=None):
+    """``h = x + Attn(N(x))``, ``y = h + FF(N(h))``; attention runs under the
+    named scope ``attn_scope`` (by default ``laguna.attn.<kind>``), a dense
+    ``FF`` under ``mlp_scope``."""
+
+    def __init__(self, attn, ff, sparse, hidden, eps, dtype, attn_scope=None,
+                 mlp_scope="laguna.dense_mlp", prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         norm = lambda name: RMSNorm(eps, in_channels=hidden, dtype=dtype,
                                     prefix=name)
@@ -165,8 +171,9 @@ class DecoderLayer(HybridBlock):
             self.post_norm = norm("post_norm_")
             self.ff = ff()
         self.sparse = sparse
-        self._scope = "laguna.attn." + (
+        self._scope = attn_scope or "laguna.attn." + (
             "window" if self.attn._window else "full")
+        self._mlp_scope = mlp_scope
 
     def hybrid_forward(self, F, x):
         # attention and the dense MLP keep their input alone and run again
@@ -177,11 +184,59 @@ class DecoderLayer(HybridBlock):
         if self.sparse:
             out, counts = self.ff(self.post_norm(h))
             return h + out, counts
-        with jax.named_scope("laguna.dense_mlp"):
+        with jax.named_scope(self._mlp_scope):
             return h + recompute(lambda v: self.ff(self.post_norm(v)), h)
 
 
-class Laguna(HybridBlock):
+class Decoder(HybridBlock):
+    """``net(ids)`` -> float32 logits [batch, seq, vocab]: the embedding,
+    the layers, the final norm and the untied head (under the named scope
+    ``HEAD_SCOPE``); the sparse layers' visits are summed into the counter
+    ``net.expert_tokens``. A model's ``__init__`` reads its configuration
+    and hands :meth:`_build` one ``DecoderLayer`` maker a layer."""
+
+    HEAD_SCOPE = "laguna.head"
+
+    def _build(self, vocab, hidden, eps, experts, layers, dtype):
+        self._vocab, self._hidden, self._eps = vocab, hidden, eps
+        with self.name_scope():
+            self.embed = self.params.get("embed_weight", dtype=dtype,
+                                         shape=(vocab, hidden))
+            self.norm = self.params.get("norm_gamma", shape=(hidden,),
+                                        dtype=dtype, init="ones")
+            self.head = self.params.get("head_weight", dtype=dtype,
+                                        shape=(vocab, hidden))
+            self.layers = []
+            for i, make in enumerate(layers):
+                layer = make(prefix="layer%d_" % i)
+                self.register_child(layer)
+                self.layers.append(layer)
+            # visits to each expert of each sparse layer, summed on the
+            # device over the forward passes so far (lfm2_moe's counter)
+            n_sparse = sum(layer.sparse for layer in self.layers)
+            self.expert_tokens = self.params.get(
+                "expert_tokens", shape=(n_sparse, experts), dtype="int32",
+                init="zeros", differentiable=False) if n_sparse else None
+
+    def hybrid_forward(self, F, ids, embed, norm, head, expert_tokens=None):
+        x = F.Embedding(ids, embed, input_dim=self._vocab,
+                        output_dim=self._hidden)
+        visits = []
+        for layer in self.layers:
+            if layer.sparse:
+                x, counts = layer(x)
+                visits.append(counts)
+            else:
+                x = layer(x)
+        if visits:
+            defer_aux_update(self.expert_tokens,
+                             expert_tokens + F.stack(*visits, axis=0))
+        with jax.named_scope(self.HEAD_SCOPE):
+            return _dense(F, F.RMSNorm(x, norm, eps=self._eps), head,
+                          out_dtype="float32")
+
+
+class Laguna(Decoder):
     """``net(ids)`` -> float32 logits [batch, seq, vocab_size]."""
 
     def __init__(self, config, held=None, dtype="bfloat16", prefix=None,
@@ -229,48 +284,16 @@ class Laguna(HybridBlock):
                 hidden, cfg["moe_intermediate_size"],
                 cfg["shared_expert_intermediate_size"], held, router, dtype,
                 prefix="moe_")}
-        self._vocab, self._hidden, self._eps = cfg["vocab_size"], hidden, eps
+        for i in range(depth):
+            if kinds[i] not in windows or mlps[i] not in ffs:
+                raise MXNetError(f"laguna: layer {i} of kind "
+                                 f"{kinds[i]!r} / {mlps[i]!r}")
         self.held = held
-        with self.name_scope():
-            self.embed = self.params.get("embed_weight", dtype=dtype,
-                                         shape=(self._vocab, hidden))
-            self.norm = self.params.get("norm_gamma", shape=(hidden,),
-                                        dtype=dtype, init="ones")
-            self.head = self.params.get("head_weight", dtype=dtype,
-                                        shape=(self._vocab, hidden))
-            self.layers = []
-            for i in range(depth):
-                if kinds[i] not in windows or mlps[i] not in ffs:
-                    raise MXNetError(f"laguna: layer {i} of kind "
-                                     f"{kinds[i]!r} / {mlps[i]!r}")
-                layer = DecoderLayer(attn(i), ffs[mlps[i]],
-                                     mlps[i] == "sparse", hidden, eps, dtype,
-                                     prefix="layer%d_" % i)
-                self.register_child(layer)
-                self.layers.append(layer)
-            # visits to each expert of each sparse layer, summed on the
-            # device over the forward passes so far (lfm2_moe's counter)
-            n_sparse = sum(layer.sparse for layer in self.layers)
-            self.expert_tokens = self.params.get(
-                "expert_tokens", shape=(n_sparse, experts), dtype="int32",
-                init="zeros", differentiable=False) if n_sparse else None
-
-    def hybrid_forward(self, F, ids, embed, norm, head, expert_tokens=None):
-        x = F.Embedding(ids, embed, input_dim=self._vocab,
-                        output_dim=self._hidden)
-        visits = []
-        for layer in self.layers:
-            if layer.sparse:
-                x, counts = layer(x)
-                visits.append(counts)
-            else:
-                x = layer(x)
-        if visits:
-            defer_aux_update(self.expert_tokens,
-                             expert_tokens + F.stack(*visits, axis=0))
-        with jax.named_scope("laguna.head"):
-            return _dense(F, F.RMSNorm(x, norm, eps=self._eps), head,
-                          out_dtype="float32")
+        self._build(cfg["vocab_size"], hidden, eps, experts, [
+            lambda prefix, i=i: DecoderLayer(
+                attn(i), ffs[mlps[i]], mlps[i] == "sparse", hidden, eps,
+                dtype, prefix=prefix)
+            for i in range(depth)], dtype)
 
 
 def laguna(config, held=None, dtype="bfloat16", **kwargs):

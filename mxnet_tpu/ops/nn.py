@@ -867,7 +867,7 @@ def yarn_bounds(theta, dim, original, beta_fast, beta_slow):
 @_recomputed
 def rotary_embedding(data, theta=10000.0, offset=0, rotary_dim=0,
                      yarn_factor=0.0, yarn_original=0, beta_fast=32.0,
-                     beta_slow=1.0, attention_factor=1.0):
+                     beta_slow=1.0, attention_factor=1.0, interleaved=False):
     """Rotary position encoding of ``data`` [batch, seq, heads, dim], the
     rotate-half form over the first ``rotary_dim`` lanes (all ``dim`` where
     0), the other lanes untouched: with ``d = rotary_dim``, ``a_t,i =
@@ -879,7 +879,14 @@ def rotary_embedding(data, theta=10000.0, offset=0, rotary_dim=0,
     + f_i e_i`` with ``e_i = 1 - clip((i - low) / (high - low), 0, 1)``
     and ``(low, high) = yarn_bounds(theta, d, yarn_original, beta_fast,
     beta_slow)``. ``attention_factor`` multiplies cos and sin, so the
-    rotated lanes alone."""
+    rotated lanes alone.
+
+    ``interleaved``: the rotated lanes pair as ``(2i, 2i + 1)``, as
+    DeepSeek-V3's published weights have them; they are first
+    de-interleaved, ``x <- concat(x[0::2], x[1::2])`` (the modeling code's
+    ``x.view(d/2, 2).T``), and the result stays in that order, as the
+    modeling code leaves it: q and k alike, so their products are the
+    pairs' own."""
     t, d = data.shape[1], rotary_dim or data.shape[3]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     if yarn_factor:
@@ -892,6 +899,8 @@ def rotary_embedding(data, theta=10000.0, offset=0, rotary_dim=0,
     ang = (offset + jnp.arange(t, dtype=jnp.float32))[:, None] * inv_freq
     ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
     x = data[..., :d].astype(jnp.float32)
+    if interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     half = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if attention_factor != 1.0:
@@ -939,10 +948,11 @@ def causal_conv1d(data, weight, activation=None):
 
 @register("GQAttention", aliases=("_contrib_GQAttention",))
 def gq_attention(query, key, value, causal=True, scale=None, window=0):
-    """Softmax attention with grouped-query heads: ``query`` [batch, seq,
-    heads, dim], ``key`` / ``value`` [batch, seq, kv_heads, dim], each K/V
-    head serving ``heads // kv_heads`` consecutive query heads; softmax in
-    float32. ``window`` > 0 (causal only; 0 = none): query t sees keys
+    """Softmax attention with grouped-query heads: ``query`` / ``key``
+    [batch, seq, heads / kv_heads, dim], ``value`` [batch, seq, kv_heads,
+    value dim] (the output's; latent attention's differs from ``dim``),
+    each K/V head serving ``heads // kv_heads`` consecutive query heads;
+    softmax in float32, ``scale`` by default ``dim ** -0.5``. ``window`` > 0 (causal only; 0 = none): query t sees keys
     t - window + 1 ... t. Long sequences take the flash kernel
     (``pallas_kernels.flash_attention`` owns the dispatch), which reads
     the shared K/V head through its index map; its gradient is a kernel

@@ -23,7 +23,7 @@ NEG_INF = -1e30
 
 def _dense_reference(q, k, v, causal, scale, window=0):
     """jnp fallback, also the numerics oracle for the kernel tests.
-    q, k, v: (BH, T, D). ``window`` > 0 (causal only): a query sees the
+    q, k: (BH, T, D), v: (BH, T, D_v). ``window`` > 0 (causal only): a query sees the
     last ``window`` keys up to itself and nothing older."""
     s = jnp.einsum("btd,bsd->bts", q * scale, k)
     if causal:
@@ -118,7 +118,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
     Under ``window`` the loop starts at the block that holds the oldest
     key the program's first query sees, and both edges are masked.
     Beside the output it writes each row's log-sum-exp, the one
-    statistic the backward kernel rebuilds the weights from."""
+    statistic the backward kernel rebuilds the weights from. V and the
+    output have lanes of their own (D_v), which may differ from the
+    scores' (D)."""
     qi = pl.program_id(1)
     q = q_ref[0]                                       # (BQ, D)
     n_k = k_ref.shape[1] // block_k
@@ -142,7 +144,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
 
     m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
-    a0 = jnp.zeros((block_q, q_ref.shape[2]), jnp.float32)
+    a0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
     # a row whose window starts past the first block's end folds that
     # block in as all-masked (weight exp(0) under the running maximum
     # NEG_INF); its first visible key then rescales that by exp(-1e30)
@@ -173,9 +175,10 @@ def _kernel_specs(interpret):
                                              "interpret", "window"))
 def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
                 window=0):
-    """-> (out [bh, t_q, d], lse [bh, 1, t_q] float32)."""
+    """-> (out [bh, t_q, d_v], lse [bh, 1, t_q] float32); q and k are
+    ``d`` lanes wide, v ``d_v``."""
     bh, t_q, d = q.shape
-    t_k = k.shape[1]
+    t_k, d_v = k.shape[1], v.shape[2]
     # grouped-query heads: consecutive ``group`` query heads read one
     # K/V head, picked by the index map; nothing is repeated in HBM
     group = bh // k.shape[0]
@@ -185,15 +188,15 @@ def _flash_call(q, k, v, causal, scale, block_q, block_k, interpret,
     spec, like = _kernel_specs(interpret)
     return pl.pallas_call(
         kernel,
-        out_shape=(like(q.shape, q.dtype, q),
+        out_shape=(like((bh, t_q, d_v), q.dtype, q),
                    like((bh, 1, t_q), jnp.float32, q)),
         grid=(bh, t_q // block_q),
         in_specs=[
             spec((1, block_q, d), lambda b, i: (b, i, 0)),
             spec((1, t_k, d), lambda b, i: (b // group, 0, 0)),
-            spec((1, t_k, d), lambda b, i: (b // group, 0, 0)),
+            spec((1, t_k, d_v), lambda b, i: (b // group, 0, 0)),
         ],
-        out_specs=(spec((1, block_q, d), lambda b, i: (b, i, 0)),
+        out_specs=(spec((1, block_q, d_v), lambda b, i: (b, i, 0)),
                    spec((1, 1, block_q), lambda b, i: (b, 0, i))),
         interpret=interpret,
     )(q, k, v)
@@ -210,9 +213,10 @@ def _flash_bwd_kernel(q_ref, do_ref, o_ref, lse_ref, k_ref, v_ref, dq_ref,
     and ``dv`` accumulate in float32 over the whole key length in VMEM,
     across every program of the K/V head (its ``group`` query heads
     among them: nothing is repeated or folded in HBM), and are written
-    once, by the last."""
+    once, by the last. ``dq``, ``dk`` are D lanes wide as q and k are,
+    ``dv`` D_v as v, ``do`` and ``o`` are."""
     g, qi = pl.program_id(1), pl.program_id(2)
-    q, do = q_ref[0], do_ref[0]                        # (BQ, D)
+    q, do = q_ref[0], do_ref[0]                        # (BQ, D), (BQ, D_v)
     lse = lse_ref[0, 0][:, None]                       # (BQ, 1)
     delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -249,15 +253,21 @@ def _flash_bwd_kernel(q_ref, do_ref, o_ref, lse_ref, k_ref, v_ref, dq_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_vmem_bytes(t_k, d, block_q, block_k, itemsize):
-    """What one backward program keeps in VMEM: K and V of its head and
-    the ``dk``, ``dv`` blocks staged twice (the pipeline's two buffers),
-    the two float32 accumulators, the per-block operands twice, and the
+def _lanes(d):
+    """A row of ``d`` lanes as VMEM holds it: whole 128-lane tiles."""
+    return -(-d // 128) * 128
+
+
+def _bwd_vmem_bytes(t_k, d, block_q, block_k, itemsize, d_v=None):
+    """What one backward program keeps in VMEM, each operand at its own
+    width (q, k and their gradients ``d`` lanes, v, o, do and ``dv``
+    ``d_v``; a row pads to whole lane tiles): K and V of its head and the
+    ``dk``, ``dv`` blocks staged twice (the pipeline's two buffers), the
+    two float32 accumulators, the per-block operands twice, and the
     float32 tiles of a step (scores, weights, their gradients)."""
-    lanes = max(d, 128)                 # a row pads to the lane width
-    whole = t_k * lanes * (4 * 2 * itemsize + 2 * 4)
-    blocks = block_q * lanes * (4 * 2 * itemsize + 2 * 4)
-    return whole + blocks + 6 * block_q * block_k * 4
+    lanes = _lanes(d) + _lanes(d if d_v is None else d_v)
+    per_row = lanes * (2 * 2 * itemsize + 4)
+    return (t_k + block_q) * per_row + 6 * block_q * block_k * 4
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale",
@@ -266,11 +276,13 @@ def _bwd_vmem_bytes(t_k, d, block_q, block_k, itemsize):
 def _flash_bwd_call(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                     interpret, window=0):
     """-> (dq, dk, dv) from the forward's residuals and ``do``, in one
-    kernel (:func:`_flash_bwd_kernel`)."""
+    kernel (:func:`_flash_bwd_kernel`); ``dv``, ``o`` and ``do`` are v's
+    width, ``dq`` and ``dk`` q's."""
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
     bh_k, t_k, _ = k.shape
+    d_v = v.shape[2]
     group = bh // bh_k
     spec, like = _kernel_specs(interpret)
     rows = lambda h, g, i: (h * group + g, i, 0)
@@ -278,7 +290,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     # Mosaic's default scope holds 16 MiB; the accumulators alone are
     # 8 MiB at 8,192 x 128. Ask for what the shapes need and its half
     # again for the compiler's own temporaries.
-    need = _bwd_vmem_bytes(t_k, d, block_q, block_k, q.dtype.itemsize)
+    need = _bwd_vmem_bytes(t_k, d, block_q, block_k, q.dtype.itemsize, d_v)
     params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=need + need // 2)}
     return pl.pallas_call(
@@ -290,16 +302,16 @@ def _flash_bwd_call(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         grid=(bh_k, group, t_q // block_q),
         in_specs=[
             spec((1, block_q, d), rows),
-            spec((1, block_q, d), rows),
-            spec((1, block_q, d), rows),
+            spec((1, block_q, d_v), rows),
+            spec((1, block_q, d_v), rows),
             spec((1, 1, block_q), lambda h, g, i: (h * group + g, 0, i)),
             spec((1, t_k, d), head),
-            spec((1, t_k, d), head),
+            spec((1, t_k, d_v), head),
         ],
         out_specs=(spec((1, block_q, d), rows), spec((1, t_k, d), head),
-                   spec((1, t_k, d), head)),
+                   spec((1, t_k, d_v), head)),
         scratch_shapes=[pltpu.VMEM((t_k, d), jnp.float32),
-                        pltpu.VMEM((t_k, d), jnp.float32)],
+                        pltpu.VMEM((t_k, d_v), jnp.float32)],
         interpret=interpret,
         # the instruction's name in a capture, whatever transform wraps
         # the call; the forward's events keep ``_flash_call``
@@ -1177,8 +1189,11 @@ def delta_rule(query, key, value, g, beta, chunk=64, eps=1e-6,
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
                     block_k=512, interpret=None, force=False, window=0):
-    """Blockwise attention, O(T) memory. q, k, v: (B, H, T, D) or
-    (BH, T, D); k and v may have fewer heads than q (grouped-query
+    """Blockwise attention, O(T) memory. q, k: (B, H, T, D) or (BH, T, D);
+    v: the same with a width of its own, D_v, which is the output's
+    (latent attention scores over 192 lanes and reads values of 128; a
+    width that is no multiple of 128 is the whole last dimension of its
+    block); k and v may have fewer heads than q (grouped-query
     attention: H a multiple of their head count, query head h reading
     K/V head h // group). Dispatches to the Pallas kernel for long sequences
     (>= FLASH_MIN_SEQ, where it beats XLA's dense lowering by the
@@ -1201,7 +1216,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
         b, h, t, d = q.shape
         q = q.reshape(b * h, t, d)
         k = k.reshape(b * k.shape[1], k.shape[2], d)
-        v = v.reshape(b * v.shape[1], v.shape[2], d)
+        v = v.reshape(b * v.shape[1], v.shape[2], v.shape[3])
         squeeze = (b, h)
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -1216,7 +1231,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
         interpret = jax.default_backend() != "tpu"
     tiles = not (t_q % block_q or t_k % block_k or
                  (causal and t_q != t_k))
-    if 2 * t_k * q.shape[-1] * q.dtype.itemsize > VMEM_BUDGET_BYTES:
+    if t_k * (q.shape[-1] + v.shape[-1]) * q.dtype.itemsize > \
+            VMEM_BUDGET_BYTES:
         tiles = False  # K+V won't fit VMEM; see VMEM_BUDGET_BYTES
     if interpret and jax.typeof(q).vma:
         # pallas interpret mode cannot propagate shard_map

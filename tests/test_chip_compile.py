@@ -210,6 +210,33 @@ def test_flash_compiles_with_a_512_key_window_over_groups_of_9_and_6(chip):
         _backward_is_a_kernel(q, kv, 128 ** -0.5, window)
 
 
+def test_latent_attention_compiles_at_192_lane_scores_over_128_lane_values(
+        chip):
+    """Moonlight-16B-A3B's latent attention at the benchmark's batch: 16
+    heads, one sequence of 8,192 tokens, q and k of 192 lanes (the whole
+    last dimension of their blocks), v of 128. Both kernels are
+    ``tpu_custom_call``s; the backward keeps K, V, ``dk`` and ``dv`` of the
+    head at their own widths (66 MiB of scope asked: 192 lanes take two
+    lane tiles) and writes ``dv`` at 128 lanes."""
+    q = chip((16, 8192, 192), jnp.bfloat16)
+    v = chip((16, 8192, 128), jnp.bfloat16)
+    _is_kernel(pk._flash_call.lower(
+        q, q, v, causal=True, scale=192 ** -0.5, block_q=256, block_k=512,
+        interpret=False))
+
+    def loss(q, k, v):
+        out = pk._flash_diff(q, k, v, True, 192 ** -0.5, 256, 512, False, 0)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile().as_text()
+    names = sorted(_kernel_names(text))
+    assert len(names) == 2 and names[0] == "_flash_bwd_call"
+    assert " while(" not in text
+    assert pk._bwd_vmem_bytes(8192, 192, 512, 512, 2, 128) * 3 // 2 < \
+        100 * 2 ** 20
+
+
 def _recorded_pair(chip, net, plist, in_spec, ins):
     """``net``'s two programs of a recorded training call
     (``_build_recorded``: the forward that writes the residuals, and the

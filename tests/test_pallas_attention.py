@@ -249,3 +249,132 @@ def test_backward_is_one_kernel_and_no_scan():
     for gone in ("scan[", "dynamic_update_slice", "f32[14,32,128]", "f32[14,128,128]",
                  "bf16[14,128,16] = broadcast", "repeat"):
         assert gone not in text, gone
+
+
+# -- a value width of its own (latent attention) -------------------------------
+
+@pytest.mark.parametrize("group,d_v,causal", [(1, 128, True), (2, 64, False)])
+def test_values_of_their_own_width_forward_and_backward(group, d_v, causal):
+    """Moonlight-16B-A3B's latent attention scores over 192 lanes (128
+    without position, 64 rotary) and reads values of 128: the kernels take
+    ``v`` of its own width, 192 as the whole last dimension of a q or k
+    block; the output and ``dv`` are ``d_v`` wide, ``dq`` and ``dk`` 192.
+    Here also ``d_v`` 64 under grouped heads. Forward and gradient against
+    the dense oracle."""
+    rng = np.random.default_rng(d_v + group)
+    t_q, t_k = (64, 64) if causal else (32, 96)
+    q, k, _ = _qkv(rng, 2 * group, 2, t_q, t_k, 192, jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, t_k, d_v)), jnp.float32)
+    head = jnp.asarray(rng.standard_normal((2 * group, t_q, d_v)),
+                       jnp.float32)
+    scale = 192 ** -0.5
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=16,
+                               block_k=32, force=True, interpret=True)
+
+    oracle = lambda q, k, v: _oracle(q, k, v, causal, scale)
+    out = flash(q, k, v)
+    assert out.shape == (2 * group, t_q, d_v)
+    np.testing.assert_allclose(out, oracle(q, k, v), rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: (flash(*a) * head).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (oracle(*a) * head).sum(), (0, 1, 2))(q, k, v)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(a), w, rtol=1e-4,
+                                   atol=1e-4 * np.abs(w).max())
+
+
+def test_the_four_head_layout_passes_the_value_width_through():
+    """(B, H, T, D) in, (B, H, T, D_v) out, the dense path alike."""
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.standard_normal((1, 2, 64, 24)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 2, 64, 24)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 2, 64, 16)), jnp.float32)
+    want = _dense_reference(q[0], k[0], v[0], True, 24 ** -0.5)
+    for force in (True, False):
+        out = flash_attention(q, k, v, causal=True, block_q=16, block_k=32,
+                              force=force, interpret=True)
+        assert out.shape == (1, 2, 64, 16)
+        np.testing.assert_allclose(out[0], want, rtol=2e-5, atol=2e-5)
+
+
+def _plans(q, kv, window, scale):
+    """(name, grid, block shapes, VMEM limit) of every Pallas call in the
+    gradient of the kernel path at the chip's blocks (256 x 512 forward,
+    512 x 512 backward), compiled mode, from the traced program."""
+    def loss(q, k, v):
+        return pk._flash_diff(q, k, v, True, scale, 256, 512, False,
+                              window).astype(jnp.float32).sum()
+
+    out = []
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, kv)
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                mosaic = eqn.params.get("compiler_params", {}).get(
+                    "mosaic_tpu")
+                out.append((eqn.params.get("name") or "_flash_call",
+                            tuple(gm.grid),
+                            [tuple(getattr(b, "block_size", b)
+                                   for b in m.block_shape)
+                             for m in gm.block_mappings],
+                            mosaic and mosaic.vmem_limit_bytes))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return out
+
+
+# the four language-model cells' attention (heads, keys, lanes over K/V
+# heads, window) and what their kernels were planned as before the value
+# width became a width of its own: grid, block shapes, the backward's VMEM
+@pytest.mark.parametrize("q,kv,window,fwd_grid,bwd_grid,vmem", [
+    ((64, 4096, 64), (16, 4096, 64), 0, (64, 16), (16, 4, 8), 30670848),
+    ((32, 4096, 256), (4, 4096, 256), 0, (32, 16), (4, 8, 8), 51904512),
+    ((28, 8192, 128), (4, 8192, 128), 4096, (28, 32), (4, 7, 16), 49545216),
+    ((28, 8192, 128), (4, 8192, 128), 0, (28, 32), (4, 7, 16), 49545216),
+    ((72, 4096, 128), (8, 4096, 128), 512, (72, 16), (8, 9, 8), 30670848),
+    ((48, 4096, 128), (8, 4096, 128), 0, (48, 16), (8, 6, 8), 30670848)])
+def test_the_cells_kernels_are_planned_as_before(q, kv, window, fwd_grid,
+                                                 bwd_grid, vmem):
+    """Where q, k and v share a width the kernels are the ones the
+    language-model cells compiled before latent attention: the same grids,
+    block shapes and backward VMEM scope (the forward asks for none)."""
+    d, t_k = q[2], kv[1]
+    plans = _plans(jax.ShapeDtypeStruct(q, jnp.bfloat16),
+                   jax.ShapeDtypeStruct(kv, jnp.bfloat16), window, d ** -0.5)
+    assert [p[0] for p in plans] == ["_flash_call", "_flash_bwd_call"]
+    (_, grid, blocks, limit), (_, bgrid, bblocks, blimit) = plans
+    assert grid == fwd_grid and limit is None
+    assert blocks == [(1, 256, d), (1, t_k, d), (1, t_k, d), (1, 256, d),
+                      (1, 1, 256)]
+    assert bgrid == bwd_grid and blimit == vmem
+    assert bblocks == [(1, 512, d)] * 3 + [(1, 1, 512)] + \
+        [(1, t_k, d)] * 2 + [(1, 512, d)] + [(1, t_k, d)] * 2
+
+
+def test_latent_attention_kernels_plan_each_width_apart():
+    """At the Moonlight cell's shapes (16 heads, 8,192 keys, 192-lane q and
+    k, 128-lane v) the blocks of v, the output, ``do`` and ``dv`` are 128
+    lanes and the rest 192; the backward's VMEM counts each operand at its
+    own padded width (192 takes two lane tiles)."""
+    q = jax.ShapeDtypeStruct((16, 8192, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((16, 8192, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return pk._flash_diff(q, k, v, True, 192 ** -0.5, 256, 512, False,
+                              0).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, q, v))
+    assert "bf16[16,8192,128]" in text and text.count("pallas_call[") == 2
+    need = pk._bwd_vmem_bytes(8192, 192, 512, 512, 2, 128)
+    assert need == (8192 + 512) * (256 + 128) * 12 + 6 * 512 * 512 * 4
+    assert pk._bwd_vmem_bytes(8192, 128, 512, 512, 2) == \
+        pk._bwd_vmem_bytes(8192, 128, 512, 512, 2, 128)
+    # K and V of a head at their own widths are inside the dispatch's line
+    assert 8192 * (192 + 128) * 2 <= pk.VMEM_BUDGET_BYTES
